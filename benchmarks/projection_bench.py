@@ -36,6 +36,7 @@ def _bench(fn, *args, iters: int = 30, warmup: int = 3) -> float:
 def bench_projection_standalone(batch: int = 256) -> list[dict]:
     """Raw projection op: XLA one-hot-matmul vs Pallas kernel."""
     from d4pg_tpu.ops import categorical_projection, make_support
+    from d4pg_tpu.ops.pallas_mode import pallas_interpret
     from d4pg_tpu.ops.pallas_projection import categorical_projection_pallas
 
     rows = []
@@ -47,7 +48,7 @@ def bench_projection_standalone(batch: int = 256) -> list[dict]:
         )
         rewards = jnp.asarray(rng.uniform(-1, 0, batch), jnp.float32)
         discounts = jnp.full((batch,), 0.99**3, jnp.float32)
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
 
         xla_fn = jax.jit(lambda p, r, d: categorical_projection(support, p, r, d))
         pallas_fn = jax.jit(

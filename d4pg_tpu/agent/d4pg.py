@@ -285,7 +285,7 @@ def train_step(
         whose bits a single-device vmap oracle can replay exactly —
         ``pmean``'s backend AllReduce cannot be (its accumulation order is
         the backend's choice). ``None`` keeps the pmean/axis_name path.
-      descent: ``(leaves [L], next_prefixes [B])`` — the fused-tier
+      descent: ``(sums_lane [2L], next_prefixes [B])`` — the fused-tier
         pipelining seam (ISSUE 16, ``ops/pallas_fused_step.py``): the
         step's fused-loss Pallas program ALSO descends the device-PER
         segment tree for the NEXT scan step's stratified prefixes, so the
@@ -420,10 +420,11 @@ def train_step(
             # Pallas kernel: the projected target distribution is never
             # materialized in HBM (fwd or bwd — the VJP recomputes Φ in
             # VMEM). The XLA branch below stays the reference oracle.
+            from d4pg_tpu.ops.pallas_mode import pallas_interpret
             from d4pg_tpu.ops.pallas_projection import fused_categorical_loss
 
             fused_target_probs = jax.lax.stop_gradient(target_probs)
-            interpret = jax.default_backend() != "tpu"  # CPU tests
+            interpret = pallas_interpret()
 
             def critic_loss_fn(critic_params):
                 pred = critic.apply(critic_params, batch["obs"], batch["action"])
@@ -432,7 +433,7 @@ def train_step(
                         fused_categorical_loss_descent,
                     )
 
-                    leaves, next_prefixes = descent
+                    sums_lane, next_prefixes = descent
                     ce, overlap, next_idx = fused_categorical_loss_descent(
                         support,
                         pred,
@@ -440,7 +441,7 @@ def train_step(
                         batch["reward"],
                         batch["discount"],
                         next_prefixes,
-                        leaves,
+                        sums_lane,
                         interpret,
                     )
                 else:
@@ -463,6 +464,7 @@ def train_step(
                 return loss, per_sample
 
         elif config.projection_backend == "pallas":
+            from d4pg_tpu.ops.pallas_mode import pallas_interpret
             from d4pg_tpu.ops.pallas_projection import categorical_projection_pallas
 
             proj = categorical_projection_pallas(
@@ -470,7 +472,7 @@ def train_step(
                 target_probs,
                 batch["reward"],
                 batch["discount"],
-                jax.default_backend() != "tpu",  # interpret mode off-TPU
+                pallas_interpret(),
             )
         else:
             proj = categorical_projection(
@@ -544,7 +546,7 @@ def train_step(
         def critic_loss_fn(stacked_params):
             losses, per_sample = jax.vmap(_single_loss_fn)(stacked_params)
             if descent is not None:
-                # Every member ran the identical descent (same leaves,
+                # Every member ran the identical descent (same tree,
                 # same prefixes, exact int32) — member 0 IS the result.
                 per_sample, next_idx = per_sample
                 return jnp.sum(losses), (
@@ -684,7 +686,7 @@ def fused_train_scan(
     """Scan ``train_step`` over pre-gathered [K, B] batches — the shared
     inner loop of the on-device trainer, the benchmark, and the host
     trainer's ``steps_per_dispatch`` mode (one dispatch per K grad steps
-    amortizes per-call latency, which dominates on remote/tunneled TPUs).
+    amortizes per-call overhead).
     ``axis_name``/``sync_fn`` thread through to each step's gradient
     combine (DP under shard_map; the sharded megastep's deterministic
     mean). Returns (state, metrics pytree with leading K axis,

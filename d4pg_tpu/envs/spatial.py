@@ -3,8 +3,8 @@
 
 Why this exists: Humanoid is the reference's scale-out task (env capability
 ``main.py:42,68``, worker fan-out ``main.py:399-403``) and the one
-BASELINE.json config whose host path is permanently walled by host→device
-link bandwidth (~16 grad-steps/s; docs/REMOTE_TPU.md "fourth tax"). The
+BASELINE.json config whose host path ships the widest rows host→device
+(348-dim observations, twice per transition). The
 planar engine's own docstring argues its design generalizes to 3D; this
 module is that generalization, so Humanoid rolls out ON the TPU inside the
 same XLA program as the learner.

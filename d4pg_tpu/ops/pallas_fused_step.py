@@ -9,24 +9,24 @@ loss) forbids fusing a step's OWN descent into its loss — but the tree is
 constant for the whole scan (priorities write back post-scan, last-wins),
 so every step's prefixes are known up front and the descents are
 order-independent. That makes the classic software-pipelining move legal:
-the step-``t`` loss program also computes the descent counts for step
+the step-``t`` loss program also computes the descent for step
 ``t+1``'s prefixes, with one small prologue descent
 (:func:`~d4pg_tpu.ops.pallas_tree.find_prefix_pallas`) covering step 0.
-Steady state then runs ONE Pallas program per scan step — the leaf array
-rides the same VMEM residency as the loss tiles instead of paying its own
-kernel launch + HBM sweep.
+Steady state then runs ONE Pallas program per scan step — the tree's node
+sums ride the same VMEM residency as the loss tiles instead of paying
+their own kernel launch + HBM sweep.
 
 Byte-parity with the separate-programs oracle is by construction, not by
 tolerance: the loss tile is :func:`~d4pg_tpu.ops.pallas_projection
 .loss_tile` and the descent tile is :func:`~d4pg_tpu.ops.pallas_tree
-.count_tile` — the literal functions the separate kernels run — on
-identical inputs (same leaves, same prefix values, same grid tiling), and
+.descend_tile` — the literal functions the separate kernels run — on
+identical inputs (same tree, same prefix values, same grid tiling), and
 the descent output is exact int32. ``tests/test_fused_descent.py`` pins
 the whole-TrainState equality across multi-dispatch runs.
 
 The backward pass is unchanged from the fused-loss kernel: the VJP
 recomputes Φ in VMEM via the SAME ``_fused_loss_grad_kernel`` program
-(descent has no gradient — the count output's cotangent is structurally
+(descent has no gradient — the index output's cotangent is structurally
 zero), so gradients are bit-identical to the non-descent fused tier.
 """
 
@@ -47,47 +47,52 @@ from d4pg_tpu.ops.pallas_projection import (
     _pad_batch,
     loss_tile,
 )
-from d4pg_tpu.ops.pallas_tree import _BLOCK_L, count_tile
+from d4pg_tpu.ops.pallas_tree import (
+    _BLOCK_L,
+    descend_tile,
+    left_rows,
+    tree_depth,
+    vmem_limit_bytes,
+)
 
 
 def _fused_step_kernel(
-    num_atoms, v_min, v_max, n_blocks,
-    q_ref, p_ref, r_ref, d_ref, pref_ref, leaves_ref,
-    ce_ref, ov_ref, cnt_ref,
+    num_atoms, v_min, v_max, depth,
+    q_ref, p_ref, r_ref, d_ref, pref_ref, lefts_ref,
+    ce_ref, ov_ref, idx_ref,
 ):
     """One [TILE_B] batch tile: loss for THIS step + descent for the NEXT.
 
     ``q_ref``/``p_ref`` [TB, A], ``r_ref``/``d_ref``/``pref_ref`` [TB, 1],
-    ``leaves_ref`` [1, L] (whole leaf array, VMEM-resident across the
-    grid), outputs ce/ov [TB, 1] f32 and cnt [TB, 1] i32 (unclamped
-    counts — the wrapper applies the reference clamps)."""
+    ``lefts_ref`` [rows, 128] (the tree's left-child sums, VMEM-resident
+    across the grid), outputs ce/ov [TB, 1] f32 and idx [TB, 1] i32 (leaf
+    indices)."""
     ce_ref[:], ov_ref[:] = loss_tile(
         num_atoms, v_min, v_max, q_ref[:], p_ref[:], r_ref[:], d_ref[:]
     )
-    cnt_ref[:] = count_tile(n_blocks, leaves_ref, pref_ref[:])
+    idx_ref[:] = descend_tile(depth, lefts_ref, pref_ref[:])
 
 
 def _fused_step_call(support, interpret, pred_logits, target_probs,
-                     rewards, discounts, next_prefixes, leaves):
+                     rewards, discounts, next_prefixes, sums_lane):
     B, A = target_probs.shape
-    L = leaves.shape[0]
-    lpad = pl.cdiv(L, _BLOCK_L) * _BLOCK_L
     padded, (pred_logits, target_probs), cols1d = _pad_batch(
         [pred_logits, target_probs], [rewards, discounts, next_prefixes]
     )
     cols = [a[:, None].astype(jnp.float32) for a in cols1d]
-    leaves2 = jnp.pad(leaves.astype(jnp.float32), (0, lpad - L))[None, :]
+    lefts = left_rows(sums_lane)
+    n_rows = lefts.shape[0]
     kernel = functools.partial(
         _fused_step_kernel, A, support.v_min, support.v_max,
-        lpad // _BLOCK_L,
+        tree_depth(sums_lane),
     )
     row_spec = pl.BlockSpec((_TILE_B, A), lambda i: (i, 0),
                             memory_space=pltpu.VMEM)
     col_spec = pl.BlockSpec((_TILE_B, 1), lambda i: (i, 0),
                             memory_space=pltpu.VMEM)
-    leaf_spec = pl.BlockSpec((1, lpad), lambda i: (0, 0),
+    tree_spec = pl.BlockSpec((n_rows, _BLOCK_L), lambda i: (0, 0),
                              memory_space=pltpu.VMEM)
-    ce, ov, cnt = pl.pallas_call(
+    ce, ov, idx = pl.pallas_call(
         kernel,
         out_shape=[
             jax.ShapeDtypeStruct((padded, 1), jnp.float32),
@@ -95,30 +100,31 @@ def _fused_step_call(support, interpret, pred_logits, target_probs,
             jax.ShapeDtypeStruct((padded, 1), jnp.int32),
         ],
         grid=(padded // _TILE_B,),
-        in_specs=[row_spec, row_spec] + [col_spec] * 3 + [leaf_spec],
+        in_specs=[row_spec, row_spec] + [col_spec] * 3 + [tree_spec],
         out_specs=[col_spec, col_spec, col_spec],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=vmem_limit_bytes(n_rows),
+        ),
         interpret=interpret,
     )(pred_logits.astype(jnp.float32), target_probs.astype(jnp.float32),
-      *cols, leaves2)
-    # Same clamp as find_prefix_pallas: a float-edge prefix past the last
-    # nonzero leaf's cumsum counts padded leaves too.
-    idx = jnp.minimum(cnt[:B, 0], jnp.int32(L - 1))
-    return ce[:B, 0], ov[:B, 0], idx
+      *cols, lefts)
+    return ce[:B, 0], ov[:B, 0], idx[:B, 0]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _fused_step(support, interpret, pred_logits, target_probs, rewards,
-                discounts, next_prefixes, leaves):
+                discounts, next_prefixes, sums_lane):
     return _fused_step_call(
         support, interpret, pred_logits, target_probs, rewards, discounts,
-        next_prefixes, leaves,
+        next_prefixes, sums_lane,
     )
 
 
 def _fused_step_fwd(support, interpret, pred_logits, target_probs, rewards,
-                    discounts, next_prefixes, leaves):
+                    discounts, next_prefixes, sums_lane):
     out = _fused_step(support, interpret, pred_logits, target_probs,
-                      rewards, discounts, next_prefixes, leaves)
+                      rewards, discounts, next_prefixes, sums_lane)
     # Residuals are all pre-existing arrays (the fused-loss discipline):
     # the backward kernel recomputes Φ in VMEM and never needs the tree.
     return out, (pred_logits, target_probs, rewards, discounts)
@@ -130,7 +136,7 @@ def _fused_step_bwd(support, interpret, residuals, cotangents):
     _, A = target_probs.shape
     # The EXACT backward program of the non-descent fused tier
     # (_fused_loss_grad_kernel) — gradients are bit-identical between the
-    # two tiers by sharing it. Prefixes/leaves take no gradient: the draw
+    # two tiers by sharing it. Prefixes/tree take no gradient: the draw
     # is sampling, not a differentiable path (matching stop_gradient on
     # the target side).
     (dq,) = _fused_call(
@@ -151,7 +157,7 @@ def fused_categorical_loss_descent(
     rewards: jax.Array,
     discounts: jax.Array,
     next_prefixes: jax.Array,
-    leaves: jax.Array,
+    sums_lane: jax.Array,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Fused Φ-projection + CE loss for THIS scan step, plus the segment-
@@ -160,14 +166,14 @@ def fused_categorical_loss_descent(
 
     Loss outputs are exactly :func:`~d4pg_tpu.ops.pallas_projection
     .fused_categorical_loss`'s; the descent output is exactly
-    ``minimum(find_prefix_pallas(leaves, next_prefixes), L-1)`` (the
-    caller applies ``lane_draw``'s fill clamp on top, like the megastep
-    body does for the standalone kernel).
+    ``find_prefix_pallas(sums_lane, next_prefixes)`` over one lane's flat
+    ``[2L]`` tree (the caller applies ``lane_draw``'s fill clamp on top,
+    like the megastep body does for the standalone kernel).
 
     Returns:
       (ce [B] f32, overlap [B] f32, next_idx [B] int32).
     """
     return _fused_step(
         support, bool(interpret), pred_logits, target_probs, rewards,
-        discounts, next_prefixes, leaves,
+        discounts, next_prefixes, sums_lane,
     )

@@ -24,11 +24,11 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from d4pg_tpu.agent.d4pg import fused_train_scan, train_step
 from d4pg_tpu.agent.state import D4PGConfig
-from d4pg_tpu.parallel.compat import shard_map
 
 
 def make_dp_train_step(config: D4PGConfig, mesh: Mesh, donate: bool = True):

@@ -42,7 +42,8 @@ it reduces to the host formula term for term.
 
 Backend ladder (the ``ops/pallas_projection.py`` convention): the jnp
 log-depth gather descent here is the reference program; a Pallas
-blocked-prefix-scan kernel (``ops/pallas_tree.py``) is selectable via
+kernel that runs the same walk without the gathers
+(``ops/pallas_tree.py``) is selectable via
 ``TrainConfig.device_tree_backend="pallas"`` with the XLA path kept as
 its equivalence oracle.
 
@@ -247,8 +248,9 @@ def lane_draw(
     ``local_filled`` clamp mirrors the host ``_draw``'s ``size - 1``
     guard (at dp=1 ``local_filled`` IS the global fill count).
     ``tree_backend`` selects the descent implementation: "xla" is the
-    reference log-depth gather descent, "pallas" the blocked prefix-scan
-    kernel (``ops/pallas_tree.py``) validated against it."""
+    reference log-depth gather descent, "pallas" the gather-free kernel
+    (``ops/pallas_tree.py``) that runs the same walk and returns the same
+    leaves."""
     width = sums_lane.shape[0]
     half = width // 2
     total = sums_lane[1]
@@ -256,7 +258,7 @@ def lane_draw(
     if tree_backend == "pallas":
         from d4pg_tpu.ops.pallas_tree import find_prefix_pallas
 
-        idx = find_prefix_pallas(sums_lane[half:], pre, interpret=interpret)
+        idx = find_prefix_pallas(sums_lane, pre, interpret=interpret)
     else:
         idx = descend_prefix(sums_lane, pre)
     idx = jnp.clip(idx, 0, jnp.maximum(local_filled - 1, 0))
@@ -348,9 +350,9 @@ def make_tree_ingest(alpha: float, local_capacity: int, mesh=None):
 
         return jax.jit(_ingest, donate_argnums=(0,))
 
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from d4pg_tpu.parallel.compat import shard_map
     from d4pg_tpu.parallel.partition import tree_partition_specs
 
     n_shards = int(mesh.shape["dp"])

@@ -408,9 +408,9 @@ def make_sharded_ingest(mesh, chunk_local: int, obs_dim: int, action_dim: int):
     leading axis is the shard axis, placed ``P("dp", ...)`` so each dp
     shard receives exactly its sub-chunk — ingest stays shard-local, no
     collectives in the lowered program."""
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from d4pg_tpu.parallel.compat import shard_map
     from d4pg_tpu.parallel.partition import ring_partition_specs
 
     template = DeviceRing(

@@ -10,6 +10,7 @@ API-compatible with :class:`d4pg_tpu.replay.SumTree` / ``MinTree`` so
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -31,26 +32,43 @@ def _build_dir() -> str:
     return d
 
 
+def library_path() -> str:
+    """Where the binary for the CURRENT ``native/sumtree.cpp`` lives: the
+    file name carries a hash of the source, so a binary left on disk by
+    another checkout, another commit or a tree copy with scrambled mtimes
+    is never mistaken for this one (the build dir is gitignored and
+    travels with directory copies)."""
+    with open(_source_path(), "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_build_dir(), f"libsumtree-{digest}.so")
+
+
 def load_library() -> ctypes.CDLL:
-    """Compile (if stale) and load the shared library. Raises on any failure;
-    callers with ``tree_backend='auto'`` catch and fall back to NumPy."""
+    """Compile (if absent for this source) and load the shared library.
+    Raises on any failure; callers with ``tree_backend='auto'`` catch and
+    fall back to NumPy."""
     global _LIB
     with _LIB_LOCK:
         if _LIB is not None:
             return _LIB
-        src = _source_path()
-        so = os.path.join(_build_dir(), "libsumtree.so")
-        # <= so a fresh checkout (equal mtimes) rebuilds rather than loading
-        # a foreign binary; no -march=native for the same reason (the build
-        # dir is gitignored, but belt and braces).
-        if not os.path.exists(so) or os.path.getmtime(so) <= os.path.getmtime(src):
+        so = library_path()
+        if not os.path.exists(so):
             # one-time compile; serializing concurrent first-users on the
-            # lock is the point (two racing g++ -o same.so corrupt it)
-            subprocess.run(  # d4pglint: disable=lock-blocking-call
-                ["g++", "-O3", "-shared", "-fPIC", "-o", so, src],
-                check=True,
-                capture_output=True,
-            )
+            # lock is the point. Built under a private name and renamed
+            # into place so another PROCESS never loads a half-written
+            # file. No -march=native: the build dir travels with copies.
+            tmp = f"{so}.{os.getpid()}.tmp"
+            try:
+                subprocess.run(  # d4pglint: disable=lock-blocking-call
+                    ["g++", "-O3", "-shared", "-fPIC", "-o", tmp,
+                     _source_path()],
+                    check=True,
+                    capture_output=True,
+                )
+                os.replace(tmp, so)
+            finally:
+                if os.path.exists(tmp):
+                    os.unlink(tmp)
         lib = ctypes.CDLL(so)
         lib.st_create.restype = ctypes.c_void_p
         lib.st_create.argtypes = [ctypes.c_int64, ctypes.c_int]

@@ -59,6 +59,12 @@ from typing import List, Optional, Tuple
 #          to local collection, and says so in the matrix).
 OBS_MODES = ("f32", "u8", "bf16")
 
+# Tree leaves per dp shard the Pallas descent tiers accept: the kernel
+# (ops/pallas_tree.py) keeps 4 bytes per leaf resident in VMEM, and 2^24
+# leaves (64 MiB of a v5e's 128 MiB) is the largest size it was compiled
+# and checked against the XLA descent at (chip run, PR 21).
+PALLAS_TREE_MAX_LEAVES = 1 << 24
+
 
 @dataclass(frozen=True)
 class CapabilityGap:
@@ -118,6 +124,7 @@ class RequestedCaps:
     # negotiable facts, not trainer-side asserts.
     fused_descent: bool = False
     ingest_prefetch: bool = False
+    device_tree: str = "xla"        # xla | pallas (device-PER descent)
     projection: str = "xla"         # xla | pallas | pallas_fused
     dist_kind: str = "categorical"  # categorical | quantile | iqn
     chaos: bool = False
@@ -162,6 +169,7 @@ def from_train_config(config, *, on_device: bool = False,
         prefetch=bool(config.prefetch),
         fused_descent=bool(getattr(config, "fused_descent", False)),
         ingest_prefetch=bool(getattr(config, "ingest_prefetch", False)),
+        device_tree=getattr(config, "device_tree_backend", "xla"),
         projection=config.agent.projection_backend,
         dist_kind=config.agent.dist.kind,
         chaos=bool(config.chaos),
@@ -350,6 +358,30 @@ def negotiate(caps: RequestedCaps) -> Negotiation:
                 "--fused-descent fuses into the CATEGORICAL projection "
                 "kernel; quantile/IQN heads keep the separate-programs "
                 "tier",
+            )
+
+    # Both Pallas descent tiers (the standalone kernel and the fused
+    # descent-in-scan) keep one lane's left-child sums — 4 bytes per leaf —
+    # resident in VMEM. A ring too large for that is refused here, by name
+    # — never swapped for the XLA descent behind the user's back. THE one
+    # check: the kernel itself has none (past it, Mosaic fails the compile).
+    if (
+        caps.placement == "device" and caps.prioritized
+        and (caps.device_tree == "pallas" or caps.fused_descent)
+        and caps.replay_capacity
+    ):
+        per_shard = -(-caps.replay_capacity // max(caps.dp, 1))
+        leaves = 1 << (per_shard - 1).bit_length()  # next pow2
+        if leaves > PALLAS_TREE_MAX_LEAVES:
+            gap(
+                "pallas_tree_too_many_leaves",
+                f"--device-tree-backend pallas / --fused-descent hold each "
+                f"shard's tree in VMEM, 4 bytes per leaf: {leaves} leaves "
+                f"per shard is over the limit of {PALLAS_TREE_MAX_LEAVES} "
+                "(2^24, 64 MiB — the largest that compiled and matched the "
+                "XLA descent on a TPU v5e, PR 21). Use "
+                "--device-tree-backend xla, a smaller --rmsize, or more "
+                "--dp shards",
             )
 
     # Double-buffered ingest staging: meaningful only where a DeviceRing

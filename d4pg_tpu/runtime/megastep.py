@@ -1,9 +1,8 @@
 """Fused training megastep over the device-resident replay ring.
 
-The host trainer's steady-state loop used to pay a full host→device batch
-upload and a device→host priority fetch per dispatch — ``BENCH_r04``
-measured the learner pinned at 9% MFU with the chip idling on exactly that
-traffic.  The megastep is the Podracer/Anakin answer (ROADMAP item 1): ONE
+The host trainer's steady-state loop pays a full host→device batch
+upload and a device→host priority fetch per dispatch, and the chip idles
+on exactly that traffic.  The megastep is the Podracer/Anakin answer: ONE
 donated-buffer jitted call runs ``lax.scan`` over K grad steps — batch
 gather from the HBM ring (``replay/device_ring.py``), the PR-1 fused
 Pallas projection+loss (when ``projection_backend="pallas_fused"``), both
@@ -44,6 +43,7 @@ import jax.numpy as jnp
 
 from d4pg_tpu.agent.d4pg import fused_train_scan, gather_batches, train_step
 from d4pg_tpu.agent.state import D4PGConfig, TrainState
+from d4pg_tpu.ops.pallas_mode import pallas_interpret
 from d4pg_tpu.replay.device_ring import DeviceRing
 
 
@@ -180,9 +180,9 @@ def make_megastep_uniform_sharded(
     transfers survive scale-out: state, ring, and key all live sharded on
     the mesh between dispatches, and the dispatch site runs under the
     same ``no_transfers`` budget as the single-device megastep."""
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from d4pg_tpu.parallel.compat import shard_map
     from d4pg_tpu.parallel.partition import (
         DEFAULT_RULES,
         _abstract_state,
@@ -342,8 +342,8 @@ def megastep_device_per_fused_body(
     Byte-parity with the separate-programs oracle is structural, not
     approximate (tests/test_fused_descent.py pins whole-TrainState + tree
     equality): same PRNG stream (split → fold_in(0) → stratified
-    prefixes), the descent tile is the standalone kernel's ``count_tile``
-    verbatim on the same leaves (exact int32), the IS weights are the
+    prefixes), the descent tile is the standalone kernel's ``descend_tile``
+    verbatim on the same tree (exact int32), the IS weights are the
     same elementwise formula on the same dispatch-start scalars
     (total/min_ratio/β), and the loss/backward tiles are the fused-loss
     kernel's own.
@@ -366,7 +366,7 @@ def megastep_device_per_fused_body(
         jax.random.fold_in(k_draw, jnp.int32(0)), k, batch, total
     )
     idx0 = jnp.clip(
-        find_prefix_pallas(leaves, pre[0], interpret=interpret),
+        find_prefix_pallas(sums_lane, pre[0], interpret=interpret),
         0, jnp.maximum(local_filled - 1, 0),
     )
     # Dispatch-start scalars, shared by every step's IS weights — exactly
@@ -385,7 +385,7 @@ def megastep_device_per_fused_body(
         batches = gather_batches(ring, idx_t)
         batches["weights"] = weights
         st, metrics, priorities, idx_raw = train_step(
-            config, st, batches, descent=(leaves, pre_next)
+            config, st, batches, descent=(sums_lane, pre_next)
         )
         idx_next = jnp.clip(idx_raw, 0, jnp.maximum(local_filled - 1, 0))
         return (st, idx_next), (metrics, priorities, idx_t)
@@ -406,10 +406,11 @@ def megastep_device_per_fused_body(
     )
 
 
-def _pallas_interpret() -> bool:
-    """Pallas kernels run the interpreter off-TPU (the CPU-test mode the
-    projection kernels use; d4pg.py:build sets the same switch)."""
-    return jax.default_backend() != "tpu"
+def _tree_interpret(tree_backend: str) -> bool:
+    """The descent's ``interpret`` flag: only the Pallas tier asks
+    :func:`pallas_interpret` (which raises off tpu/cpu); the XLA descent
+    never reads the flag and runs anywhere."""
+    return tree_backend == "pallas" and pallas_interpret()
 
 
 def make_megastep_device_per(
@@ -435,7 +436,7 @@ def make_megastep_device_per_fused(config: D4PGConfig, k: int, batch: int):
     from d4pg_tpu.replay.device_per import DevicePerTree
 
     body = partial(
-        megastep_device_per_fused_body, config, k, batch, _pallas_interpret()
+        megastep_device_per_fused_body, config, k, batch, pallas_interpret()
     )
 
     def lane(state, ring, tree, key):
@@ -455,7 +456,7 @@ def _device_per_lane_fn(config, k, b_local, n_shards, tree_backend):
 
     body = partial(
         megastep_device_per_body, config, k, b_local, n_shards,
-        tree_backend, _pallas_interpret(),
+        tree_backend, _tree_interpret(tree_backend),
     )
 
     def lane(state, ring, tree, key):
@@ -477,9 +478,9 @@ def make_megastep_device_per_sharded(
     ``match_partition_rules``, ring: ``RING_RULES``, tree:
     ``PER_TREE_RULES``). Same mesh constraints as the uniform sharded
     megastep (dp-only, divisible batch)."""
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from d4pg_tpu.parallel.compat import shard_map
     from d4pg_tpu.parallel.partition import (
         DEFAULT_RULES,
         _abstract_state,
@@ -565,7 +566,7 @@ def make_megastep_device_per_oracle(
 
     body = partial(
         megastep_device_per_body, config, k, batch // n_shards, n_shards,
-        tree_backend, _pallas_interpret(),
+        tree_backend, _tree_interpret(tree_backend),
     )
     lane_axes = DeviceRing(
         obs=0, action=0, reward=0, next_obs=0, discount=0, size=None

@@ -33,11 +33,11 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from d4pg_tpu.agent import TrainState
 from d4pg_tpu.agent.d4pg import fused_train_scan, gather_batches, make_noise
 from d4pg_tpu.agent.state import D4PGConfig
-from d4pg_tpu.parallel.compat import shard_map
 from d4pg_tpu.runtime.collect import make_segment_collector
 
 
@@ -494,9 +494,9 @@ def run_on_device(config, preempt_event=None) -> dict:
     last: dict = {}
     # --total-steps is a PER-INVOCATION budget, exactly like Trainer.train
     # (`while grad_steps_done < total`): a resumed leg runs `total_steps`
-    # MORE grad steps on top of the restored counter. Supervisors
-    # (runs/hc_supervisor.sh, docs/REMOTE_TPU.md) pass the remainder each
-    # leg; with a global interpretation a restored step >= the remainder
+    # MORE grad steps on top of the restored counter. Supervisor loops
+    # pass the remainder each leg; with a global interpretation a
+    # restored step >= the remainder
     # would make every leg eval-only and livelock the supervisor loop.
     total = grad_steps + config.total_steps
     t0 = time.monotonic()

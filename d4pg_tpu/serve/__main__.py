@@ -97,6 +97,9 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> None:
     args = build_parser().parse_args(argv)
+    from d4pg_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     if args.debug_guards:
         # Arm the lock-order witness BEFORE the server builds its locks;
         # drain() checks the recorded nesting against the committed graph,

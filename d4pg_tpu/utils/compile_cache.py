@@ -1,0 +1,42 @@
+"""Where XLA's persistent compilation cache lives — decided in one place.
+
+Every entry point that compiles (``train.py``, ``bench.py``,
+``chip_smoke.py``, ``__graft_entry__.py``, ``python -m d4pg_tpu.serve``)
+calls :func:`configure_compile_cache` first thing. Without it each process
+recompiles the planar physics, the megastep and the eval rollout cold —
+the largest share of a short run on a freshly provisioned machine.
+
+The directory is part of the cache key's environment, so it must not move:
+no pid, timestamp or tempdir in the path.
+"""
+
+from __future__ import annotations
+
+import os
+
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+
+def default_cache_dir() -> str:
+    """``<checkout>/.jax_cache`` — next to the ``d4pg_tpu`` package,
+    gitignored."""
+    package_dir = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(package_dir), ".jax_cache")
+
+
+def configure_compile_cache() -> str:
+    """Point JAX at the persistent compilation cache and return the
+    directory in use.
+
+    An exported ``JAX_COMPILATION_CACHE_DIR`` wins and nothing is set in
+    code (JAX reads the variable itself; the operator's placement —
+    e.g. a volume that outlives the machine — is the whole point).
+    Otherwise the cache goes to :func:`default_cache_dir`."""
+    exported = os.environ.get(ENV_VAR)
+    if exported:
+        return exported
+    import jax
+
+    path = default_cache_dir()
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

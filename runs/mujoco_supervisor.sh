@@ -5,7 +5,8 @@
 # Single critic by default: the round-4 Hopper comparison showed clipped
 # double-Q's pessimism suppresses the optimistic Q that discovers hop/
 # gait cycles on real contacts (twin best 1,030 vs single 3,558 —
-# runs/hopper_mujoco_tpu/NOTES.md). Pass --twin-critic via EXTRA args
+# runs/hopper_mujoco_tpu_twin vs runs/hopper_mujoco_tpu). Pass
+# --twin-critic via EXTRA args
 # for the ablation arm.
 # Usage: bash runs/mujoco_supervisor.sh ENV DIR [TOTAL_STEPS] [EXTRA...]
 #   e.g. bash runs/mujoco_supervisor.sh Hopper-v5 runs/hopper_mujoco_tpu

@@ -13,8 +13,8 @@ Covers the oracle contracts the tentpole rests on:
   determinism contract (PR 1 changed it once; this pins it);
 - ``BatchedNStepWriter`` emits exactly what N sequential ``NStepWriter``s
   emit;
-- a fresh checkout rebuilds ``libsumtree.so`` from source instead of
-  loading a stale binary;
+- a foreign ``libsumtree*.so`` is never loaded: the binary is keyed on a
+  hash of the committed source, not on file mtimes;
 - ``StageTimers`` telemetry lands in a training run's metrics.jsonl.
 """
 
@@ -227,24 +227,31 @@ def test_auto_backend_falls_back_to_numpy_without_gcc(monkeypatch):
 
 
 @needs_native
-def test_fresh_checkout_rebuilds_stale_so(tmp_path, monkeypatch):
-    """A clean checkout can leave libsumtree.so with mtime == source (or a
-    foreign/corrupt binary entirely): load_library must REBUILD from source
-    rather than dlopen the stale file — dlopening this garbage would raise."""
+def test_foreign_binary_is_never_loaded(tmp_path, monkeypatch):
+    """The build dir is gitignored and travels with directory copies, so a
+    binary found there proves nothing about the committed source — not even
+    with a NEWER mtime (the old staleness test). The library is keyed on a
+    hash of ``sumtree.cpp``: a foreign file is ignored and the source is
+    compiled; a changed source resolves to a different binary."""
     src = tmp_path / "sumtree.cpp"
     shutil.copy(native._source_path(), src)
     bdir = tmp_path / "build"
     bdir.mkdir()
-    so = bdir / "libsumtree.so"
-    so.write_bytes(b"definitely not an ELF shared object")
-    t = os.stat(src).st_mtime
-    os.utime(so, (t, t))  # equal mtimes — the fresh-checkout signature
+    foreign = bdir / "libsumtree.so"
+    foreign.write_bytes(b"definitely not an ELF shared object")
+    t = os.stat(src).st_mtime + 3600
+    os.utime(foreign, (t, t))  # newer than the source: mtime would trust it
     monkeypatch.setattr(native, "_source_path", lambda: str(src))
     monkeypatch.setattr(native, "_build_dir", lambda: str(bdir))
     monkeypatch.setattr(native, "_LIB", None)  # restored after the test
     lib = native.load_library()
     assert lib.st_root is not None
-    assert so.stat().st_size > 1000  # the garbage file was replaced
+    built = native.library_path()
+    assert os.path.getsize(built) > 1000
+    assert foreign.read_bytes().startswith(b"definitely")  # left untouched
+    with open(src, "a") as f:
+        f.write("\n// edited\n")
+    assert native.library_path() != built
 
 
 class TestBatchedNStepWriter:

@@ -3,7 +3,7 @@ ring ingest, and the large-batch (``--batch-scale``) recipe.
 
 The fused tier's contract is BYTE parity, not tolerance: the one-program
 scan body (ops/pallas_fused_step.py) computes its loss tile and descent
-tile with the literal ``loss_tile``/``count_tile`` functions the
+tile with the literal ``loss_tile``/``descend_tile`` functions the
 separate-programs oracle runs, on identical inputs, with the identical
 backward program — so fused-vs-oracle equality is structural and these
 tests pin it end to end (kernel outputs, gradients, whole TrainState +
@@ -58,12 +58,13 @@ def _kernel_inputs(B=40, A=11, L=300, seed=0):
     p = jax.nn.softmax(jnp.asarray(r.normal(size=(B, A)).astype(np.float32)))
     rew = jnp.asarray(r.uniform(-1, 0, B).astype(np.float32))
     disc = jnp.asarray(r.uniform(0, 0.99, B).astype(np.float32))
-    leaves = jnp.asarray(r.uniform(0.1, 2.0, L).astype(np.float32))
+    lane = dper.tree_from_priorities(
+        r.uniform(0.1, 2.0, L).astype(np.float32), L
+    ).sums[0]
     pre = jnp.asarray(
-        r.uniform(0, float(np.sum(np.asarray(leaves))) * 0.999, B)
-        .astype(np.float32)
+        r.uniform(0, float(lane[1]) * 0.999, B).astype(np.float32)
     )
-    return q, p, rew, disc, pre, leaves
+    return q, p, rew, disc, pre, lane
 
 
 class TestFusedStepKernel:
@@ -73,23 +74,26 @@ class TestFusedStepKernel:
         """ce/overlap match fused_categorical_loss and the descent matches
         find_prefix_pallas — all to the BYTE (the fused kernel runs the
         same tile functions on the same operands)."""
-        q, p, rew, disc, pre, leaves = _kernel_inputs()
+        q, p, rew, disc, pre, lane = _kernel_inputs()
         ce_f, ov_f, idx_f = fused_categorical_loss_descent(
-            self.SUP, q, p, rew, disc, pre, leaves, interpret=True
+            self.SUP, q, p, rew, disc, pre, lane, interpret=True
         )
         ce_s, ov_s = fused_categorical_loss(
             self.SUP, q, p, rew, disc, interpret=True
         )
-        idx_s = find_prefix_pallas(leaves, pre, interpret=True)
+        idx_s = find_prefix_pallas(lane, pre, interpret=True)
         assert np.asarray(ce_f).tobytes() == np.asarray(ce_s).tobytes()
         assert np.asarray(ov_f).tobytes() == np.asarray(ov_s).tobytes()
         np.testing.assert_array_equal(np.asarray(idx_f), np.asarray(idx_s))
+        np.testing.assert_array_equal(
+            np.asarray(idx_f), np.asarray(dper.descend_prefix(lane, pre))
+        )
         assert np.asarray(idx_f).dtype == np.int32
 
     def test_gradients_byte_identical(self):
         """Both tiers share _fused_loss_grad_kernel, so an IS-weighted
         loss gradient through either is the same bytes."""
-        q, p, rew, disc, pre, leaves = _kernel_inputs(seed=1)
+        q, p, rew, disc, pre, lane = _kernel_inputs(seed=1)
         w = jnp.asarray(
             np.random.default_rng(2).uniform(0.2, 1.0, q.shape[0])
             .astype(np.float32)
@@ -97,7 +101,7 @@ class TestFusedStepKernel:
 
         def loss_fused(qq):
             ce, ov, _idx = fused_categorical_loss_descent(
-                self.SUP, qq, p, rew, disc, pre, leaves, interpret=True
+                self.SUP, qq, p, rew, disc, pre, lane, interpret=True
             )
             return jnp.sum(ce * w) + 0.5 * jnp.sum(ov * w)
 

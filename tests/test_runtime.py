@@ -75,8 +75,8 @@ def test_trainer_uniform_replay_mode(tmp_path):
 
 @pytest.mark.slow  # compile-heavy (conftest fast-tier budget)
 def test_trainer_bf16_transfer_staging(tmp_path):
-    """--transfer-dtype bfloat16 (the wide-obs link-bandwidth rung,
-    docs/REMOTE_TPU.md): obs go over the wire as bf16 and are restored to
+    """--transfer-dtype bfloat16 (half the host→device bytes for wide
+    observations): obs go over the wire as bf16 and are restored to
     f32 in-jit — training must stay finite and the staged arrays must
     actually be 2 bytes/element."""
     import ml_dtypes

@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import numpy as np
 import pytest
+from conftest import assert_sharded_parity
 
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
@@ -177,8 +178,11 @@ class TestShardedMegastepParity:
         for _ in range(3):
             st_m, key_m, met_m = mega(st_m, ring, key_m)
             st_o, key_o, met_o = oracle(st_o, lanes, key_o)
-        # the WHOLE TrainState: params, targets, both Adam moment sets
-        assert _leaves_equal(st_m, st_o)
+        # the WHOLE TrainState: params, targets, both Adam moment sets —
+        # step and key exact, floats to a few ulp (see the helper for why
+        # not bytes); the draw stream and the loss stay exact
+        assert_sharded_parity(st_m, st_o)
+        assert np.array_equal(np.asarray(key_m), np.asarray(key_o))
         assert np.asarray(met_m["critic_loss"]) == np.asarray(
             met_o["critic_loss"]
         )
@@ -206,7 +210,8 @@ class TestShardedMegastepParity:
         for _ in range(2):
             st_m, key_m, _ = mega(st_m, ring, key_m)
             st_o, key_o, _ = oracle(st_o, lanes, key_o)
-        assert _leaves_equal(st_m, st_o)
+        assert_sharded_parity(st_m, st_o)
+        assert np.array_equal(np.asarray(key_m), np.asarray(key_o))
 
     def test_different_keys_diverge(self):
         """Sanity: the parity comparison is not vacuous."""

@@ -208,7 +208,10 @@ MEGASTEP_FUNCTIONS = (
     "d4pg_tpu/replay/device_per.py::tree_ingest_lane_body",
     # The Pallas descent kernel and its wrapper trace into the megastep
     # when device_tree_backend="pallas".
-    "d4pg_tpu/ops/pallas_tree.py::_count_kernel",
+    "d4pg_tpu/ops/pallas_tree.py::_descend_kernel",
+    "d4pg_tpu/ops/pallas_tree.py::descend_tile",
+    "d4pg_tpu/ops/pallas_tree.py::_pick_rows",
+    "d4pg_tpu/ops/pallas_tree.py::left_rows",
     "d4pg_tpu/ops/pallas_tree.py::find_prefix_pallas",
     # The sharded megastep's deterministic cross-shard combine: traced
     # into every sharded dispatch, so a host coercion here would smuggle
