@@ -37,6 +37,7 @@ from d4pg_tpu.ops import (
     polyak_update,
 )
 from d4pg_tpu.models.critic import mixture_gaussian_mean
+from d4pg_tpu.utils.profiling import phase
 
 
 def _dtype(config: D4PGConfig):
@@ -314,11 +315,12 @@ def train_step(
         )
 
     def _sync(tree):
-        if sync_fn is not None:
-            return sync_fn(tree)
-        if axis_name is None:
-            return tree
-        return jax.lax.pmean(tree, axis_name)
+        with phase("parallel.sync"):
+            if sync_fn is not None:
+                return sync_fn(tree)
+            if axis_name is None:
+                return tree
+            return jax.lax.pmean(tree, axis_name)
 
     actor, critic = build_networks(config)
     actor_opt, critic_opt = make_optimizers(config)
@@ -345,8 +347,9 @@ def train_step(
                 tree,
             )
 
-        tgt_actor_params = _to_bf16(tgt_actor_params)
-        tgt_critic_params = _to_bf16(tgt_critic_params)
+        with phase("agent.networks"):
+            tgt_actor_params = _to_bf16(tgt_actor_params)
+            tgt_critic_params = _to_bf16(tgt_critic_params)
     weights = batch.get("weights")
     if weights is None:
         weights = jnp.ones_like(batch["reward"])
@@ -370,43 +373,44 @@ def train_step(
         )
 
     # ---- target: y = Φ(r + γ_eff · Z_target(s', μ_target(s'))) ----
-    next_action = actor.apply(tgt_actor_params, batch["next_obs"])
-    if config.critic_ensemble:
-        # REDQ in-target minimization, distributionally: back up whichever
-        # member of a per-step RANDOM SUBSET of M target critics has the
-        # smallest expected value, per sample — the whole distribution of
-        # the argmin member, same rationale as the twin branch below
-        # (an elementwise min of probs would not be a distribution).
-        E = config.critic_ensemble
-        M = config.ensemble_min_targets
-        heads = jax.vmap(
-            lambda p: critic.apply(p, batch["next_obs"], next_action)
-        )(tgt_critic_params)                                    # [E, B, H]
-        vals = jax.vmap(lambda h: _critic_value(config, support, h))(heads)
-        k_subset, new_key = jax.random.split(new_key)
-        subset = jax.random.permutation(k_subset, E)[:M]        # [M]
-        sub_vals = vals[subset]                                 # [M, B]
-        sub_heads = heads[subset]                               # [M, B, H]
-        which = jnp.argmin(sub_vals, axis=0)                    # [B]
-        target_head = jnp.take_along_axis(
-            sub_heads, which[None, :, None], axis=0
-        )[0]                                                    # [B, H]
-    elif config.twin_critic:
-        # Clipped double-Q, distributionally: back up whichever target
-        # critic's WHOLE distribution has the smaller mean, per sample —
-        # the distributional analogue of TD3's min(Q1, Q2) (taking an
-        # elementwise min of probs would not be a distribution).
-        heads = jax.vmap(
-            lambda p: critic.apply(p, batch["next_obs"], next_action)
-        )(tgt_critic_params)
-        vals = jax.vmap(lambda h: _critic_value(config, support, h))(heads)
-        target_head = jnp.where(
-            (vals[0] <= vals[1])[..., None], heads[0], heads[1]
-        )
-    else:
-        target_head = critic.apply(
-            tgt_critic_params, batch["next_obs"], next_action
-        )
+    with phase("agent.networks"):
+        next_action = actor.apply(tgt_actor_params, batch["next_obs"])
+        if config.critic_ensemble:
+            # REDQ in-target minimization, distributionally: back up whichever
+            # member of a per-step RANDOM SUBSET of M target critics has the
+            # smallest expected value, per sample — the whole distribution of
+            # the argmin member, same rationale as the twin branch below
+            # (an elementwise min of probs would not be a distribution).
+            E = config.critic_ensemble
+            M = config.ensemble_min_targets
+            heads = jax.vmap(
+                lambda p: critic.apply(p, batch["next_obs"], next_action)
+            )(tgt_critic_params)                                    # [E, B, H]
+            vals = jax.vmap(lambda h: _critic_value(config, support, h))(heads)
+            k_subset, new_key = jax.random.split(new_key)
+            subset = jax.random.permutation(k_subset, E)[:M]        # [M]
+            sub_vals = vals[subset]                                 # [M, B]
+            sub_heads = heads[subset]                               # [M, B, H]
+            which = jnp.argmin(sub_vals, axis=0)                    # [B]
+            target_head = jnp.take_along_axis(
+                sub_heads, which[None, :, None], axis=0
+            )[0]                                                    # [B, H]
+        elif config.twin_critic:
+            # Clipped double-Q, distributionally: back up whichever target
+            # critic's WHOLE distribution has the smaller mean, per sample —
+            # the distributional analogue of TD3's min(Q1, Q2) (taking an
+            # elementwise min of probs would not be a distribution).
+            heads = jax.vmap(
+                lambda p: critic.apply(p, batch["next_obs"], next_action)
+            )(tgt_critic_params)
+            vals = jax.vmap(lambda h: _critic_value(config, support, h))(heads)
+            target_head = jnp.where(
+                (vals[0] <= vals[1])[..., None], heads[0], heads[1]
+            )
+        else:
+            target_head = critic.apply(
+                tgt_critic_params, batch["next_obs"], next_action
+            )
 
     if config.dist.kind == "categorical":
         # Atom-layout audit: every per-atom op below (softmax, projection,
@@ -414,7 +418,8 @@ def train_step(
         # tensor — atoms live in the 128-lane dimension, so the critic-head
         # "gathers" are contiguous lane reads, never a strided HBM walk.
         # Keep it that way: any new head-side op must put atoms last.
-        target_probs = jax.nn.softmax(target_head, axis=-1)
+        with phase("ops.projection_loss"):
+            target_probs = jax.nn.softmax(target_head, axis=-1)
         if config.projection_backend == "pallas_fused":
             # Projection + log-softmax CE + IS/priority signals in ONE
             # Pallas kernel: the projected target distribution is never
@@ -428,34 +433,35 @@ def train_step(
 
             def critic_loss_fn(critic_params):
                 pred = critic.apply(critic_params, batch["obs"], batch["action"])
-                if descent is not None:
-                    from d4pg_tpu.ops.pallas_fused_step import (
-                        fused_categorical_loss_descent,
-                    )
+                with phase("ops.projection_loss"):
+                    if descent is not None:
+                        from d4pg_tpu.ops.pallas_fused_step import (
+                            fused_categorical_loss_descent,
+                        )
 
-                    sums_lane, next_prefixes = descent
-                    ce, overlap, next_idx = fused_categorical_loss_descent(
-                        support,
-                        pred,
-                        fused_target_probs,
-                        batch["reward"],
-                        batch["discount"],
-                        next_prefixes,
-                        sums_lane,
-                        interpret,
-                    )
-                else:
-                    next_idx = None
-                    ce, overlap = fused_categorical_loss(
-                        support,
-                        pred,
-                        fused_target_probs,
-                        batch["reward"],
-                        batch["discount"],
-                        interpret,
-                    )
-                # f32 weighted reduction on [B] vectors — byte-trivial.
-                loss = jnp.mean(weights * ce)
+                        sums_lane, next_prefixes = descent
+                        ce, overlap, next_idx = fused_categorical_loss_descent(
+                            support,
+                            pred,
+                            fused_target_probs,
+                            batch["reward"],
+                            batch["discount"],
+                            next_prefixes,
+                            sums_lane,
+                            interpret,
+                        )
+                    else:
+                        next_idx = None
+                        ce, overlap = fused_categorical_loss(
+                            support,
+                            pred,
+                            fused_target_probs,
+                            batch["reward"],
+                            batch["discount"],
+                            interpret,
+                        )
+                    # f32 weighted reduction on [B] vectors — byte-trivial.
+                    loss = jnp.mean(weights * ce)
                 per_sample = (
                     overlap if config.priority_kind == "overlap" else ce
                 )
@@ -467,41 +473,49 @@ def train_step(
             from d4pg_tpu.ops.pallas_mode import pallas_interpret
             from d4pg_tpu.ops.pallas_projection import categorical_projection_pallas
 
-            proj = categorical_projection_pallas(
-                support,
-                target_probs,
-                batch["reward"],
-                batch["discount"],
-                pallas_interpret(),
-            )
+            with phase("ops.projection_loss"):
+                proj = categorical_projection_pallas(
+                    support,
+                    target_probs,
+                    batch["reward"],
+                    batch["discount"],
+                    pallas_interpret(),
+                )
         else:
-            proj = categorical_projection(
-                support, target_probs, batch["reward"], batch["discount"]
-            )
+            with phase("ops.projection_loss"):
+                proj = categorical_projection(
+                    support, target_probs, batch["reward"], batch["discount"]
+                )
         if config.projection_backend != "pallas_fused":
             proj = jax.lax.stop_gradient(proj)
 
             def critic_loss_fn(critic_params):
                 pred = critic.apply(critic_params, batch["obs"], batch["action"])
-                loss, per_sample_ce = categorical_td_loss(pred, proj, weights)
-                if config.priority_kind == "overlap":
-                    # Reference-compatible surrogate |−Σ m·p| (ddpg.py:220-222).
-                    per_sample = jnp.abs(
-                        -jnp.sum(proj * jax.nn.softmax(pred, axis=-1), axis=-1)
-                    )
-                else:
-                    per_sample = per_sample_ce
+                with phase("ops.projection_loss"):
+                    loss, per_sample_ce = categorical_td_loss(pred, proj, weights)
+                    if config.priority_kind == "overlap":
+                        # Reference-compatible surrogate |−Σ m·p|
+                        # (ddpg.py:220-222).
+                        per_sample = jnp.abs(
+                            -jnp.sum(
+                                proj * jax.nn.softmax(pred, axis=-1), axis=-1
+                            )
+                        )
+                    else:
+                        per_sample = per_sample_ce
                 return loss, per_sample
     elif config.dist.kind == "scalar":
         # Plain DDPG TD(0)/TD(n) target (BASELINE.json config 1).
-        y = batch["reward"] + batch["discount"] * target_head[..., 0]
-        y = jax.lax.stop_gradient(y)
+        with phase("ops.projection_loss"):
+            y = batch["reward"] + batch["discount"] * target_head[..., 0]
+            y = jax.lax.stop_gradient(y)
 
         def critic_loss_fn(critic_params):
             pred = critic.apply(critic_params, batch["obs"], batch["action"])[..., 0]
-            td = pred - y
-            loss = jnp.mean(weights * jnp.square(td))
-            return loss, jnp.abs(td)
+            with phase("ops.projection_loss"):
+                td = pred - y
+                loss = jnp.mean(weights * jnp.square(td))
+                return loss, jnp.abs(td)
     elif config.dist.kind == "mixture_gaussian":
         # TRUE distributional MoG Bellman backup (the D4PG paper's
         # alternative head; reference declares but never implements it,
@@ -517,22 +531,25 @@ def train_step(
         from d4pg_tpu.ops.mog import mog_bellman_targets, mog_cross_entropy
 
         M = config.dist.num_mixtures
-        y_nodes, node_w = mog_bellman_targets(
-            target_head, batch["reward"], batch["discount"], M,
-            config.dist.quadrature_points,
-        )
-        # Scalar TD magnitude for PER priorities (the CE of a continuous
-        # density can be negative, which scrambles |·|-based rankings).
-        y_mean = batch["reward"] + batch["discount"] * _critic_value(
-            config, support, target_head
-        )
-        y_mean = jax.lax.stop_gradient(y_mean)
+        with phase("ops.projection_loss"):
+            y_nodes, node_w = mog_bellman_targets(
+                target_head, batch["reward"], batch["discount"], M,
+                config.dist.quadrature_points,
+            )
+            # Scalar TD magnitude for PER priorities (the CE of a
+            # continuous density can be negative, which scrambles
+            # |·|-based rankings).
+            y_mean = batch["reward"] + batch["discount"] * _critic_value(
+                config, support, target_head
+            )
+            y_mean = jax.lax.stop_gradient(y_mean)
 
         def critic_loss_fn(critic_params):
             head = critic.apply(critic_params, batch["obs"], batch["action"])
-            ce = mog_cross_entropy(head, y_nodes, node_w, M)
-            td = jnp.abs(y_mean - mixture_gaussian_mean(head, M))
-            return jnp.mean(weights * ce), td
+            with phase("ops.projection_loss"):
+                ce = mog_cross_entropy(head, y_nodes, node_w, M)
+                td = jnp.abs(y_mean - mixture_gaussian_mean(head, M))
+                return jnp.mean(weights * ce), td
     else:
         raise ValueError(config.dist.kind)
 
@@ -554,18 +571,22 @@ def train_step(
                 )
             return jnp.sum(losses), jnp.mean(per_sample, axis=0)
 
-    (critic_loss, loss_aux), critic_grads = jax.value_and_grad(
-        critic_loss_fn, has_aux=True
-    )(state.critic_params)
+    with phase("agent.networks"):
+        (critic_loss, loss_aux), critic_grads = jax.value_and_grad(
+            critic_loss_fn, has_aux=True
+        )(state.critic_params)
     if descent is not None:
         priorities, descent_idx = loss_aux
     else:
         priorities = loss_aux
     critic_grads = _sync(critic_grads)
-    critic_updates, critic_opt_state = critic_opt.update(
-        critic_grads, state.critic_opt_state
-    )
-    critic_params = optax.apply_updates(state.critic_params, critic_updates)
+    with phase("agent.optimizer"):
+        critic_updates, critic_opt_state = critic_opt.update(
+            critic_grads, state.critic_opt_state
+        )
+        critic_params = optax.apply_updates(
+            state.critic_params, critic_updates
+        )
 
     # ---- actor: maximize E[Q(s, μ(s))] against the UPDATED critic ----
     # (critic 0 under twin critics — TD3 convention; the ensemble-MEAN
@@ -602,27 +623,31 @@ def train_step(
         # must stay comparable across action_l2 settings.
         return loss, q_mean
 
-    (actor_loss, batch_q_mean), actor_grads = jax.value_and_grad(
-        actor_loss_fn, has_aux=True
-    )(state.actor_params)
+    with phase("agent.networks"):
+        (actor_loss, batch_q_mean), actor_grads = jax.value_and_grad(
+            actor_loss_fn, has_aux=True
+        )(state.actor_params)
     actor_grads = _sync(actor_grads)
-    actor_updates, actor_opt_state = actor_opt.update(
-        actor_grads, state.actor_opt_state
-    )
-    actor_params = optax.apply_updates(state.actor_params, actor_updates)
+    with phase("agent.optimizer"):
+        actor_updates, actor_opt_state = actor_opt.update(
+            actor_grads, state.actor_opt_state
+        )
+        actor_params = optax.apply_updates(state.actor_params, actor_updates)
 
-    # ---- Polyak target updates (reference ddpg.py:250 → 110-116) ----
+        # ---- Polyak target updates (reference ddpg.py:250 → 110-116) ----
+        target_actor_params = polyak_update(
+            state.target_actor_params, actor_params, config.tau
+        )
+        target_critic_params = polyak_update(
+            state.target_critic_params, critic_params, config.tau
+        )
     new_state = state.replace(
         step=state.step + 1,
         key=new_key,
         actor_params=actor_params,
         critic_params=critic_params,
-        target_actor_params=polyak_update(
-            state.target_actor_params, actor_params, config.tau
-        ),
-        target_critic_params=polyak_update(
-            state.target_critic_params, critic_params, config.tau
-        ),
+        target_actor_params=target_actor_params,
+        target_critic_params=target_critic_params,
         actor_opt_state=actor_opt_state,
         critic_opt_state=critic_opt_state,
     )
@@ -668,11 +693,12 @@ def gather_batches(store, idx: jax.Array) -> dict:
     pool) in ONE op per field. Doing this before the train scan instead of
     per-step inside it measured ~2.2x on v5e (per-step RBG PRNG + scattered
     HBM reads dominate otherwise)."""
-    batches = {
-        k: getattr(store, k)[idx] if not isinstance(store, dict) else store[k][idx]
-        for k in ("obs", "action", "reward", "next_obs", "discount")
-    }
-    batches["weights"] = jnp.ones(idx.shape, jnp.float32)
+    with phase("replay.row_gather"):
+        batches = {
+            k: getattr(store, k)[idx] if not isinstance(store, dict) else store[k][idx]
+            for k in ("obs", "action", "reward", "next_obs", "discount")
+        }
+        batches["weights"] = jnp.ones(idx.shape, jnp.float32)
     return batches
 
 

@@ -45,6 +45,7 @@ from d4pg_tpu.agent.d4pg import fused_train_scan, gather_batches, train_step
 from d4pg_tpu.agent.state import D4PGConfig, TrainState
 from d4pg_tpu.ops.pallas_mode import pallas_interpret
 from d4pg_tpu.replay.device_ring import DeviceRing
+from d4pg_tpu.utils.profiling import phase
 
 
 def draw_uniform_indices(key: jax.Array, k: int, batch: int,
@@ -64,8 +65,9 @@ def megastep_uniform_body(
     Returns ``(state, key', metrics)`` — all device-resident; ``key'`` is
     the split-forward key the trainer threads into the next dispatch, so
     steady state needs no host operand whatsoever."""
-    key, k_idx = jax.random.split(key)
-    idx = draw_uniform_indices(k_idx, k, batch, ring.size)
+    with phase("replay.draw"):
+        key, k_idx = jax.random.split(key)
+        idx = draw_uniform_indices(k_idx, k, batch, ring.size)
     batches = gather_batches(ring, idx)
     # Determinism contract (tests/test_megastep.py pins it): uniform IS
     # weights are identically 1, so leave the key OUT and let train_step's
@@ -147,12 +149,13 @@ def sharded_megastep_uniform_body(
     batch is the concatenation of the shard batches — B = b_local · dp —
     and the returned key threads forward exactly like the unsharded body.
     """
-    shard = jax.lax.axis_index("dp")
-    key, k_idx = jax.random.split(key)
-    local_n = ring.size // n_shards
-    idx = jax.random.randint(
-        jax.random.fold_in(k_idx, shard), (k, b_local), 0, local_n
-    )
+    with phase("replay.draw"):
+        shard = jax.lax.axis_index("dp")
+        key, k_idx = jax.random.split(key)
+        local_n = ring.size // n_shards
+        idx = jax.random.randint(
+            jax.random.fold_in(k_idx, shard), (k, b_local), 0, local_n
+        )
     batches = gather_batches(ring, idx)
     # Same determinism contract as megastep_uniform_body: the uniform
     # path carries NO weights key on either side.
@@ -271,31 +274,35 @@ def megastep_device_per_body(
     """
     from d4pg_tpu.replay import device_per as dper
 
-    if n_shards > 1:
-        shard = jax.lax.axis_index("dp")
-    else:
-        shard = jnp.int32(0)
-    key, k_draw = jax.random.split(key)
-    # Shard-local fill count: striping lands host slot j on shard j % D,
-    # so shard d holds ceil((size - d) / D) mirrored rows (== size at D=1
-    # — the host _draw's size-1 clamp).
-    local_filled = (ring.size - shard + n_shards - 1) // n_shards
-    idx, p_leaf, total_local = dper.lane_draw(
-        sums_lane, jax.random.fold_in(k_draw, shard), k, b_local,
-        local_filled, tree_backend=tree_backend, interpret=interpret,
-    )
-    min_ratio = dper.lane_min_leaf(sums_lane) / (
-        jnp.float32(n_shards) * total_local
-    )
-    if n_shards > 1:
-        # Exact order-independent reduce over the gathered lane scalars
-        # (min is associative+commutative+exact in fp — no fixed-order
-        # unroll needed for bit-parity, unlike the gradient sum).
-        min_ratio = jnp.min(jax.lax.all_gather(min_ratio, "dp"))
-    beta = dper.beta_at(state.step, config.per_beta0, config.per_beta_steps)
-    weights = dper.importance_weights(
-        p_leaf, total_local, min_ratio, ring.size, n_shards, beta
-    )
+    with phase("replay.draw"):
+        if n_shards > 1:
+            shard = jax.lax.axis_index("dp")
+        else:
+            shard = jnp.int32(0)
+        key, k_draw = jax.random.split(key)
+        # Shard-local fill count: striping lands host slot j on shard
+        # j % D, so shard d holds ceil((size - d) / D) mirrored rows
+        # (== size at D=1 — the host _draw's size-1 clamp).
+        local_filled = (ring.size - shard + n_shards - 1) // n_shards
+        idx, p_leaf, total_local = dper.lane_draw(
+            sums_lane, jax.random.fold_in(k_draw, shard), k, b_local,
+            local_filled, tree_backend=tree_backend, interpret=interpret,
+        )
+        min_ratio = dper.lane_min_leaf(sums_lane) / (
+            jnp.float32(n_shards) * total_local
+        )
+        if n_shards > 1:
+            # Exact order-independent reduce over the gathered lane
+            # scalars (min is associative+commutative+exact in fp — no
+            # fixed-order unroll needed for bit-parity, unlike the
+            # gradient sum).
+            min_ratio = jnp.min(jax.lax.all_gather(min_ratio, "dp"))
+        beta = dper.beta_at(
+            state.step, config.per_beta0, config.per_beta_steps
+        )
+        weights = dper.importance_weights(
+            p_leaf, total_local, min_ratio, ring.size, n_shards, beta
+        )
     batches = gather_batches(ring, idx)
     batches["weights"] = weights
     if n_shards > 1:
@@ -307,13 +314,14 @@ def megastep_device_per_body(
     state, metrics, priorities = fused_train_scan(
         config, state, batches, sync_fn=sync
     )
-    sums_lane, mp_local = dper.write_back_lane(
-        sums_lane, idx, priorities, config.per_alpha, config.per_eps,
-        local_capacity=ring.obs.shape[0],
-    )
-    if n_shards > 1:
-        mp_local = jnp.max(jax.lax.all_gather(mp_local, "dp"))
-    max_priority = jnp.maximum(max_priority, mp_local)
+    with phase("replay.write_back"):
+        sums_lane, mp_local = dper.write_back_lane(
+            sums_lane, idx, priorities, config.per_alpha, config.per_eps,
+            local_capacity=ring.obs.shape[0],
+        )
+        if n_shards > 1:
+            mp_local = jnp.max(jax.lax.all_gather(mp_local, "dp"))
+        max_priority = jnp.maximum(max_priority, mp_local)
     return (
         state, sums_lane, max_priority, key,
         jax.tree.map(lambda x: x.mean(), metrics),
@@ -356,38 +364,45 @@ def megastep_device_per_fused_body(
     from d4pg_tpu.ops.pallas_tree import find_prefix_pallas
     from d4pg_tpu.replay import device_per as dper
 
-    key, k_draw = jax.random.split(key)
-    local_filled = ring.size  # n_shards == 1: the global fill count
-    half = sums_lane.shape[0] // 2
-    leaves = sums_lane[half:]
-    total = sums_lane[1]
-    # The oracle's exact draw stream: lane_draw(fold_in(k_draw, 0), ...).
-    pre = dper.stratified_prefixes(
-        jax.random.fold_in(k_draw, jnp.int32(0)), k, batch, total
-    )
-    idx0 = jnp.clip(
-        find_prefix_pallas(sums_lane, pre[0], interpret=interpret),
-        0, jnp.maximum(local_filled - 1, 0),
-    )
-    # Dispatch-start scalars, shared by every step's IS weights — exactly
-    # the separate-programs body's (one β per dispatch, state.step before
-    # the scan).
-    min_ratio = dper.lane_min_leaf(sums_lane) / (jnp.float32(1) * total)
-    beta = dper.beta_at(state.step, config.per_beta0, config.per_beta_steps)
+    with phase("replay.draw"):
+        key, k_draw = jax.random.split(key)
+        local_filled = ring.size  # n_shards == 1: the global fill count
+        half = sums_lane.shape[0] // 2
+        leaves = sums_lane[half:]
+        total = sums_lane[1]
+        # The oracle's exact draw stream: lane_draw(fold_in(k_draw, 0), ...).
+        pre = dper.stratified_prefixes(
+            jax.random.fold_in(k_draw, jnp.int32(0)), k, batch, total
+        )
+        idx0 = jnp.clip(
+            find_prefix_pallas(sums_lane, pre[0], interpret=interpret),
+            0, jnp.maximum(local_filled - 1, 0),
+        )
+        # Dispatch-start scalars, shared by every step's IS weights —
+        # exactly the separate-programs body's (one β per dispatch,
+        # state.step before the scan).
+        min_ratio = dper.lane_min_leaf(sums_lane) / (jnp.float32(1) * total)
+        beta = dper.beta_at(
+            state.step, config.per_beta0, config.per_beta_steps
+        )
 
     def body(carry, pre_next):
         st, idx_t = carry
-        weights = dper.importance_weights(
-            p_leaf=leaves[idx_t], total_local=total,
-            min_ratio_global=min_ratio, n_global=ring.size, n_shards=1,
-            beta=beta,
-        )
+        with phase("replay.draw"):
+            weights = dper.importance_weights(
+                p_leaf=leaves[idx_t], total_local=total,
+                min_ratio_global=min_ratio, n_global=ring.size, n_shards=1,
+                beta=beta,
+            )
         batches = gather_batches(ring, idx_t)
         batches["weights"] = weights
+        # the NEXT step's descent runs inside this step's fused loss
+        # program, so its time is booked to ops.projection_loss
         st, metrics, priorities, idx_raw = train_step(
             config, st, batches, descent=(sums_lane, pre_next)
         )
-        idx_next = jnp.clip(idx_raw, 0, jnp.maximum(local_filled - 1, 0))
+        with phase("replay.draw"):
+            idx_next = jnp.clip(idx_raw, 0, jnp.maximum(local_filled - 1, 0))
         return (st, idx_next), (metrics, priorities, idx_t)
 
     # xs[t] = pre[t+1]: step t descends the NEXT step's prefixes. The last
@@ -395,11 +410,12 @@ def megastep_device_per_fused_body(
     (state, _), (metrics, priorities, idx_all) = jax.lax.scan(
         body, (state, idx0), jnp.roll(pre, -1, axis=0)
     )
-    sums_lane, mp_local = dper.write_back_lane(
-        sums_lane, idx_all, priorities, config.per_alpha, config.per_eps,
-        local_capacity=ring.obs.shape[0],
-    )
-    max_priority = jnp.maximum(max_priority, mp_local)
+    with phase("replay.write_back"):
+        sums_lane, mp_local = dper.write_back_lane(
+            sums_lane, idx_all, priorities, config.per_alpha,
+            config.per_eps, local_capacity=ring.obs.shape[0],
+        )
+        max_priority = jnp.maximum(max_priority, mp_local)
     return (
         state, sums_lane, max_priority, key,
         jax.tree.map(lambda x: x.mean(), metrics),

@@ -71,7 +71,12 @@ from d4pg_tpu.runtime.checkpoint import (
 )
 from d4pg_tpu.runtime.evaluator import evaluate
 from d4pg_tpu.runtime.metrics import MetricsLogger, interval_crossed
-from d4pg_tpu.utils.profiling import StageTimers, annotate
+from d4pg_tpu.utils.profiling import (
+    StageTimers,
+    annotate,
+    start_trace,
+    stop_trace,
+)
 from d4pg_tpu.analysis import lockwitness
 
 
@@ -2089,10 +2094,10 @@ class Trainer:
                     and not tracing
                     and grad_steps_done >= 10
                 ):
-                    jax.profiler.start_trace(cfg.profile_dir)
+                    start_trace(cfg.profile_dir)
                     tracing = True
                 if tracing and grad_steps_done >= max(60, 10 + K):
-                    jax.profiler.stop_trace()
+                    stop_trace()
                     tracing = False
                     profiled = True
                 if cfg.async_collect:
@@ -2280,7 +2285,7 @@ class Trainer:
             raise
         finally:
             if tracing:
-                jax.profiler.stop_trace()
+                stop_trace()
             if cfg.async_collect:
                 self._stop_collector()
             try:

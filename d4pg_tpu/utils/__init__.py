@@ -12,7 +12,6 @@ from d4pg_tpu._lazy import lazy_exports
 
 _EXPORTS = {
     "annotate": "d4pg_tpu.utils.profiling",
-    "profile_trace": "d4pg_tpu.utils.profiling",
     # matplotlib-adjacent, kept off the training path
     "compare_runs": "d4pg_tpu.utils.plotting",
     "ewma": "d4pg_tpu.utils.plotting",

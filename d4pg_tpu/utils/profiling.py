@@ -3,8 +3,13 @@
 The reference's only timing is wall-clock deltas in train logs
 (``main.py:250,359``; SURVEY.md §5 'tracing/profiling'). Here:
 
-- :func:`profile_trace` captures a TensorBoard-viewable XLA trace (HLO
-  timelines, per-op device time) for a bounded window;
+- :func:`start_trace` / :func:`stop_trace` are the one place the program
+  starts the profiler (``Trainer``'s ``--profile-dir``): an XProf-viewable
+  trace (HLO timelines, per-op device time) with the Python tracer off;
+- :func:`phase` names a phase of the megastep on the DEVICE timeline: a
+  ``jax.named_scope`` whose token lands in the ``op_name`` of every HLO
+  instruction traced under it, so the trace can be cut by phase
+  (:data:`PHASES`; ``cellbench/scopes.py`` reads them back);
 - :func:`annotate` tags host-side phases (sample/dispatch/priority-writeback)
   so host stalls show up next to device ops in the trace viewer;
 - :class:`StageTimers` keeps cumulative wall-time counters per host
@@ -27,17 +32,46 @@ import jax
 from d4pg_tpu.analysis import lockwitness
 
 
-@contextlib.contextmanager
-def profile_trace(log_dir: str | None):
-    """Capture a jax.profiler trace into ``log_dir`` (no-op when None)."""
-    if not log_dir:
-        yield
-        return
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
+# The phases of one megastep dispatch, from draw to write-back. Each is one
+# ``jax.named_scope`` (opened through :func:`phase`, nowhere else) and is
+# read by exactly one per-layer metric of the benchmark, ``<name>_ms``
+# (PERF.md section 3 has the span -> metric table). A scope is HLO metadata:
+# it changes no instruction and costs nothing on the device.
+PHASES = (
+    "replay.draw",          # key split, stratified prefixes, descent, IS weights
+    "replay.row_gather",    # agent.d4pg.gather_batches
+    "replay.write_back",    # write_back_lane, max-priority reduce
+    "agent.networks",       # target forwards, both losses forward and backward
+    "ops.projection_loss",  # projection, cross-entropy, priority signal
+    "agent.optimizer",      # both Adam updates, both Polyak updates
+    "parallel.sync",        # train_step's _sync: det_pmean / pmean
+)
+# One path component of an instruction's ``op_name``, no "/" in it:
+# ``jit(lane)/while/body/closed_call/jvp(ph:agent.networks)/...``. An
+# instruction belongs to the LAST token in its path (innermost scope; the
+# backward pass carries the token inside ``transpose(jvp(...))``).
+PHASE_PREFIX = "ph:"
+
+
+def phase(name: str):
+    """``jax.named_scope`` of one of :data:`PHASES`."""
+    if name not in PHASES:
+        raise ValueError(f"unknown phase {name!r} (PHASES: {PHASES})")
+    return jax.named_scope(PHASE_PREFIX + name)
+
+
+def start_trace(log_dir: str) -> None:
+    """Start a jax.profiler trace into ``log_dir``, Python tracer off: it
+    records every Python call and slows the host loop it is meant to
+    observe. Host annotations (:func:`annotate`) and the device tracer —
+    where the :func:`phase` scopes show — stay on."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=options)
+
+
+def stop_trace() -> None:
+    jax.profiler.stop_trace()
 
 
 def annotate(name: str):
