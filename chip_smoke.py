@@ -547,7 +547,7 @@ def _check_sharding(trainer, sizes: Sizes, dp: int) -> dict:
 
     cap = trainer.config.replay_capacity
     out = {
-        "ring_devices": spread(ring.obs, cap // dp),
+        "ring_devices": spread(ring.reward, cap // dp),
         "tree_devices": spread(tree.sums, 1),
         "mesh_devices": sorted(
             d.id for d in trainer._mega_mesh.devices.flatten()

@@ -11,8 +11,12 @@ batch upload per grad step.
 
 Three pieces:
 
-- :class:`DeviceRing` — the transition fields as a ``[capacity, ...]``
-  pytree of device arrays plus a device-resident ``size`` scalar;
+- :class:`DeviceRing` — the transition fields as a pytree of device
+  arrays of ``capacity`` rows plus a device-resident ``size`` scalar. Wide
+  row fields are stored lane-dense, ``P`` rows to a storage row
+  (:func:`storage_shape`: the TPU's default layout of ``[capacity, 376]``
+  is feature-major, and XLA copied the whole array before every gather);
+  rows are read and written through ``rows`` / ``set_rows``;
 - :func:`ingest_body` / :func:`make_ingest` — the jit-compiled,
   donated-buffer chunk writer: a fixed-shape ``[chunk_cap, ...]`` chunk
   scatters into the ring at explicit slot indices (pad rows carry slot
@@ -45,6 +49,7 @@ combination loudly).
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -66,20 +71,168 @@ class _StagedChunk(NamedTuple):
     nbytes: int
 
 
-class DeviceRing(NamedTuple):
-    """Transition fields as device-resident ``[capacity, ...]`` arrays.
+def rows_per_storage_row(width: int) -> int:
+    """``P``: how many logical rows of ``width`` floats share one storage
+    row. The TPU's default layout for a ``[rows, width]`` array is decided
+    by the SHAPE alone: where row-major would pad the lane dimension (376 →
+    384) it puts the rows on the 128 lanes instead (feature-major), and XLA
+    then copies the whole array to row-major before every row gather.
+    ``P * width`` is the smallest multiple of 128, so ``[rows / P, P *
+    width]`` is row-major and unpadded by default. Narrow fields (< 128:
+    they gather from feature-major storage without a copy) and fields
+    already a multiple of 128 wide keep ``P`` = 1."""
+    if width < 128 or width % 128 == 0:
+        return 1
+    return 128 // math.gcd(width, 128)
+
+
+def storage_shape(shape: tuple) -> tuple:
+    """The stored shape of a ``[..., R, W]`` row field: ``[..., R // P,
+    P * W]`` — the plain row-major reshape, logical row ``r`` at storage
+    row ``r // P``, lanes ``(r % P) * W … + W``. ``R % P != 0`` (two-row
+    templates, odd toy capacities) stays ``[..., R, W]``: correct, only
+    slow. A stored shape is its own stored shape (``P * W % 128 == 0``)."""
+    shape = tuple(shape)
+    if len(shape) < 2:
+        return shape
+    p = rows_per_storage_row(shape[-1])
+    if shape[-2] % p:
+        return shape
+    return shape[:-2] + (shape[-2] // p, p * shape[-1])
+
+
+def _to_storage(value):
+    """Array-like field values (numpy, jax arrays, tracers) go to storage
+    form; what a DeviceRing-shaped pytree otherwise carries — PartitionSpecs,
+    shardings, vmap axes, ``None``, ShapeDtypeStructs (which come out of
+    ``eval_shape`` stored already) — passes through."""
+    reshape = getattr(value, "reshape", None)
+    if reshape is None:
+        return value
+    stored = storage_shape(value.shape)
+    return value if stored == tuple(value.shape) else reshape(stored)
+
+
+class _RingFields(NamedTuple):
+    obs: jax.Array        # [C/P, P*O] f32 (P = 1: [C, O])
+    action: jax.Array     # [C, A] f32
+    reward: jax.Array     # [C]    f32
+    next_obs: jax.Array   # [C/P, P*O] f32
+    discount: jax.Array   # [C]    f32
+    size: jax.Array       # scalar int32
+
+
+ROW_FIELDS = ("obs", "action", "reward", "next_obs", "discount")
+VECTOR_FIELDS = ("obs", "action", "next_obs")   # [..., C, W]; the others [..., C]
+
+
+class DeviceRing(_RingFields):
+    """Transition fields as device-resident arrays of ``capacity`` rows.
 
     Field names match the batch-dict keys every train path consumes, so
     :func:`d4pg_tpu.agent.d4pg.gather_batches` works on it directly.
     ``size`` is the filled-row count (int32 scalar, device-resident so the
-    megastep's in-kernel uniform draw needs no host operand)."""
+    megastep's in-kernel uniform draw needs no host operand).
 
-    obs: jax.Array        # [C, O] f32
-    action: jax.Array     # [C, A] f32
-    reward: jax.Array     # [C]    f32
-    next_obs: jax.Array   # [C, O] f32
-    discount: jax.Array   # [C]    f32
-    size: jax.Array       # scalar int32
+    A wide row field is STORED lane-dense, ``P`` logical rows to a storage
+    row (:func:`storage_shape`); construction brings ``[C, W]`` values to
+    that form, so it is idempotent and safe under pytree unflattening,
+    ``_replace`` and re-wrapping. ``ring.obs.shape[0]`` is therefore NOT
+    the capacity: read :attr:`capacity`, and rows through :meth:`rows` /
+    :meth:`set_rows`. The geometry is recovered from the ring's own
+    (local) shapes, so it holds under ``vmap`` and ``shard_map`` too."""
+
+    __slots__ = ()
+
+    def __new__(cls, obs, action, reward, next_obs, discount, size):
+        return super().__new__(
+            cls, _to_storage(obs), _to_storage(action), reward,
+            _to_storage(next_obs), discount, size,
+        )
+
+    @classmethod
+    def _make(cls, iterable):  # what ``_replace`` rebuilds through
+        return cls(*iterable)
+
+    @property
+    def capacity(self) -> int:
+        """Logical rows held (of this shard, inside ``shard_map``)."""
+        return self.reward.shape[-1]
+
+    def rows_packed(self, name: str) -> int:
+        """``P`` of one field: logical rows to a storage row, from the
+        ring's own shapes."""
+        if name not in VECTOR_FIELDS:   # a scalar a row: [..., C] as it is
+            return 1
+        return self.capacity // getattr(self, name).shape[-2]
+
+    def rows(self, name: str, idx: jax.Array) -> jax.Array:
+        """``logical[idx]`` of one field, bit for bit. A packed field
+        gathers the storage rows and selects each row's window from ``P``
+        static lane slices: exact, and the only form XLA:TPU compiles
+        without touching the whole store (one ``lax.gather`` with ``(row,
+        lane)`` start indices becomes a ``convert`` of all of it)."""
+        field = getattr(self, name)
+        p = self.rows_packed(name)
+        if p == 1:
+            return field[idx]
+        w = field.shape[-1] // p
+        block, sub = field[idx // p], (idx % p)[..., None]
+        out = block[..., :w]
+        for k in range(1, p):
+            out = jnp.where(sub == k, block[..., k * w:(k + 1) * w], out)
+        return out
+
+    def set_rows(self, chunk: dict, slots: jax.Array,
+                 new_size: jax.Array) -> "DeviceRing":
+        """``logical.at[slots].set(chunk[field], mode="drop")`` for every
+        row field (slot == capacity: a pad row, dropped), and the new fill
+        count. A packed field takes each row as a ``[1, W]`` window at
+        ``(slot // P, (slot % P) * W)`` — in place on a donated store."""
+        out = {}
+        for name in ROW_FIELDS:
+            field, p = getattr(self, name), self.rows_packed(name)
+            if p == 1:
+                out[name] = field.at[slots].set(chunk[name], mode="drop")
+                continue
+            w = field.shape[-1] // p
+            out[name] = jax.lax.scatter(
+                field,
+                jnp.stack([slots // p, (slots % p) * w], axis=-1),
+                chunk[name].astype(field.dtype),
+                jax.lax.ScatterDimensionNumbers(
+                    update_window_dims=(1,), inserted_window_dims=(0,),
+                    scatter_dims_to_operand_dims=(0, 1),
+                ),
+                mode=jax.lax.GatherScatterMode.FILL_OR_DROP,
+            )
+        return DeviceRing(size=new_size, **out)
+
+    def logical(self, name: str):
+        """The whole field as ``[..., capacity, W]``. On the device this is
+        the relayout the storage exists to avoid: for hosts (snapshots,
+        numpy copies) and tests only, never inside a dispatch."""
+        field, p = getattr(self, name), self.rows_packed(name)
+        if p == 1:
+            return field
+        return field.reshape(
+            field.shape[:-2] + (self.capacity, field.shape[-1] // p)
+        )
+
+    def describe_storage(self) -> dict:
+        """Per row field: logical width, ``P``, stored shape, bytes — what
+        the trainer logs once at start-up and the tests read to see that
+        the lane-dense form engaged."""
+        out = {}
+        for name in ROW_FIELDS:
+            field, p = getattr(self, name), self.rows_packed(name)
+            out[name] = {
+                "width": field.shape[-1] // p if name in VECTOR_FIELDS else 1,
+                "rows_per_storage_row": p,
+                "stored_shape": tuple(field.shape),
+                "bytes": math.prod(field.shape) * field.dtype.itemsize,
+            }
+        return out
 
 
 def device_ring_init(
@@ -95,11 +248,14 @@ def device_ring_init(
     # each dp shard owns capacity/dp rows, in the STRIPED host↔device row
     # mapping (see ShardedDeviceRingSync) so every shard fills evenly from
     # the first rows of experience.
+    #
+    # Fields are made in storage form directly: a [C, W] zeros array only
+    # to reshape it would hold a wide field twice at start-up.
     ring = DeviceRing(
-        obs=jnp.zeros((capacity, obs_dim), jnp.float32),
-        action=jnp.zeros((capacity, action_dim), jnp.float32),
+        obs=jnp.zeros(storage_shape((capacity, obs_dim)), jnp.float32),
+        action=jnp.zeros(storage_shape((capacity, action_dim)), jnp.float32),
         reward=jnp.zeros((capacity,), jnp.float32),
-        next_obs=jnp.zeros((capacity, obs_dim), jnp.float32),
+        next_obs=jnp.zeros(storage_shape((capacity, obs_dim)), jnp.float32),
         discount=jnp.zeros((capacity,), jnp.float32),
         size=jnp.zeros((), jnp.int32),
     )
@@ -115,6 +271,14 @@ def device_ring_init(
             f"sharded ring: capacity {capacity} not divisible by dp="
             f"{n_shards}"
         )
+    for name in VECTOR_FIELDS:
+        if getattr(ring, name).shape[0] % n_shards:
+            p = ring.rows_packed(name)
+            raise ValueError(
+                f"sharded ring: {name} is stored {p} rows to a storage row "
+                f"(replay/device_ring.py:storage_shape), so capacity "
+                f"{capacity} must be divisible by {p} x dp={n_shards}"
+            )
     specs = ring_partition_specs(ring)
     if jax.process_count() > 1:
         # Collective-free placement (parallel/distributed.stage_global):
@@ -144,14 +308,7 @@ def ingest_body(ring: DeviceRing, chunk: dict, slots: jax.Array,
     program. In the d4pglint ``MEGASTEP_FUNCTIONS`` manifest: this body is
     jit-traced, so host numpy / ``.item()`` coercions here would smuggle a
     per-flush host sync into the device loop."""
-    return DeviceRing(
-        obs=ring.obs.at[slots].set(chunk["obs"], mode="drop"),
-        action=ring.action.at[slots].set(chunk["action"], mode="drop"),
-        reward=ring.reward.at[slots].set(chunk["reward"], mode="drop"),
-        next_obs=ring.next_obs.at[slots].set(chunk["next_obs"], mode="drop"),
-        discount=ring.discount.at[slots].set(chunk["discount"], mode="drop"),
-        size=new_size,
-    )
+    return ring.set_rows(chunk, slots, new_size)
 
 
 def make_ingest():
@@ -370,14 +527,8 @@ def sharded_ingest_body(ring: DeviceRing, chunk: dict, slots: jax.Array,
     ``MEGASTEP_FUNCTIONS`` manifest: jit-traced, so host numpy or
     ``.item()`` here would smuggle a per-flush host sync into the device
     loop."""
-    sl = slots[0]
-    return DeviceRing(
-        obs=ring.obs.at[sl].set(chunk["obs"][0], mode="drop"),
-        action=ring.action.at[sl].set(chunk["action"][0], mode="drop"),
-        reward=ring.reward.at[sl].set(chunk["reward"][0], mode="drop"),
-        next_obs=ring.next_obs.at[sl].set(chunk["next_obs"][0], mode="drop"),
-        discount=ring.discount.at[sl].set(chunk["discount"][0], mode="drop"),
-        size=new_size,
+    return ring.set_rows(
+        {name: rows[0] for name, rows in chunk.items()}, slots[0], new_size
     )
 
 
@@ -766,7 +917,9 @@ class MultihostRingSync:
         perm = striped_perm(self.capacity, D).reshape(-1)
         out = {"pos": np.asarray(pos), "size": np.asarray(size)}
         for name in ("obs", "action", "reward", "next_obs", "discount"):
-            lanes = gather_global(getattr(ring, name))
+            # gathered as stored, read as rows: a numpy view on the host
+            stored = gather_global(getattr(ring, name))
+            lanes = stored.reshape((self.capacity, -1)[: stored.ndim])
             host = np.empty_like(lanes)
             host[perm] = lanes
             out[name] = host[:size]

@@ -317,7 +317,7 @@ def megastep_device_per_body(
     with phase("replay.write_back"):
         sums_lane, mp_local = dper.write_back_lane(
             sums_lane, idx, priorities, config.per_alpha, config.per_eps,
-            local_capacity=ring.obs.shape[0],
+            local_capacity=ring.capacity,
         )
         if n_shards > 1:
             mp_local = jnp.max(jax.lax.all_gather(mp_local, "dp"))
@@ -413,7 +413,7 @@ def megastep_device_per_fused_body(
     with phase("replay.write_back"):
         sums_lane, mp_local = dper.write_back_lane(
             sums_lane, idx_all, priorities, config.per_alpha,
-            config.per_eps, local_capacity=ring.obs.shape[0],
+            config.per_eps, local_capacity=ring.capacity,
         )
         max_priority = jnp.maximum(max_priority, mp_local)
     return (

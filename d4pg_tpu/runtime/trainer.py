@@ -469,6 +469,12 @@ class Trainer:
                 config.replay_capacity, obs_dim, act_dim,
                 mesh=self._mega_mesh,
             )
+            # Static per field: whether the lane-dense storage of wide
+            # rows engaged (replay/device_ring.py:storage_shape).
+            print(
+                "[replay] device ring storage: "
+                + json.dumps(self._ring.describe_storage())
+            )
             if self._mega_mesh is not None and self._procs > 1:
                 # Multi-host: each process's host buffer feeds only its
                 # LOCAL dp shards through make_array_from_callback staging;

@@ -211,6 +211,94 @@ class TestUniformMegastepParity:
         assert not _leaves_equal(s1.actor_params, s2.actor_params)
 
 
+# --------------------------------------------- wide rows, stored lane-dense
+class TestWideRowStorage:
+    """ISSUE 25: an obs width of 136 is stored 16 rows to a storage row
+    (``DeviceRing``: ``[C/16, 2176]``). The same megastep over the same
+    rows stored logically (the rule switched off: ``P`` = 1 is the old
+    ``[C, W]`` ring and the old ``field[idx]``) must return the same bits
+    everywhere: state, tree, priorities, metrics."""
+
+    W, A, C, ROWS, K, B = 136, 2, 128, 96, 3, 8
+
+    def _cfg(self):
+        return dataclasses.replace(_small_cfg(), obs_dim=self.W, action_dim=self.A)
+
+    def _run(self, kind):
+        from d4pg_tpu.replay import device_per as dper
+        from d4pg_tpu.runtime.megastep import (
+            make_megastep_device_per,
+            make_megastep_hybrid,
+        )
+
+        cfg = self._cfg()
+        buf = ReplayBuffer(self.C, self.W, self.A)
+        _fill(buf, self.ROWS)
+        sync = DeviceRingSync(buf, chunk_cap=40)    # 40 % 16: chunks straddle
+        dps = dper.DevicePerSync(self.C, cfg.per_alpha)
+        sync.tree_hook = dps.on_chunk
+        ring = sync.flush(device_ring_init(self.C, self.W, self.A))
+        state = create_train_state(cfg, jax.random.PRNGKey(1))
+        key, out = jax.random.PRNGKey(7), []
+        if kind == "uniform":
+            mega = make_megastep_uniform(cfg, self.K, self.B)
+        elif kind == "per":
+            mega, tree = make_megastep_device_per(cfg, self.K, self.B), dps.tree
+        else:
+            mega = make_megastep_hybrid(cfg)
+            r = np.random.default_rng(5)
+        for _ in range(3):
+            if kind == "uniform":
+                state, key, metrics = mega(state, ring, key)
+            elif kind == "per":
+                state, tree, key, metrics = mega(state, ring, tree, key)
+                out.append(jax.device_get(tree))   # donated next round
+            else:
+                idx = r.integers(0, self.ROWS, (self.K, self.B)).astype(np.int32)
+                idx[0, :2] = self.ROWS - 1
+                w = r.uniform(0.5, 1.0, (self.K, self.B)).astype(np.float32)
+                state, metrics, priorities = mega(
+                    state, ring, jnp.asarray(idx), jnp.asarray(w))
+                out.append(priorities)
+            out.append(metrics)
+        return ring, jax.device_get((state, key, out))
+
+    @pytest.mark.parametrize("kind", ["uniform", "per", "hybrid"])
+    def test_packed_ring_is_bit_identical_to_logical_rows(self, kind, monkeypatch):
+        from d4pg_tpu.replay import device_ring
+
+        packed_ring, packed = self._run(kind)
+        assert packed_ring.obs.shape == (self.C // 16, 16 * self.W)
+        assert packed_ring.describe_storage()["obs"]["rows_per_storage_row"] == 16
+        monkeypatch.setattr(device_ring, "rows_per_storage_row", lambda width: 1)
+        logical_ring, logical = self._run(kind)
+        assert logical_ring.obs.shape == (self.C, self.W)
+        assert _leaves_equal(packed, logical)
+        np.testing.assert_array_equal(
+            np.asarray(packed_ring.logical("obs")), np.asarray(logical_ring.obs))
+
+
+    def test_trainer_logs_the_ring_storage_once(self, tmp_path, capsys):
+        """The counter that says the storage engaged is static, so the
+        trainer prints it once at start-up (``describe_storage``)."""
+        import json
+
+        from d4pg_tpu.runtime.trainer import Trainer
+
+        t = Trainer(_trainer_cfg("device", str(tmp_path / "d")))
+        try:
+            described = t._ring.describe_storage()
+        finally:
+            t.close()
+        lines = [line for line in capsys.readouterr().out.splitlines()
+                 if line.startswith("[replay] device ring storage: ")]
+        assert len(lines) == 1
+        logged = json.loads(lines[0].split(": ", 1)[1])
+        assert logged["obs"]["stored_shape"] == list(described["obs"]["stored_shape"])
+        assert logged["obs"]["rows_per_storage_row"] == 1     # pendulum: 3 wide
+        assert set(logged) == {"obs", "action", "reward", "next_obs", "discount"}
+
+
 # ------------------------------------------------ hybrid index determinism
 def _per_buf(backend: str) -> PrioritizedReplayBuffer:
     buf = PrioritizedReplayBuffer(64, 3, 2, tree_backend=backend)

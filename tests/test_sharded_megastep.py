@@ -213,6 +213,56 @@ class TestShardedMegastepParity:
         assert_sharded_parity(st_m, st_o)
         assert np.array_equal(np.asarray(key_m), np.asarray(key_o))
 
+    @pytest.mark.parametrize("kind", ["uniform", "per"])
+    def test_wide_rows_packed_bit_identical_to_logical(self, kind, monkeypatch):
+        """ISSUE 25: obs width 136 is stored 16 rows to a storage row, so a
+        dp=4 ring of 128 rows is ``[8, 2176]`` with two storage rows (its
+        own 32 logical rows) on each chip. The sharded megastep over it
+        returns the bits of the same megastep over the rows stored
+        logically (the rule switched off): state, tree, key, metrics."""
+        from d4pg_tpu.replay import device_per as dper
+        from d4pg_tpu.replay import device_ring
+        from d4pg_tpu.runtime.megastep import make_megastep_device_per_sharded
+
+        D, K, B, C, W, A = 4, 2, 16, 128, 136, 2
+        cfg = _small_cfg(obs_dim=W, action_dim=A)
+        mesh = make_mesh(dp=D, tp=1)
+
+        def run():
+            buf = ReplayBuffer(C, W, A)
+            _fill(buf, 100)          # uneven: shards hold 25 rows each
+            sync = ShardedDeviceRingSync(buf, mesh, chunk_cap=40)
+            dps = dper.DevicePerSync(C, cfg.per_alpha, mesh=mesh)
+            sync.tree_hook = dps.on_chunk
+            ring = sync.flush(device_ring_init(C, W, A, mesh=mesh))
+            state = shard_train_state(
+                create_train_state(cfg, jax.random.PRNGKey(1)), mesh)
+            key = jax.device_put(jax.random.PRNGKey(7), NamedSharding(mesh, P()))
+            out, tree = [], dps.tree
+            if kind == "uniform":
+                mega = make_megastep_uniform_sharded(cfg, K, B, mesh)
+            else:
+                mega = make_megastep_device_per_sharded(cfg, K, B, mesh)
+            for _ in range(3):
+                if kind == "uniform":
+                    state, key, metrics = mega(state, ring, key)
+                else:
+                    state, tree, key, metrics = mega(state, ring, tree, key)
+                    out.append(jax.device_get(tree))    # donated next round
+                out.append(metrics)
+            return ring, jax.device_get((state, key, out))
+
+        packed_ring, packed = run()
+        assert packed_ring.obs.shape == (C // 16, 16 * W)
+        assert {s.data.shape for s in packed_ring.obs.addressable_shards} == {
+            (C // 16 // D, 16 * W)}
+        monkeypatch.setattr(device_ring, "rows_per_storage_row", lambda width: 1)
+        logical_ring, logical = run()
+        assert logical_ring.obs.shape == (C, W)
+        assert _leaves_equal(packed, logical)
+        np.testing.assert_array_equal(
+            np.asarray(packed_ring.logical("obs")), np.asarray(logical_ring.obs))
+
     def test_different_keys_diverge(self):
         """Sanity: the parity comparison is not vacuous."""
         D, K, B, C = 4, 2, 8, 64

@@ -193,6 +193,10 @@ MEGASTEP_FUNCTIONS = (
     "d4pg_tpu/ops/pallas_fused_step.py::fused_categorical_loss_descent",
     "d4pg_tpu/replay/device_ring.py::ingest_body",
     "d4pg_tpu/replay/device_ring.py::sharded_ingest_body",
+    # the ring's row accessors: what the ingest bodies and gather_batches
+    # trace (lane-dense storage of wide rows lives behind them)
+    "d4pg_tpu/replay/device_ring.py::DeviceRing.rows",
+    "d4pg_tpu/replay/device_ring.py::DeviceRing.set_rows",
     # The device priority tree's traced primitives (replay/device_per.py):
     # every one is traced into the megastep or the per-flush tree seed.
     "d4pg_tpu/replay/device_per.py::repair_ancestors",
