@@ -37,6 +37,7 @@ from d4pg_tpu.ops import (
     polyak_update,
 )
 from d4pg_tpu.models.critic import mixture_gaussian_mean
+from d4pg_tpu.models.torso import torso_apply, torso_init
 from d4pg_tpu.utils.profiling import phase
 
 
@@ -98,6 +99,44 @@ def _stacked_critics(config: D4PGConfig) -> int:
     return 2 if config.twin_critic else 0
 
 
+def _check_torso(config: D4PGConfig) -> None:
+    """What a torso composes with today; the rest is refused by name."""
+    if config.twin_critic or config.critic_ensemble:
+        raise ValueError(
+            "a torso is owned by ONE critic: --twin-critic / "
+            "--critic-ensemble would stack it (not supported)")
+    if config.pixel_shape:
+        raise ValueError("a torso reads flat observation rows, not pixels")
+    if config.dist.kind != "categorical" or config.projection_backend == "pallas_fused":
+        raise ValueError(
+            "a torso trains the categorical head on the xla or pallas "
+            "projection (the fused tier's descent is not wired through it)")
+
+
+def _encoders(config: D4PGConfig, batch):
+    """``(encode, head_of)``: how ``train_step`` turns critic parameters
+    and a batch's observations into what the actor and critic networks
+    read. The MLP/conv networks read the rows themselves (both are the
+    identity: no op is added to their program). A torso is part of the
+    critic's parameters and reads ``[B, T, O]`` windows under the batch's
+    ``mask``; its routing counts are dropped here."""
+    if config.torso is None:
+        return (lambda params, obs: obs), (lambda params: params)
+
+    def encode(params, obs):
+        return torso_apply(config.torso, params["torso"], obs, batch["mask"])[0]
+
+    return encode, (lambda params: params["head"])
+
+
+def acting_params(config: D4PGConfig, state: TrainState):
+    """The parameters a policy needs to act: the actor's, and with a torso
+    the critic's torso beside them (the actor is a head on its output)."""
+    if config.torso is None:
+        return state.actor_params
+    return {"head": state.actor_params, "torso": state.critic_params["torso"]}
+
+
 def create_train_state(config: D4PGConfig, key: jax.Array) -> TrainState:
     """Initialize params, hard-copy targets (reference ``ddpg.py:57-64,92-94``).
 
@@ -109,11 +148,20 @@ def create_train_state(config: D4PGConfig, key: jax.Array) -> TrainState:
     """
     actor, critic = build_networks(config)
     k_actor, k_critic, k_state = jax.random.split(key, 3)
-    obs = jnp.zeros((1, config.obs_dim))
+    # what actor and critic networks read: the rows, or a torso's output
+    width = config.obs_dim if config.torso is None else config.torso.hidden_size
+    obs = jnp.zeros((1, width))
     action = jnp.zeros((1, config.action_dim))
     actor_params = actor.init(k_actor, obs)
     n_stack = _stacked_critics(config)
-    if n_stack:
+    if config.torso is not None:
+        _check_torso(config)
+        k_torso, k_critic = jax.random.split(k_critic)
+        critic_params = {
+            "torso": torso_init(config.torso, k_torso, config.obs_dim),
+            "head": critic.init(k_critic, obs, action),
+        }
+    elif n_stack:
         stack_keys = jax.random.split(k_critic, n_stack)
         critic_params = jax.tree_util.tree_map(
             lambda *leaves: jnp.stack(leaves),
@@ -212,6 +260,26 @@ def act_deterministic(config: D4PGConfig, actor_params: Any, obs: jax.Array) -> 
     """Greedy policy for evaluation (reference ``main.py:122,324``)."""
     actor, _ = build_networks(config)
     return actor.apply(actor_params, obs)
+
+
+def act_on_window(config: D4PGConfig, params: Any, window: jax.Array,
+                  valid: jax.Array) -> jax.Array:
+    """Greedy action of a torso policy on ``[B, T, O]`` windows of each
+    stream's last observations (``valid [B, T]``: False before the episode's
+    start); ``params`` are :func:`acting_params`."""
+    actor, _ = build_networks(config)
+    h, _ = torso_apply(config.torso, params["torso"], window, valid)
+    return actor.apply(params["head"], h)
+
+
+def push_observation(window, count, obs):
+    """What a torso policy carries of one stream: the ``[T, O]`` window with
+    ``obs`` as its newest row, the new count of rows that belong to the
+    running episode, and which rows are valid (the newest ``count``)."""
+    t = window.shape[0]
+    window = jnp.concatenate([window[1:], obs[None]], axis=0)
+    count = jnp.minimum(count + 1, t)
+    return window, count, jnp.arange(t) >= t - count
 
 
 def noisy_explore(config: D4PGConfig, noise_sample, a, key, nstate, scale):
@@ -325,6 +393,7 @@ def train_step(
     actor, critic = build_networks(config)
     actor_opt, critic_opt = make_optimizers(config)
     support = support_of(config)
+    encode, head_of = _encoders(config, batch)
 
     # ---- bf16 hot-path dtype policy ----
     # Master weights, Adam moments, Polyak targets and every loss reduction
@@ -374,7 +443,8 @@ def train_step(
 
     # ---- target: y = Φ(r + γ_eff · Z_target(s', μ_target(s'))) ----
     with phase("agent.networks"):
-        next_action = actor.apply(tgt_actor_params, batch["next_obs"])
+        next_feat = encode(tgt_critic_params, batch["next_obs"])
+        next_action = actor.apply(tgt_actor_params, next_feat)
         if config.critic_ensemble:
             # REDQ in-target minimization, distributionally: back up whichever
             # member of a per-step RANDOM SUBSET of M target critics has the
@@ -384,7 +454,7 @@ def train_step(
             E = config.critic_ensemble
             M = config.ensemble_min_targets
             heads = jax.vmap(
-                lambda p: critic.apply(p, batch["next_obs"], next_action)
+                lambda p: critic.apply(p, next_feat, next_action)
             )(tgt_critic_params)                                    # [E, B, H]
             vals = jax.vmap(lambda h: _critic_value(config, support, h))(heads)
             k_subset, new_key = jax.random.split(new_key)
@@ -401,7 +471,7 @@ def train_step(
             # the distributional analogue of TD3's min(Q1, Q2) (taking an
             # elementwise min of probs would not be a distribution).
             heads = jax.vmap(
-                lambda p: critic.apply(p, batch["next_obs"], next_action)
+                lambda p: critic.apply(p, next_feat, next_action)
             )(tgt_critic_params)
             vals = jax.vmap(lambda h: _critic_value(config, support, h))(heads)
             target_head = jnp.where(
@@ -409,7 +479,7 @@ def train_step(
             )
         else:
             target_head = critic.apply(
-                tgt_critic_params, batch["next_obs"], next_action
+                head_of(tgt_critic_params), next_feat, next_action
             )
 
     if config.dist.kind == "categorical":
@@ -490,7 +560,8 @@ def train_step(
             proj = jax.lax.stop_gradient(proj)
 
             def critic_loss_fn(critic_params):
-                pred = critic.apply(critic_params, batch["obs"], batch["action"])
+                feat = encode(critic_params, batch["obs"])
+                pred = critic.apply(head_of(critic_params), feat, batch["action"])
                 with phase("ops.projection_loss"):
                     loss, per_sample_ce = categorical_td_loss(pred, proj, weights)
                     if config.priority_kind == "overlap":
@@ -503,6 +574,8 @@ def train_step(
                         )
                     else:
                         per_sample = per_sample_ce
+                if config.torso is not None:    # the actor reads this pass's features
+                    return loss, (per_sample, jax.lax.stop_gradient(feat))
                 return loss, per_sample
     elif config.dist.kind == "scalar":
         # Plain DDPG TD(0)/TD(n) target (BASELINE.json config 1).
@@ -575,8 +648,14 @@ def train_step(
         (critic_loss, loss_aux), critic_grads = jax.value_and_grad(
             critic_loss_fn, has_aux=True
         )(state.critic_params)
+    # What the actor reads: the rows themselves, or a torso's output on them
+    # as the critic's loss pass just computed it (the torso BEFORE this
+    # step's update, under stop_gradient; the head it ascends is updated).
+    feat = batch["obs"]
     if descent is not None:
         priorities, descent_idx = loss_aux
+    elif config.torso is not None:
+        priorities, feat = loss_aux
     else:
         priorities = loss_aux
     critic_grads = _sync(critic_grads)
@@ -599,15 +678,15 @@ def train_step(
     )
 
     def actor_loss_fn(actor_params):
-        a = actor.apply(actor_params, batch["obs"])
+        a = actor.apply(actor_params, feat)
         if config.critic_ensemble:
             heads = jax.vmap(
-                lambda p: critic.apply(p, batch["obs"], a)
+                lambda p: critic.apply(p, feat, a)
             )(critic_params)                                    # [E, B, H]
             q = jax.vmap(lambda h: _critic_value(config, support, h))(heads)
             q_mean = jnp.mean(q)          # mean over members AND batch
         else:
-            head = critic.apply(actor_critic_params, batch["obs"], a)
+            head = critic.apply(head_of(actor_critic_params), feat, a)
             q_mean = jnp.mean(_critic_value(config, support, head))
         loss = -q_mean
         if config.action_l2:
@@ -688,13 +767,16 @@ def jit_train_step(config: D4PGConfig, donate: bool = True):
     return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
 
-def gather_batches(store, idx: jax.Array) -> dict:
+def gather_batches(store, idx: jax.Array, torso=None) -> dict:
     """Bulk-gather [K, B] batches from a columnar store (device replay or
     pool) in ONE op per field. Doing this before the train scan instead of
     per-step inside it measured ~2.2x on v5e (per-step RBG PRNG + scattered
-    HBM reads dominate otherwise)."""
+    HBM reads dominate otherwise). With a ``torso`` (``D4PGConfig.torso``)
+    the observations are windows: :func:`gather_windows`."""
     from d4pg_tpu.replay.device_ring import ROW_FIELDS, DeviceRing
 
+    if torso is not None:
+        return gather_windows(store, idx, torso.window, torso.row_stride)
     with phase("replay.row_gather"):
         def rows(k):
             if isinstance(store, DeviceRing):  # wide fields are stored packed
@@ -702,6 +784,36 @@ def gather_batches(store, idx: jax.Array) -> dict:
             return (store[k] if isinstance(store, dict) else getattr(store, k))[idx]
 
         batches = {k: rows(k) for k in ROW_FIELDS}
+        batches["weights"] = jnp.ones(idx.shape, jnp.float32)
+    return batches
+
+
+def gather_windows(store, idx: jax.Array, window: int, stride: int) -> dict:
+    """[K, B] batches whose observations are the WINDOW of the ``window``
+    ring rows that end at each drawn slot: ``obs`` / ``next_obs`` ``[K, B,
+    T, O]`` (rows ``idx − (T−1−j)·stride``, ``stride`` = the writer's env
+    interleave), ``mask [K, B, T]``, the other fields of the drawn row.
+
+    A position is masked when its row lies before the ring's first row
+    (``DeviceRing`` carries its fill, which is also the write cursor of a
+    ring that has not wrapped; a drawn slot is below it, so only the rows
+    *before* row 0 can be beyond it — they are never wrapped around to), or
+    when a row between it and the window's end, the end excluded, has
+    ``discount == 0``: an episode ended there, the position belongs to the
+    episode before. The last position is always valid."""
+    from d4pg_tpu.replay.device_ring import ROW_FIELDS
+
+    with phase("replay.row_gather"):
+        back = (jnp.arange(window, dtype=idx.dtype) - (window - 1)) * stride
+        pos = idx[..., None] + back                              # [K, B, T]
+        inside = pos >= 0
+        pos = jnp.maximum(pos, 0)
+        batches = {k: store.rows(k, pos if k in ("obs", "next_obs") else idx)
+                   for k in ROW_FIELDS}
+        ended = (store.rows("discount", pos) == 0.0) & inside
+        ended = ended.at[..., -1].set(False)
+        later_end = jnp.flip(jnp.cumsum(jnp.flip(ended, -1), -1), -1) > 0
+        batches["mask"] = inside & ~later_end
         batches["weights"] = jnp.ones(idx.shape, jnp.float32)
     return batches
 

@@ -17,6 +17,7 @@ import jax
 from flax import struct
 
 from d4pg_tpu.models.critic import DistConfig
+from d4pg_tpu.models.torso import TorsoConfig
 
 
 @dataclass(frozen=True)
@@ -119,6 +120,12 @@ class D4PGConfig:
     # min over M of E controls the under/overestimation trade — M=2 is
     # the paper's setting; M=E recovers "min over all".
     ensemble_min_targets: int = 2
+    # A sequence torso over a window of the last ``torso.window`` ring rows
+    # (models/torso.py). The critic owns it (``critic_params = {"torso",
+    # "head"}``; its target copy is the target critic's), actor and critic
+    # MLPs become heads on its output, the actor reads it under
+    # stop_gradient. None = the MLP/conv networks, byte-unchanged.
+    torso: TorsoConfig | None = None
 
 
 class TrainState(struct.PyTreeNode):

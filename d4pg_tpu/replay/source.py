@@ -128,6 +128,9 @@ class RequestedCaps:
     projection: str = "xla"         # xla | pallas | pallas_fused
     dist_kind: str = "categorical"  # categorical | quantile | iqn
     chaos: bool = False
+    # A sequence torso under the critic (models/torso.py): batches are
+    # windows over the device ring, the policy acts on a window.
+    torso: bool = False
     batch_size: int = 256
     replay_capacity: Optional[int] = None
     # Multi-host (ISSUE 17): how many jax.distributed processes share the
@@ -173,6 +176,7 @@ def from_train_config(config, *, on_device: bool = False,
         projection=config.agent.projection_backend,
         dist_kind=config.agent.dist.kind,
         chaos=bool(config.chaos),
+        torso=config.agent.torso is not None,
         batch_size=int(config.batch_size),
         replay_capacity=config.replay_capacity,
         processes=int(getattr(config, "num_processes", 1) or 1),
@@ -286,6 +290,39 @@ def negotiate(caps: RequestedCaps) -> Negotiation:
             # composes with ingest through the same host-buffer mirror
             # local collection uses, so nothing refuses here.
             pass
+
+    # A torso's batches are windows of consecutive ring rows of one env's
+    # stream, gathered inside the single-device megastep, and its policy
+    # carries each env's last observations: the paths that have neither
+    # are refused by name.
+    if caps.torso:
+        if caps.placement != "device":
+            gap(
+                "torso_device_placement_only",
+                "--torso gathers its history windows from the device ring "
+                "inside the megastep; it requires --replay-placement device",
+            )
+        if caps.dp or caps.processes > 1:
+            gap(
+                "torso_single_device",
+                "--torso is single-device: a striped ring splits a stream's "
+                "consecutive rows over the shards and the torso's weights "
+                "are not sharded yet (drop --dp)",
+            )
+        if caps.fused_descent:
+            gap(
+                "torso_no_fused_descent",
+                "--torso trains through the separate-programs PER tier; "
+                "--fused-descent is not wired through it",
+            )
+        if (caps.her or caps.fleet or caps.async_collect or caps.on_device
+                or caps.is_jax_env is False):
+            gap(
+                "torso_sync_jax_collection_only",
+                "--torso acts on each env's last observations, which only "
+                "the synchronous pure-JAX collector keeps (no --her, "
+                "--fleet-listen, --async-collect, --on-device or host env)",
+            )
 
     # ISSUE 17 — the process-spanning mesh. Every structural requirement
     # is a declared gap: multihost exists only where the dp-sharded device

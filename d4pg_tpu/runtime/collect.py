@@ -31,10 +31,31 @@ import jax
 import jax.numpy as jnp
 
 from d4pg_tpu.agent import act_deterministic
-from d4pg_tpu.agent.d4pg import make_noise, noisy_explore
+from d4pg_tpu.agent.d4pg import (
+    act_on_window, make_noise, noisy_explore, push_observation,
+)
 from d4pg_tpu.agent.state import D4PGConfig
 from d4pg_tpu.envs.rollouts import rollout
 from d4pg_tpu.ops import nstep_returns
+
+
+def policy_state_fns(config: D4PGConfig, noise_fns):
+    """``(init, reset)`` of what a collecting policy carries from step to
+    step: the noise state, and with a torso each env's last ``window``
+    observations and how many of them belong to the running episode (a
+    torso policy acts on the window; an episode's start empties it)."""
+    noise_init, _, noise_reset = noise_fns
+    if config.torso is None:
+        return noise_init, noise_reset
+
+    def init():
+        window = jnp.zeros((config.torso.window, config.obs_dim), jnp.float32)
+        return noise_init(), window, jnp.zeros((), jnp.int32)
+
+    def reset(state):
+        return noise_reset(state[0]), jnp.zeros_like(state[1]), jnp.zeros_like(state[2])
+
+    return init, reset
 
 
 def make_segment_collector(
@@ -52,6 +73,11 @@ def make_segment_collector(
     transitions (obs, action, reward=R^(m), next_obs=s_{t+m},
     discount=γ^m·(1−terminal)); ``traj`` is the raw segment for metrics.
     ``noise_scale`` is a traced scalar — schedules don't retrace.
+    ``noise_states`` is what :func:`policy_state_fns` makes (``collect.
+    policy_state_init``). With a torso ``actor_params`` are
+    ``agent.d4pg.acting_params`` and ``flat`` is time-major — row
+    ``t·num_envs + e`` — so an env's consecutive steps lie ``num_envs`` rows
+    apart in the ring (``TorsoConfig.row_stride``), across segments too.
 
     ``return_traj=False`` returns ``None`` for ``traj`` so XLA prunes the
     raw-segment outputs from the program — callers that only consume
@@ -60,20 +86,28 @@ def make_segment_collector(
     envs). Callers that trace this inside their own jit (the on-device
     trainer) get that pruning for free and can keep ``traj`` for metrics.
     """
-    noise_init, noise_sample, noise_reset = noise_fns or make_noise(config)
+    noise_fns = noise_fns or make_noise(config)
+    noise_sample = noise_fns[1]
+    state_init, state_reset = policy_state_fns(config, noise_fns)
     n_new = num_envs * segment_len
 
     @jax.jit
     def collect(actor_params, env_states, obs, noise_states, key, noise_scale):
-        def policy(o, k, nstate):
-            a = act_deterministic(config, actor_params, o[None])[0]
-            return noisy_explore(config, noise_sample, a, k, nstate, noise_scale)
+        def policy(o, k, pstate):
+            if config.torso is None:
+                a = act_deterministic(config, actor_params, o[None])[0]
+                return noisy_explore(config, noise_sample, a, k, pstate, noise_scale)
+            nstate, window, count = pstate
+            window, count, valid = push_observation(window, count, o)
+            a = act_on_window(config, actor_params, window[None], valid[None])[0]
+            a, nstate = noisy_explore(config, noise_sample, a, k, nstate, noise_scale)
+            return a, (nstate, window, count)
 
         def one(env_state, o, nstate, k):
             return rollout(
                 env, policy, k, segment_len,
                 init_state=env_state, init_obs=o,
-                policy_state=nstate, policy_state_reset=noise_reset,
+                policy_state=nstate, policy_state_reset=state_reset,
             )
 
         keys = jax.random.split(key, num_envs)
@@ -99,9 +133,12 @@ def make_segment_collector(
             traj.reward, traj.terminated, traj.truncated,
             traj.obs, traj.action, traj.next_obs,
         )
+        if config.torso is not None:      # time-major: an env's rows num_envs apart
+            flat = jax.tree_util.tree_map(lambda x: jnp.swapaxes(x, 0, 1), flat)
         flat = jax.tree_util.tree_map(
             lambda x: x.reshape((n_new,) + x.shape[2:]), flat
         )
         return env_states, obs, noise_states, flat, traj if return_traj else None
 
+    collect.policy_state_init = state_init
     return collect

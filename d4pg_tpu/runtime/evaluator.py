@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from d4pg_tpu.agent import D4PGConfig, act_deterministic
+from d4pg_tpu.agent.d4pg import act_on_window, push_observation
 
 
 @functools.lru_cache(maxsize=32)
@@ -35,18 +36,25 @@ def make_evaluator(config: D4PGConfig, env, num_episodes: int, max_steps: int):
 
     def one_episode(actor_params, k):
         state, obs = env.reset(k)
+        t = config.torso.window if config.torso is not None else 0
+        history = (jnp.zeros((t, config.obs_dim), jnp.float32), jnp.zeros((), jnp.int32))
 
         def body(carry, _):
-            state, obs, ret, done, succ = carry
-            action = act_deterministic(config, actor_params, obs[None])[0]
+            state, obs, ret, done, succ, history = carry
+            if config.torso is None:
+                action = act_deterministic(config, actor_params, obs[None])[0]
+            else:   # ``actor_params`` are agent.d4pg.acting_params
+                window, count, valid = push_observation(*history, obs)
+                history = (window, count)
+                action = act_on_window(config, actor_params, window[None], valid[None])[0]
             state2, obs2, r, term, trunc = env.step(state, action)
             ret = ret + r * (1.0 - done)
             succ = jnp.maximum(succ, term * (1.0 - done))
             done = jnp.maximum(done, jnp.maximum(term, trunc))
-            return (state2, obs2, ret, done, succ), None
+            return (state2, obs2, ret, done, succ, history), None
 
-        init = (state, obs, jnp.zeros(()), jnp.zeros(()), jnp.zeros(()))
-        (_, _, ret, _, succ), _ = jax.lax.scan(body, init, None, length=max_steps)
+        init = (state, obs, jnp.zeros(()), jnp.zeros(()), jnp.zeros(()), history)
+        (_, _, ret, _, succ, _), _ = jax.lax.scan(body, init, None, length=max_steps)
         return ret, succ
 
     @jax.jit

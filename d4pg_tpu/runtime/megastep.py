@@ -68,7 +68,7 @@ def megastep_uniform_body(
     with phase("replay.draw"):
         key, k_idx = jax.random.split(key)
         idx = draw_uniform_indices(k_idx, k, batch, ring.size)
-    batches = gather_batches(ring, idx)
+    batches = gather_batches(ring, idx, config.torso)
     # Determinism contract (tests/test_megastep.py pins it): uniform IS
     # weights are identically 1, so leave the key OUT and let train_step's
     # internal ones-constant supply them — measured on XLA CPU, a ones
@@ -303,7 +303,7 @@ def megastep_device_per_body(
         weights = dper.importance_weights(
             p_leaf, total_local, min_ratio, ring.size, n_shards, beta
         )
-    batches = gather_batches(ring, idx)
+    batches = gather_batches(ring, idx, config.torso)
     batches["weights"] = weights
     if n_shards > 1:
         from d4pg_tpu.parallel.dp import det_pmean

@@ -44,7 +44,9 @@ from d4pg_tpu.agent import (
     create_train_state,
     jit_train_step,
 )
-from d4pg_tpu.agent.d4pg import fused_train_scan, make_noise, noisy_explore
+from d4pg_tpu.agent.d4pg import (
+    acting_params, fused_train_scan, make_noise, noisy_explore,
+)
 from d4pg_tpu.ops.obs_norm import RunningObsNorm
 from d4pg_tpu.config import ENV_PRESETS, TrainConfig
 from d4pg_tpu.envs import make_env
@@ -1081,7 +1083,7 @@ class Trainer:
         self.key, reset_key = jax.random.split(self.key)
         reset_keys = jax.random.split(reset_key, cfg.num_envs)
         self.env_states, self.obs = jax.vmap(env.reset)(reset_keys)
-        self.noise_states = jax.vmap(lambda _: self._noise_init())(
+        self.noise_states = jax.vmap(lambda _: self._collect.policy_state_init())(
             jnp.arange(cfg.num_envs)
         )
 
@@ -1090,7 +1092,7 @@ class Trainer:
         scale = self._noise_scale() if noise_scale is None else noise_scale
         with self._timers.stage("env_step"):
             self.env_states, self.obs, self.noise_states, flat, _traj = self._collect(
-                self.state.actor_params, self.env_states, self.obs,
+                acting_params(self.config.agent, self.state), self.env_states, self.obs,
                 self.noise_states, k, scale,
             )
             flat = jax.device_get(flat)
@@ -2854,11 +2856,11 @@ class Trainer:
             # latest finished eval's scalars so callers always see the keys.
             self._request_eval(scalars)
             return {**scalars, **self._last_eval_ev}
+        policy_params = self.state.actor_params
         if self.is_jax_env:
             self.key, ek = jax.random.split(self.key)
-            ev = evaluate(
-                cfg.agent, self.env, self.state.actor_params, ek, cfg.eval_episodes
-            )
+            policy_params = acting_params(cfg.agent, self.state)
+            ev = evaluate(cfg.agent, self.env, policy_params, ek, cfg.eval_episodes)
         else:
             ev = self._host_eval()
         # Same EWMA/log/print path as the concurrent evaluator, inline.
@@ -2866,7 +2868,7 @@ class Trainer:
         # steps made multi-leg metrics.jsonl non-monotone, which zigzags
         # any step-keyed plot. Inline eval scored the LIVE params (learner
         # thread, no dispatch in flight) so keep-best saves those.
-        self._apply_eval(self.grad_steps, scalars, ev, params=self.state.actor_params)
+        self._apply_eval(self.grad_steps, scalars, ev, params=policy_params)
         return self._last_eval_row
 
     def close(self):

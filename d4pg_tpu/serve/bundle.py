@@ -97,6 +97,11 @@ def actor_template(config: D4PGConfig):
 
     from d4pg_tpu.agent.d4pg import build_networks
 
+    if config.torso is not None:
+        raise ValueError(
+            "a serving bundle holds a stateless actor; a torso actor needs "
+            "its session's observation window and the critic's torso "
+            "(stateful serving sessions: ROADMAP B-m3)")
     actor, _ = build_networks(config)
     return actor.init(
         jax.random.PRNGKey(0), np.zeros((1, config.obs_dim), np.float32)

@@ -45,6 +45,9 @@ PHASES = (
     "ops.projection_loss",  # projection, cross-entropy, priority signal
     "agent.optimizer",      # both Adam updates, both Polyak updates
     "parallel.sync",        # train_step's _sync: det_pmean / pmean
+    # inside agent.networks, a sequence torso's two parts (models/torso.py)
+    "agent.attention",      # MLA: projections, rotary, scores, output
+    "agent.experts",        # router, dispatch plan, expert blocks, shared expert, combine
 )
 # One path component of an instruction's ``op_name``, no "/" in it:
 # ``jit(lane)/while/body/closed_call/jvp(ph:agent.networks)/...``. An

@@ -40,6 +40,7 @@ TIMED = {"gather", "scatter", "dot", "all-gather", "all-reduce",
          "all-gather-start", "all-reduce-start"}
 COMMON = {"replay.draw", "replay.row_gather", "agent.networks",
           "ops.projection_loss", "agent.optimizer"}
+TORSO = {"agent.attention", "agent.experts"}     # opened by models/torso.py only
 
 
 def _cfg(**kw) -> D4PGConfig:
@@ -79,6 +80,17 @@ def _device_per_fused():
     return ms.make_megastep_device_per_fused(cfg, K, B), _shapes(cfg, 1, per=True)
 
 
+def _device_per_torso():
+    import dataclasses
+
+    from d4pg_tpu.models.torso import TORSO_PRESETS
+
+    torso = dataclasses.replace(TORSO_PRESETS["glm47_flash_tiny"], experts_first=2,
+                                experts_held=4)
+    cfg = _cfg(torso=torso)
+    return ms.make_megastep_device_per(cfg, K, B), _shapes(cfg, 1, per=True)
+
+
 def _uniform_sharded():
     cfg = _cfg()
     mesh = make_mesh(dp=DP, tp=1)
@@ -99,7 +111,8 @@ VARIANTS = {
     "device_per": (_device_per, COMMON | {"replay.write_back"}),
     "device_per_fused": (_device_per_fused, COMMON | {"replay.write_back"}),
     "uniform_sharded": (_uniform_sharded, COMMON | {"parallel.sync"}),
-    "device_per_sharded": (_device_per_sharded, set(PHASES)),
+    "device_per_sharded": (_device_per_sharded, set(PHASES) - TORSO),
+    "device_per_torso": (_device_per_torso, COMMON | {"replay.write_back"} | TORSO),
 }
 
 
@@ -138,6 +151,15 @@ def test_every_variant_holds_its_phases(variant):
               and f"{PHASE_PREFIX}ops.projection_loss" in name]
     assert nested and {phase_of(n) for n in nested} == {"ops.projection_loss"}
     assert any("transpose(jvp(" in n for n in nested)
+    if TORSO <= want:
+        # the torso's two parts nest in the networks' scope too, forward,
+        # recomputed under jax.checkpoint and backward, and hold its dots
+        for part in TORSO:
+            names = [n for op, n, _ in instructions if phase_of(n) == part]
+            assert any(f"{PHASE_PREFIX}agent.networks" in n for n in names), part
+            assert any("transpose(jvp(" in n for n in names), part
+            assert any("rematted_computation" in n or "checkpoint" in n for n in names), part
+            assert any(op == "dot" and phase_of(n) == part for op, n, _ in instructions), part
 
 
 def test_phases_lists_what_the_sources_open_and_nothing_else():

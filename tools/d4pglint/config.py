@@ -197,6 +197,15 @@ MEGASTEP_FUNCTIONS = (
     # trace (lane-dense storage of wide rows lives behind them)
     "d4pg_tpu/replay/device_ring.py::DeviceRing.rows",
     "d4pg_tpu/replay/device_ring.py::DeviceRing.set_rows",
+    # A sequence torso (ISSUE 27): the window gather and the torso's
+    # traced functions run inside the same dispatch.
+    "d4pg_tpu/agent/d4pg.py::gather_windows",
+    "d4pg_tpu/models/torso.py::torso_apply",
+    "d4pg_tpu/models/torso.py::mla",
+    "d4pg_tpu/models/torso.py::expert_layer",
+    "d4pg_tpu/models/torso.py::dispatch_plan",
+    "d4pg_tpu/models/torso.py::_routed_fwd",
+    "d4pg_tpu/models/torso.py::_routed_bwd",
     # The device priority tree's traced primitives (replay/device_per.py):
     # every one is traced into the megastep or the per-flush tree seed.
     "d4pg_tpu/replay/device_per.py::repair_ancestors",

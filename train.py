@@ -31,6 +31,7 @@ import threading
 from d4pg_tpu.agent.state import D4PGConfig
 from d4pg_tpu.config import TrainConfig
 from d4pg_tpu.models.critic import DistConfig
+from d4pg_tpu.models.torso import TORSO_PRESETS
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -114,6 +115,20 @@ def build_parser() -> argparse.ArgumentParser:
                    help="comma-separated MLP trunk widths (default "
                         "256,256,256); must match the checkpoint when "
                         "resuming or exporting a bundle")
+    p.add_argument("--torso", choices=sorted(TORSO_PRESETS), default=None,
+                   help="sequence torso under the critic (models/torso.py): "
+                        "actor and critic MLPs become heads on its output over "
+                        "a window of the last --torso-window ring rows; needs "
+                        "--replay-placement device on one device")
+    p.add_argument("--torso-layers", type=int, default=None, metavar="N",
+                   help="blocks of the torso kept, the leading dense ones "
+                        "included (default: the preset's depth)")
+    p.add_argument("--torso-experts-held", default=None, metavar="FIRST:COUNT",
+                   help="the routed experts this learner holds of each expert "
+                        "layer, one share of an expert-parallel layer (default: "
+                        "all of them); the router keeps its full width")
+    p.add_argument("--torso-window", type=int, default=None, metavar="T",
+                   help="history window in ring rows (default: the preset's)")
     p.add_argument("--twin-critic", action="store_true",
                    help="clipped double-Q (TD3-style) distributional twin "
                         "critics; fixes the single-critic plateau on "
@@ -369,6 +384,17 @@ def config_from_args(args: argparse.Namespace) -> TrainConfig:
                 int(h) for h in str(args.hidden_sizes).split(",") if h.strip()
             ),
         )
+    if args.torso:
+        changes = {"row_stride": max(1, args.num_envs)}   # the writer's env interleave
+        if args.torso_layers is not None:
+            changes["num_hidden_layers"] = args.torso_layers
+        if args.torso_experts_held is not None:
+            first, count = (int(v) for v in args.torso_experts_held.split(":"))
+            changes.update(experts_first=first, experts_held=count)
+        if args.torso_window is not None:
+            changes["window"] = args.torso_window
+        agent = dataclasses.replace(
+            agent, torso=dataclasses.replace(TORSO_PRESETS[args.torso], **changes))
     # run-identity log dir (reference main.py:59-66)
     log_dir = args.log_dir or (
         f"runs/{args.env}_{'PER' if args.prioritized else 'UNI'}"
@@ -619,6 +645,13 @@ def main(argv=None):
 
     cfg = config_from_args(args)
     if args.export_bundle:
+        if cfg.agent.torso is not None:
+            raise SystemExit(
+                "--export-bundle: a torso actor acts on each session's last "
+                f"{cfg.agent.torso.window} observations through the critic's "
+                "torso; d4pg_tpu.serve holds no per-session state and a "
+                "bundle of the actor head alone would serve garbage — "
+                "refused (stateful serving sessions: ROADMAP B-m3)")
         export_bundle_from_run(cfg, args.export_bundle)
         return None
     if info is not None:
