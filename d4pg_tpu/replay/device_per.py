@@ -47,6 +47,18 @@ kernel that runs the same walk without the gathers
 ``TrainConfig.device_tree_backend="pallas"`` with the XLA path kept as
 its equivalence oracle.
 
+Writes (``set_leaves``: the post-step write-back and the ingest seed)
+are ONE scatter of the leaves, then the ancestors: level ``d`` is rebuilt
+whole — the pairwise f32 sum of the contiguous child level, in place in
+the donated buffer, no gather and no scatter (``rebuild_ancestors``) —
+when ``2^d <= n * DENSE_REPAIR_RATIO`` for ``n`` written positions, and
+repaired position by position (``repair_ancestors``, two gathers and a
+scatter a level) below that, so a handful of slots does not pay for the
+whole tree (``repair_plan``: the static shapes decide, no flag). Either
+way a parent is ``left + right`` in f32 and an untouched parent keeps its
+value, so the two give the same tree to the bit. Duplicate draws resolve
+last-wins by a two-key sort (``update_leaves_last_wins``).
+
 The traced functions here are listed in d4pglint's ``MEGASTEP_FUNCTIONS``
 manifest: host numpy / ``.item()`` inside them would smuggle a per-step
 host sync into the zero-transfer loop.
@@ -138,20 +150,104 @@ def _place_tree(tree: DevicePerTree, mesh) -> DevicePerTree:
 
 
 # ----------------------------------------------------- per-lane traced ops
-def repair_ancestors(sums_lane: jax.Array, pos: jax.Array) -> jax.Array:
-    """Recompute every ancestor of the leaf positions ``pos`` (``[n]``
+# How many streamed tree words one random tree access is worth on the chip:
+# level ``d`` (``2^d`` parents) is rebuilt densely when ``2^d <= n * R`` for
+# ``n`` written positions, else repaired position by position. Set from the
+# v5e (PERF.md section 5, PR 28): a position costs ~130 ns a level in a
+# 2^26-element tree (two gathers and a scatter) and a densely rebuilt parent
+# 0.041 ns, so the two meet at ``2^d = 3,170 n``; in a 2^22-element tree
+# (21-64 ns against 0.018) at 1,150-3,500 n.
+DENSE_REPAIR_RATIO = 2048
+# The dense pass pairs lanes of the ``[2L / 128, 128]`` view of the flat tree
+# (a bitcast of its 1-D tiled layout); levels narrower than this many
+# children take 1-D stride-2 slices instead (a few ops of ~1 us each).
+_LANES = 128
+_LANE_FORM_MIN_CHILDREN = 2048
+
+
+def repair_plan(width: int, n: int) -> tuple[int, int]:
+    """``(sparse_levels, dense_levels)`` of a write of ``n`` positions into
+    a ``[width]`` tree lane: the lowest ``sparse_levels`` parent levels are
+    repaired position by position (:func:`repair_ancestors`), the
+    ``dense_levels`` above them rebuilt whole (:func:`rebuild_ancestors`).
+    Decided by the two static shapes alone."""
+    depth = (width // 2).bit_length() - 1
+    # 2^d <= n R  <=>  d < bit_length(n R)
+    dense = min(depth, (n * DENSE_REPAIR_RATIO).bit_length())
+    return depth - dense, dense
+
+
+def describe_repair(width: int, n: int) -> dict:
+    """The static line that says which way a write of ``n`` positions into a
+    ``[width]`` lane is repaired (``Trainer`` logs it once, next to the
+    ring's ``describe_storage``)."""
+    sparse, dense = repair_plan(width, n)
+    return {
+        "tree_width": width, "positions": n, "sparse_levels": sparse,
+        "dense_levels": dense, "R": DENSE_REPAIR_RATIO,
+    }
+
+
+def repair_ancestors(
+    sums_lane: jax.Array, pos: jax.Array, levels: int | None = None
+) -> jax.Array:
+    """Recompute the ancestors of the leaf positions ``pos`` (``[n]``
     int32; out-of-bounds entries ``>= 2L`` stay out of bounds and are
-    dropped), one gather+scatter per level — the log-depth half of every
-    tree write. Duplicate parents all write the identical
-    children-derived value, so the scatter is deterministic."""
+    dropped) on the lowest ``levels`` parent levels (default: all of them,
+    up to the root), two gathers and one scatter of all ``n`` positions per
+    level. Duplicate parents all write the identical children-derived
+    value, so the scatter is deterministic. The sparse half of a tree
+    write — what :func:`set_leaves` keeps for the levels too wide for ``n``
+    (:func:`repair_plan`) — and the oracle the dense rebuild is held to,
+    bit for bit (``tests/test_tree_rebuild.py``)."""
     width = sums_lane.shape[0]
     depth = (width // 2).bit_length() - 1
-    for _ in range(depth):
+    for _ in range(depth if levels is None else levels):
         # Pads keep pointing past the end instead of dividing back into
         # range (capacity//2 would alias a real node).
         pos = jnp.where(pos < width, pos // 2, width)
         vals = sums_lane[2 * pos] + sums_lane[2 * pos + 1]
         sums_lane = sums_lane.at[pos].set(vals, mode="drop")
+    return sums_lane
+
+
+def rebuild_ancestors(sums_lane: jax.Array, levels: int) -> jax.Array:
+    """Recompute the top ``levels`` parent levels whole, lowest first: level
+    ``d`` is the pairwise f32 sum of the contiguous child level
+    ``sums[2^(d+1) : 2^(d+2)]``, written in place to ``sums[2^d : 2^(d+1)]``
+    — no gather and no scatter. A parent that no write touched already
+    equals the f32 sum of its children (every path that builds or writes a
+    tree keeps that), so it gets the value it has: the result is
+    bit-identical to :func:`repair_ancestors` on the touched positions.
+
+    What the v5e's compiler needs (PERF.md section 6, PR 28): the pairs are
+    summed as a ``(1, 2)`` ``reduce_window`` over the ``[2L/128, 128]`` view
+    of the whole lane, the child level picked by NEGATIVE row padding — a
+    ``slice`` of it is materialised as a copy of the level, a stride-2
+    ``slice`` de-interleaves at 16 GB/s, and a ``[.., 256]`` view is hoisted
+    above the slice and relayouts the whole tree once a level."""
+    for d in reversed(range(levels)):
+        lo = 2 << d                    # children [lo, 2 lo), parents [lo/2, lo)
+        if lo >= _LANE_FORM_MIN_CHILDREN:
+            rows = sums_lane.reshape(-1, _LANES)
+            first, last = lo // _LANES, 2 * lo // _LANES
+            parents = jax.lax.reduce_window(
+                rows, 0.0, jax.lax.add, (1, 2), (1, 2),
+                padding=((-first, last - rows.shape[0]), (0, 0)),
+            )
+            rows = jax.lax.dynamic_update_slice(
+                rows, parents.reshape(-1, _LANES), (first // 2, 0)
+            )
+            sums_lane = rows.reshape(-1)
+        else:
+            child = jax.lax.slice(sums_lane, (lo,), (2 * lo,))
+            parents = (
+                jax.lax.slice(child, (0,), (lo,), (2,))
+                + jax.lax.slice(child, (1,), (lo,), (2,))
+            )
+            sums_lane = jax.lax.dynamic_update_slice(
+                sums_lane, parents, (lo // 2,)
+            )
     return sums_lane
 
 
@@ -161,8 +257,10 @@ def set_leaves(
 ) -> jax.Array:
     """Assign leaf values at ring slots (``slots`` int32; pad entries
     ``>= local_capacity`` are dropped — the ring ingest's pad-slot
-    convention) and repair ancestors. ``values`` may be a scalar (the
-    max-priority ingest seed) or ``[n]``."""
+    convention) with ONE scatter, then bring the ancestors back in line:
+    position by position on the levels too wide for ``len(slots)`` writes,
+    densely above them (:func:`repair_plan`). ``values`` may be a scalar
+    (the max-priority ingest seed) or ``[n]``."""
     width = sums_lane.shape[0]
     half = width // 2
     pos = jnp.where(slots < local_capacity, slots + half, width).astype(
@@ -170,7 +268,10 @@ def set_leaves(
     )
     vals = jnp.broadcast_to(values, pos.shape).astype(jnp.float32)
     sums_lane = sums_lane.at[pos].set(vals, mode="drop")
-    return repair_ancestors(sums_lane, pos)
+    sparse, dense = repair_plan(width, pos.shape[0])
+    if sparse:
+        sums_lane = repair_ancestors(sums_lane, pos, levels=sparse)
+    return rebuild_ancestors(sums_lane, dense)
 
 
 def update_leaves_last_wins(
@@ -181,17 +282,16 @@ def update_leaves_last_wins(
     slot appears more than once in ``idx`` (one transition drawn into
     several rows of a [K, B] block), the LAST occurrence wins — numpy
     assignment order, which a bare XLA scatter does not guarantee. A
-    deterministic scatter-max over flat positions picks each slot's last
-    occurrence; losers are routed out of bounds and dropped."""
+    two-key sort of the ``(slot, order)`` pairs puts each slot's
+    occurrences side by side in draw order, so the winner is the last of
+    its run; losers are routed out of bounds and dropped. (No
+    capacity-sized scratch array: a scatter-max into one, its memset and
+    its gather were 1.06 ms a dispatch at a 2^25-row ring.)"""
     idx = idx.reshape(-1).astype(jnp.int32)
     vals = values.reshape(-1).astype(jnp.float32)
     order = jnp.arange(idx.shape[0], dtype=jnp.int32)
-    latest = (
-        jnp.full((local_capacity,), -1, jnp.int32)
-        .at[idx]
-        .max(order, mode="drop")
-    )
-    win = latest[idx] == order
+    idx, _, vals = jax.lax.sort((idx, order, vals), num_keys=2)
+    win = jnp.concatenate([idx[1:] != idx[:-1], jnp.ones((1,), bool)])
     slots = jnp.where(win, idx, local_capacity)
     return set_leaves(sums_lane, slots, vals, local_capacity)
 

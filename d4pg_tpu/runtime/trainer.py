@@ -510,7 +510,10 @@ class Trainer:
                     # through the ring sync's tree_hook (same staged slot
                     # arrays — zero extra H2D, rows and leaves can never
                     # desync).
-                    from d4pg_tpu.replay.device_per import DevicePerSync
+                    from d4pg_tpu.replay.device_per import (
+                        DevicePerSync,
+                        describe_repair,
+                    )
 
                     self._dev_per = DevicePerSync(
                         config.replay_capacity,
@@ -518,6 +521,16 @@ class Trainer:
                         mesh=self._mega_mesh,
                     )
                     self._ring_sync.tree_hook = self._dev_per.on_chunk
+                    # Static per (lane width, positions written): which
+                    # levels a write-back repairs position by position and
+                    # which it rebuilds whole (device_per.repair_plan).
+                    print(
+                        "[replay] device tree repair: "
+                        + json.dumps(describe_repair(
+                            self._dev_per.tree.sums.shape[1],
+                            K * (config.batch_size // (config.dp or 1)),
+                        ))
+                    )
                 if self._mega_mesh is not None:
                     # Sharded megastep (ROADMAP item 2): state placed per
                     # the partition-rule registry, ring rows striped over

@@ -62,12 +62,12 @@ def _on(sharding, tree):
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), tree)
 
 
-def _ring_shapes(make, width):
+def _ring_shapes(make, width, capacity=CAPACITY):
     f32 = lambda *shape: jnp.zeros(shape, jnp.float32)  # noqa: E731
     return jax.eval_shape(lambda: make(
-        obs=f32(CAPACITY, width), action=f32(CAPACITY, ACTION),
-        reward=f32(CAPACITY), next_obs=f32(CAPACITY, width),
-        discount=f32(CAPACITY), size=jnp.zeros((), jnp.int32)))
+        obs=f32(capacity, width), action=f32(capacity, ACTION),
+        reward=f32(capacity), next_obs=f32(capacity, width),
+        discount=f32(capacity), size=jnp.zeros((), jnp.int32)))
 
 
 INSTRUCTION = re.compile(
@@ -153,3 +153,62 @@ def test_narrow_rows_compile_to_the_plain_index(one_chip):
         texts.append(_program(lowered.compile().as_text()))
     assert "gather" in texts[0]
     assert texts[0] == texts[1]
+
+
+# ------------------------------------------------ the PER tree's write-back
+# ISSUE 28: the ancestors of a write-back's leaves are rebuilt densely
+# (``replay/device_per.py:rebuild_ancestors``). At the cells' own tree sizes
+# the compiled write-back phase holds ONE scatter into the tree and no gather
+# from it, the rebuild updates the donated buffer in place (no ``copy`` of
+# the tree, no second buffer of its size), and its temporaries stay within a
+# tree's internal half.
+WRITE_BACK_K, WRITE_BACK_B = 32, 256    # 8,192 positions a dispatch: the cells'
+
+
+def _per_megastep(sharding, tree_elements, n_rows):
+    from d4pg_tpu.agent import create_train_state
+    from d4pg_tpu.replay.device_per import DevicePerTree, tree_width
+    from d4pg_tpu.runtime.megastep import make_megastep_device_per
+
+    cfg = D4PGConfig(obs_dim=NARROW, action_dim=ACTION, hidden_sizes=(64, 64),
+                     dist=DistConfig(num_atoms=51, v_min=0.0, v_max=1000.0))
+    assert tree_width(n_rows) == tree_elements
+    args = _on(sharding, (
+        jax.eval_shape(lambda: create_train_state(cfg, jax.random.PRNGKey(0))),
+        _ring_shapes(DeviceRing, NARROW, capacity=n_rows),
+        DevicePerTree(jnp.zeros((1, tree_elements), jnp.float32),
+                      jnp.zeros((), jnp.float32)),
+        jax.eval_shape(lambda: jax.random.PRNGKey(0))))
+    return make_megastep_device_per(
+        cfg, WRITE_BACK_K, WRITE_BACK_B).lower(*args).compile()
+
+
+@pytest.mark.parametrize("tree_elements", [2 ** 26, 2 ** 22])
+def test_write_back_is_one_scatter_and_an_in_place_dense_rebuild(
+        one_chip, tree_elements):
+    from d4pg_tpu.replay import device_per as dper
+
+    half = tree_elements // 2
+    levels = half.bit_length() - 1
+    assert dper.repair_plan(tree_elements, WRITE_BACK_K * WRITE_BACK_B) == (0, levels)
+    # levels whose child level is too narrow for the lane form: 1-D slices
+    narrow = dper._LANE_FORM_MIN_CHILDREN.bit_length() - 2
+    compiled = _per_megastep(one_chip, tree_elements, n_rows=half)
+    text = compiled.as_text()
+    tree = rf"f32\[(?:1,)?{tree_elements}\]"
+    phase = [line for line in text.splitlines()
+             if "ph:replay.write_back" in line and " = " in line]
+    assert phase, "the write-back lost its scope"
+    scatters = [line for line in phase if re.search(r" scatter\(", line)]
+    assert len(scatters) == 1 and re.search(rf"= {tree}", scatters[0]), scatters
+    assert not [line for line in phase if re.search(r" gather\(", line)]
+    # one reduce-window a lane-form level, one in-place update every level
+    assert len([line for line in phase if " reduce-window(" in line]) == levels - narrow
+    updates = [line for line in phase if re.search(r" dynamic-update-slice\(", line)]
+    assert len(updates) == levels
+    # no copy of the tree anywhere in the program, and no room for a second
+    # one: the largest temporary is the lowest level's lane-padded sums
+    assert not re.findall(rf"= {tree}\S* copy\(", text)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes <= 4 * half + (16 << 20), (
+        memory.temp_size_in_bytes, 4 * half)

@@ -209,6 +209,7 @@ MEGASTEP_FUNCTIONS = (
     # The device priority tree's traced primitives (replay/device_per.py):
     # every one is traced into the megastep or the per-flush tree seed.
     "d4pg_tpu/replay/device_per.py::repair_ancestors",
+    "d4pg_tpu/replay/device_per.py::rebuild_ancestors",
     "d4pg_tpu/replay/device_per.py::set_leaves",
     "d4pg_tpu/replay/device_per.py::update_leaves_last_wins",
     "d4pg_tpu/replay/device_per.py::stratified_prefixes",
