@@ -81,8 +81,8 @@ class D4PGConfig:
     # bf16 policy is: fp32 master weights / Adam moments / Polyak targets
     # and fp32 loss accumulation always; bf16 activations through the
     # actor/critic trunks; target-network params cast to bf16 once per
-    # train step (forward-only — halves target-path param bytes, the
-    # HBM-bound part of the step per bench.py's roofline).
+    # train step (forward-only — halves target-path param bytes; what
+    # that buys on the chip is not measured, PERF.md §7).
     compute_dtype: str = "float32"
     # categorical projection implementation, an oracle ladder:
     #   "xla"          — one-hot matmul reference (ops/categorical.py);

@@ -23,8 +23,8 @@ Two entry points:
   in HBM, in either the forward or the backward pass. The XLA path writes
   a [B, A_src, A_dst] one-hot weight tensor plus the [B, A] projection per
   step (≈2.7 MB at the flagship B=256, A=51 — the single largest loss-side
-  HBM tensor of the train step, on a workload that bench.py places AT the
-  HBM wall, xla_bytes_util ≈ 1.3); the fused kernel reads the four [B, A]/
+  HBM tensor of the train step; no benchmark cell runs this tier, so what
+  it buys on the chip is not measured, PERF.md §7); it reads the four [B, A]/
   [B] inputs and writes two [B] vectors. The backward pass REcomputes Φ in
   VMEM (A passes of VPU work — cheap; the workload is bytes-bound, not
   flops-bound) instead of saving it, so the only residuals are arrays that

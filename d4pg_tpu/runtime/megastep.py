@@ -12,21 +12,21 @@ host write-back).  Zero H2D/D2H per grad step in steady state; the PR-4
 transfer guard enforces it at the dispatch site with the tightened
 zero-transfer budget (``analysis.transfer.no_transfers``).
 
-Two placements (``TrainConfig.replay_placement``):
+Three megasteps, by ``TrainConfig.replay_placement`` and PER:
 
-- ``device`` — uniform replay, index draw **in-kernel** via
-  ``jax.random.randint`` from a device-resident key that the megastep
-  splits and returns (no host operand at all: state, ring, key all live
-  on device between dispatches);
-- ``hybrid`` — PER: the host sum-tree computes indices + IS weights
-  (``PrioritizedReplayBuffer.sample_block_indices``, the exact seeded
-  stream of ``sample_block``) and ships only the tiny ``[K, B]`` int32
-  index / f32 weight arrays; rows are gathered on-device, priorities come
-  back as one ``[K, B]`` block per dispatch.
+- ``device``, uniform — index draw **in-kernel** via ``jax.random.randint``
+  from a device-resident key that the megastep splits and returns (no host
+  operand at all: state, ring, key all live on device between dispatches);
+- ``device``, PER — the priority tree lives on the device too
+  (``replay/device_per.py``): descent, IS weights and write-back run inside
+  the same call. The form every benchmark cell measures (PERF.md §5);
+- ``hybrid`` — PER on the host sum-tree, which ships only the ``[K, B]``
+  int32 index / f32 weight arrays; rows are gathered on-device, priorities
+  come back as one ``[K, B]`` block per dispatch. ``host`` has no megastep.
 
 The batch gather happens ONCE before the scan (``gather_batches``), not
-per scan step — measured ~2.2× on v5e (per-step PRNG + scattered HBM reads
-dominate otherwise); everything still lives inside the single jitted call.
+per scan step (per-step PRNG + scattered HBM reads otherwise; its cost on
+the chip is ``replay.row_gather_ms``); all inside the single jitted call.
 
 The ``*_body`` functions here are jit-traced (see the makers below) and
 listed in d4pglint's ``MEGASTEP_FUNCTIONS`` manifest: host numpy,

@@ -10,8 +10,8 @@ Per-stage pipeline telemetry: ``log(..., timers=StageTimers)`` appends the
 cumulative host data-plane counters — ``stage_<name>_s`` seconds and
 ``stage_<name>_calls`` for each of env_step / replay_insert / sample /
 h2d_stage / train_dispatch / priority_writeback — to every row, so a
-training run's metrics.jsonl carries the same breakdown
-``bench.py bench_host_pipeline`` measures (schema: docs/data_plane.md).
+training run's metrics.jsonl carries the breakdown the trace shows as
+``host/<stage>`` annotations (schema: docs/data_plane.md).
 """
 
 from __future__ import annotations

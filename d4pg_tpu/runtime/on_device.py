@@ -82,9 +82,9 @@ def _encode_obs(x: jax.Array, obs_dtype, scale: float = 255.0) -> jax.Array:
     """Same contract as the host ``ReplayBuffer._encode_obs``
     (``replay/uniform.py``): store ``clip(rint(x·scale), 0, 255)`` —
     ``scale`` is 255 for [0,1]-float envs, 1.0 for byte-image envs.
-    ``bfloat16`` stores flat observations at half the HBM bytes — the ring
-    GATHER is the flagship workload's bandwidth bottleneck (bench.py
-    roofline), so halving row bytes is a direct throughput lever; 8 bits
+    ``bfloat16`` stores flat observations at half the HBM bytes — the row
+    gather is a large share of a wide-row dispatch (PERF.md §5; this loop
+    itself never ran on a chip), so row bytes are a throughput lever; 8 bits
     of mantissa cost ~1e-2 relative obs noise, the same magnitude as the
     exploration noise already injected on purpose."""
     if obs_dtype == jnp.uint8:

@@ -684,7 +684,7 @@ class Trainer:
         # Per-stage data-plane wall-time counters (env-step / replay-insert
         # / sample / H2D-stage / train-dispatch / priority-write-back),
         # shared by every thread and appended to each metrics.jsonl row —
-        # the per-stage view bench_host_pipeline summarizes.
+        # the per-stage view of a run's host data plane.
         self._timers = StageTimers()
         if self._placement != "host":
             # Pin the megastep stages into every row from the start, and —

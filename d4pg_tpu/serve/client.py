@@ -2,8 +2,8 @@
 
 ``act`` is the simple call; ``act_async`` pipelines — many requests in
 flight on one connection, matched to replies by the echoed ``req_id`` on a
-dedicated reader thread. The pipelined form is what the open-loop load
-generator (``bench.py bench_serve``) is built on: an open-loop arrival
+dedicated reader thread. The pipelined form is what an open-loop load
+generator needs (and the router's fault tests use): an open-loop arrival
 process must keep issuing at its offered rate regardless of reply latency,
 which a blocking call cannot do.
 

@@ -1,6 +1,6 @@
 """Where XLA's persistent compilation cache lives — decided in one place.
 
-Every entry point that compiles (``train.py``, ``bench.py``,
+Every entry point that compiles (``train.py``, ``cellbench/run.py``,
 ``chip_smoke.py``, ``__graft_entry__.py``, ``python -m d4pg_tpu.serve``)
 calls :func:`configure_compile_cache` first thing. Without it each process
 recompiles the planar physics, the megastep and the eval rollout cold —

@@ -15,8 +15,8 @@ The reference's only timing is wall-clock deltas in train logs
 - :class:`StageTimers` keeps cumulative wall-time counters per host
   data-plane stage (env_step / replay_insert / sample / h2d_stage /
   train_dispatch / priority_writeback) that flow into ``metrics.jsonl``
-  (via :class:`~d4pg_tpu.runtime.MetricsLogger`) and into
-  ``bench.py bench_host_pipeline`` — the schema is in docs/data_plane.md.
+  (via :class:`~d4pg_tpu.runtime.MetricsLogger`) and onto the trace as
+  ``host/<stage>`` annotations — the schema is in docs/data_plane.md.
 
 Throughput counters (grad-steps/sec, env-steps/sec, replay occupancy) are
 emitted continuously by :class:`d4pg_tpu.runtime.MetricsLogger`.

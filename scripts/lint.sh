@@ -3,9 +3,9 @@
 # required — per-file AST checks, the whole-program pass [lock-order
 # graph, protocol conformance, thread lifecycle, unused suppressions],
 # the docs-catalog drift check, and the shape-aware partition-rule
-# coverage gate in a subprocess) + the benchmark/metrics JSON schema
-# check (which also pins benchmarks/lock_order_graph.json acyclic and
-# fresh). Wired into tier-1 both directly (scripts/tier1.sh runs this
+# coverage gate in a subprocess) + the JSON schema check of the
+# committed static-analysis and soak artifacts and of metrics logs (which
+# also pins benchmarks/lock_order_graph.json acyclic and fresh). Wired into tier-1 both directly (scripts/tier1.sh runs this
 # first) and as tests (tests/test_d4pglint.py::test_repo_lints_clean,
 # tests/test_wholeprog.py), so the driver's verbatim ROADMAP pytest
 # command enforces it too.

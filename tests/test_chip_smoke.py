@@ -8,8 +8,7 @@ when it is not the one they were asked to use.
   alone and otherwise resolves to one fixed in-checkout path;
 - the Pallas interpret switch compiles on ``tpu``, interprets on ``cpu`` and
   raises on anything else;
-- ``bench.py`` / ``__graft_entry__.py`` have no CPU fallback, and an unknown
-  accelerator has no made-up roofline;
+- ``__graft_entry__.py`` has no CPU fallback;
 - a ring too large for a Pallas tree tier is refused by name at config
   validation.
 """
@@ -159,39 +158,7 @@ class TestPallasInterpretSwitch:
             pallas_interpret()
 
 
-class _FakeDevice:
-    def __init__(self, platform, kind):
-        self.platform, self.device_kind = platform, kind
-
-
 class TestNoFallback:
-    def test_bench_refuses_an_unasked_for_cpu_backend(self, monkeypatch):
-        import bench
-
-        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-        with pytest.raises(SystemExit, match="no accelerator"):
-            bench.require_backend()
-        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-        assert bench.require_backend()["platform"] == "cpu"
-
-    def test_roofline_fields_need_a_known_chip(self, monkeypatch):
-        import jax
-
-        import bench
-
-        # a CPU timing is not a device metric: no fields at all
-        assert bench.mfu_fields(100.0, 1e9, 1e6) == {}
-        monkeypatch.setattr(
-            jax, "devices", lambda *a: [_FakeDevice("tpu", "TPU v5 lite")]
-        )
-        known = bench.mfu_fields(100.0, 1e9, 1e6)
-        assert known["peak_tflops"] == 197.0 and known["peak_gbps"] == 819.0
-        monkeypatch.setattr(
-            jax, "devices", lambda *a: [_FakeDevice("tpu", "TPU v99")]
-        )
-        with pytest.raises(KeyError, match="TPU v99"):
-            bench.mfu_fields(100.0, 1e9, 1e6)
-
     def test_dryrun_needs_the_devices_it_was_asked_for(self, monkeypatch):
         import __graft_entry__ as graft
 

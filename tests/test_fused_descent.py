@@ -18,8 +18,6 @@ ride the slow tier.
 """
 
 import dataclasses
-import json
-import os
 
 import jax
 import jax.numpy as jnp
@@ -551,79 +549,3 @@ class TestFusedTrainerGuards:
             f"{base['eval_return_mean']:.0f} -> trained "
             f"{trained['eval_return_mean']:.0f}"
         )
-
-
-# --------------------------------------------- committed artifact + schema
-class TestMfuSweepArtifact:
-    """The committed large-batch recipe row (benchmarks/
-    mfu_sweep_results.json) and the lint gate that refuses to lose it."""
-
-    ARTIFACT = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "benchmarks", "mfu_sweep_results.json",
-    )
-
-    def _rows(self):
-        with open(self.ARTIFACT) as f:
-            return json.load(f)
-
-    def test_committed_large_batch_row(self):
-        rows = self._rows()
-        lb = [
-            r for r in rows
-            if str(r.get("config", "")).startswith("large_batch")
-        ]
-        assert lb, "mfu_sweep_results.json lost its large-batch recipe row"
-        for r in lb:
-            assert r["bench"] == "mfu_sweep"
-            assert "backend" in r  # CPU placeholders must be distinguishable
-            assert r["batch"] >= 2048  # the MXU-filling shape, not a toy
-            assert r["compute_dtype"] == "bfloat16"
-            assert r["transfer_bytes_per_grad_step"] == 0.0
-            assert r["steps_per_sec"] > 0
-            # the >=2x-flagship-MFU claim, anchored to on-chip rows
-            assert r["mfu_onchip_proxy"]["ratio_vs_flagship"] >= 2.0
-            # the ready-to-run on-chip recipe is the row's other half
-            assert "--fused-descent" in r["recipe"]
-            assert "--batch-scale" in r["recipe"]
-        # every other family survived the --large-batch-only regen
-        for family in ("mlp256", "megastep_mlp256", "device_per_megastep",
-                       "sharded_megastep"):
-            assert any(
-                str(r.get("config", "")).startswith(family) for r in rows
-            ), f"--large-batch-only regen clobbered the {family} family"
-
-    def test_schema_check_accepts_committed_and_refuses_mutants(self, tmp_path):
-        from tools.d4pglint.schema_check import check_mfu_sweep
-
-        assert check_mfu_sweep(self.ARTIFACT) == []
-        rows = self._rows()
-
-        def _write(mutant_rows):
-            p = tmp_path / "mfu_sweep_results.json"
-            p.write_text(json.dumps(mutant_rows))
-            return str(p)
-
-        # dropping the row (a regen without --large-batch) must fail lint
-        errs = check_mfu_sweep(_write([
-            r for r in rows
-            if not str(r.get("config", "")).startswith("large_batch")
-        ]))
-        assert errs and "large-batch" in errs[0]
-        # nonzero transfer bytes on the fused tier must fail lint
-        bad = json.loads(json.dumps(rows))
-        for r in bad:
-            if str(r.get("config", "")).startswith("large_batch"):
-                r["transfer_bytes_per_grad_step"] = 12.0
-        assert any(
-            "zero-transfer" in e for e in check_mfu_sweep(_write(bad))
-        )
-        # a sub-MXU batch or a sub-2x proxy is not the committed claim
-        bad = json.loads(json.dumps(rows))
-        for r in bad:
-            if str(r.get("config", "")).startswith("large_batch"):
-                r["batch"] = 256
-                r["mfu_onchip_proxy"]["ratio_vs_flagship"] = 1.3
-        errs = check_mfu_sweep(_write(bad))
-        assert any("B >= 2048" in e for e in errs)
-        assert any("2x the flagship" in e for e in errs)

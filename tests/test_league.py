@@ -522,7 +522,7 @@ def test_controller_kill_chaos_site_roundtrip(tmp_path):
     first = subprocess.run(
         _controller_argv(
             league, chaos="seed=5;variant_kill@2;clone_corrupt@1;"
-                          "controller_kill@8",
+                          "controller_kill@3",
         ),
         cwd=REPO, capture_output=True, text=True, timeout=120,
     )

@@ -30,22 +30,15 @@ Contracts under test, in dependency order:
 from __future__ import annotations
 
 import os
+import socket
 import subprocess
 import sys
+import textwrap
 
 import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
-
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "benchmarks"))
-
-from multihost_microbench import (  # noqa: E402
-    compare_npz,
-    child_env,
-    free_port,
-    run_exact_topology,
-)
 
 from d4pg_tpu.parallel import make_mesh  # noqa: E402
 from d4pg_tpu.replay.device_ring import (  # noqa: E402
@@ -56,6 +49,206 @@ from d4pg_tpu.replay.device_ring import (  # noqa: E402
 from d4pg_tpu.replay.uniform import ReplayBuffer, Transition  # noqa: E402
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+# ------------------------------------------------------- topology child
+# One script, two topologies: ``nprocs`` 1 (the 8-device single-process
+# oracle) or 2 (2 × 4-device jax.distributed over gloo). Every process
+# deals itself the global write stream rows its shards own — the global
+# writes k with (k % D) // L == rank, in increasing k order — so the
+# interleaved stream is identical across topologies by construction.
+CHILD_EXACT = textwrap.dedent(
+    """
+    import sys
+    nprocs, rank, out = int(sys.argv[1]), int(sys.argv[2]), sys.argv[3]
+    import os
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["XLA_FLAGS"] = (
+        f"--xla_force_host_platform_device_count={8 // nprocs}"
+    )
+    sys.path.insert(0, __REPO__)
+    import numpy as np
+    import jax
+    if nprocs > 1:
+        from d4pg_tpu.parallel import initialize_distributed
+        initialize_distributed(
+            coordinator_address=__COORD__,
+            num_processes=nprocs, process_id=rank,
+        )
+    from jax import shard_map
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from d4pg_tpu.agent import D4PGConfig, create_train_state
+    from d4pg_tpu.models.critic import DistConfig
+    from d4pg_tpu.parallel import make_mesh, shard_train_state
+    from d4pg_tpu.parallel.distributed import gather_global, stage_global
+    from d4pg_tpu.parallel.dp import det_pmean
+    from d4pg_tpu.replay.device_per import DevicePerSync
+    from d4pg_tpu.replay.device_ring import MultihostRingSync, device_ring_init
+    from d4pg_tpu.replay.uniform import ReplayBuffer, Transition
+    from d4pg_tpu.runtime.megastep import make_megastep_device_per_sharded
+    from d4pg_tpu.analysis import no_transfers
+
+    D, K, B, C = 8, 2, 16, 128
+    L = D // nprocs
+    cfg = D4PGConfig(obs_dim=3, action_dim=1, hidden_sizes=(16, 16),
+                     dist=DistConfig(num_atoms=11, v_min=-5.0, v_max=5.0))
+    mesh = make_mesh(dp=D, tp=1)
+
+    # One deterministic GLOBAL write stream, identical on every process
+    # (same seed); each process adds only its deal — the global writes k
+    # with (k % D) // L == rank, in increasing k order (host p's m-th
+    # local write IS global write (m//L)*D + p*L + (m%L)).
+    N1, N2 = 96, 64
+    r = np.random.default_rng(0)
+    g = dict(
+        obs=r.normal(size=(N1 + N2, 3)).astype(np.float32),
+        action=r.uniform(-1, 1, (N1 + N2, 1)).astype(np.float32),
+        reward=r.uniform(-1, 0, N1 + N2).astype(np.float32),
+        next_obs=r.normal(size=(N1 + N2, 3)).astype(np.float32),
+        discount=np.full(N1 + N2, 0.99, np.float32),
+    )
+    def add_deal(buf, lo, hi):
+        mine = [k for k in range(lo, hi) if (k % D) // L == rank]
+        buf.add_batch(Transition(*(g[f][mine] for f in
+            ("obs", "action", "reward", "next_obs", "discount"))))
+
+    buf = ReplayBuffer(C // nprocs, 3, 1)
+    ring = device_ring_init(C, 3, 1, mesh=mesh)
+    sync = MultihostRingSync(buf, mesh, chunk_cap=64)
+    per = DevicePerSync(C, alpha=0.6, mesh=mesh)
+    sync.tree_hook = per.on_chunk
+    mega = make_megastep_device_per_sharded(cfg, K, B, mesh)
+    state = shard_train_state(create_train_state(cfg, jax.random.PRNGKey(1)), mesh)
+    key = stage_global(mesh, P(), np.asarray(jax.random.PRNGKey(7)))
+
+    met = None
+    for lo, hi in ((0, N1), (N1, N1 + N2)):
+        add_deal(buf, lo, hi)
+        ring = sync.flush(ring)
+        for _ in range(2):
+            state, per.tree, key, met = mega(state, ring, per.tree, key)
+    # steady state is zero-transfer on THIS topology too: even an
+    # explicit device_put (or any D2H fetch) inside this dispatch raises
+    with no_transfers():
+        state, per.tree, key, met = mega(state, ring, per.tree, key)
+    print(f"proc {rank} ZERO_TRANSFER_DISPATCH_OK")
+
+    # det_pmean over the process-spanning mesh: fixed-order reduction
+    vals = stage_global(
+        mesh, P("dp", None),
+        (np.arange(D * 4, dtype=np.float32) / 7.0).reshape(D, 4) ** 2,
+    )
+    red = jax.jit(
+        shard_map(lambda x: det_pmean(x, "dp", D), mesh=mesh,
+                  in_specs=P("dp", None), out_specs=P(), check_vma=False),
+        out_shardings=NamedSharding(mesh, P()),
+    )(vals)
+    # shard-local in-kernel draws: fold_in(GLOBAL shard index)
+    draws = jax.jit(
+        shard_map(
+            lambda k: jax.random.uniform(
+                jax.random.fold_in(k[0], jax.lax.axis_index("dp")), (1, 4)
+            ),
+            mesh=mesh, in_specs=P(None), out_specs=P("dp", None),
+            check_vma=False,
+        ),
+        out_shardings=NamedSharding(mesh, P("dp", None)),
+    )(stage_global(mesh, P(None), np.asarray(jax.random.PRNGKey(11))[None]))
+
+    snap = sync.gather_snapshot(ring)          # collective
+    pa, mp = per.snapshot_host()               # collective
+    leaves = [gather_global(x) for x in jax.tree_util.tree_leaves(state)]
+    payload = {f"state_{i}": a for i, a in enumerate(leaves)}
+    payload.update(snap)
+    payload["per_pa"] = pa
+    payload["per_mp"] = np.float32(mp)
+    payload["det_pmean"] = gather_global(red)
+    payload["draws"] = gather_global(draws)
+    payload["critic_loss"] = gather_global(met["critic_loss"])
+    if rank == 0:
+        np.savez(out, **payload)
+    print(f"proc {rank} EXACT_OK")
+    """
+)
+
+def free_port() -> int:
+    s = socket.socket()
+    s.bind(("127.0.0.1", 0))
+    port = s.getsockname()[1]
+    s.close()
+    return port
+
+
+def child_env() -> dict:
+    return {
+        k: v
+        for k, v in os.environ.items()
+        # the child script pins its own platform, device count and import
+        # path; it must not inherit this process's
+        if k not in ("JAX_PLATFORMS", "XLA_FLAGS", "PYTHONPATH")
+    }
+
+
+def run_exact_topology(workdir: str, nprocs: int, timeout: int = 420) -> str:
+    """Run the topology child at ``nprocs`` (1 or 2); returns the npz path
+    process 0 wrote. Raises on any nonzero child or missing OK marker."""
+    out = os.path.join(workdir, f"exact_p{nprocs}.npz")
+    script = os.path.join(workdir, f"child_p{nprocs}.py")
+    coord = f"127.0.0.1:{free_port()}"
+    with open(script, "w") as f:
+        f.write(
+            CHILD_EXACT.replace("__REPO__", repr(REPO)).replace(
+                "__COORD__", repr(coord)
+            )
+        )
+    procs = [
+        subprocess.Popen(
+            [sys.executable, script, str(nprocs), str(rank), out],
+            stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
+            env=child_env(), text=True,
+        )
+        for rank in range(nprocs)
+    ]
+    outs = [p.communicate(timeout=timeout)[0] for p in procs]
+    for rank, (p, text) in enumerate(zip(procs, outs)):
+        if p.returncode != 0:
+            raise RuntimeError(
+                f"topology child nprocs={nprocs} rank {rank} rc="
+                f"{p.returncode}:\n{text}"
+            )
+        for marker in (f"proc {rank} EXACT_OK",
+                       f"proc {rank} ZERO_TRANSFER_DISPATCH_OK"):
+            if marker not in text:
+                raise RuntimeError(
+                    f"topology child nprocs={nprocs} rank {rank} missing "
+                    f"{marker!r}:\n{text}"
+                )
+    return out
+
+
+def compare_npz(a_path: str, b_path: str) -> dict:
+    """Byte-compare two topology payloads: same keys, same dtypes, same
+    bits. Returns counts + any mismatching key names."""
+    mismatches = []
+    with np.load(a_path) as a, np.load(b_path) as b:
+        if sorted(a.files) != sorted(b.files):
+            mismatches.append(
+                f"key sets differ: {sorted(a.files)} vs {sorted(b.files)}"
+            )
+            keys = sorted(set(a.files) & set(b.files))
+        else:
+            keys = sorted(a.files)
+        state_leaves = sum(1 for k in keys if k.startswith("state_"))
+        for k in keys:
+            if a[k].dtype != b[k].dtype:
+                mismatches.append(f"{k}: dtype {a[k].dtype} vs {b[k].dtype}")
+            elif not np.array_equal(a[k], b[k]):
+                mismatches.append(f"{k}: bits differ")
+    return {
+        "keys_compared": len(keys),
+        "state_leaves": state_leaves,
+        "mismatches": mismatches,
+    }
 
 
 def _fill(buf, n, seed=0):
@@ -252,8 +445,7 @@ def test_two_process_mesh_bit_exact_vs_single_process_oracle(tmp_path):
     assembled ring, the device-PER tree sidecar, det_pmean reductions,
     fold_in(global shard index) draws, and the loss metrics. Each
     topology also proves the zero-transfer steady state (the child
-    dispatches once under no_transfers). Drives the same child the
-    committed multihost_microbench.json attestation is generated from."""
+    dispatches once under no_transfers)."""
     single = run_exact_topology(str(tmp_path), 1)
     multi = run_exact_topology(str(tmp_path), 2)
     res = compare_npz(single, multi)

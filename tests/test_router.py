@@ -9,6 +9,7 @@ The subprocess/CLI half of this surface lives in scripts/router_smoke.sh
 here is in-process so kill instants and reload instants are deterministic.
 """
 
+import socket
 import threading
 import time
 
@@ -16,9 +17,9 @@ import jax
 import numpy as np
 import pytest
 
-from bench import kill_policy_server_abruptly
 from d4pg_tpu.agent import act_deterministic
 from d4pg_tpu.agent.state import D4PGConfig
+from d4pg_tpu.analysis import flowledger
 from d4pg_tpu.serve import (
     PolicyBundle,
     PolicyClient,
@@ -81,6 +82,20 @@ def _router(servers, **kw):
     r.start()
     r.wait_for_replicas(len(servers), timeout_s=60)
     return r
+
+
+def kill_policy_server_abruptly(server) -> None:
+    """Simulate SIGKILL on an in-process :class:`PolicyServer`: abortive-
+    close the listener and every live connection (peers see an RST —
+    exactly a killed process's teardown as observed from the wire), no
+    drain, nothing answered. The REAL ``kill -9`` path runs through
+    subprocess replicas in scripts/router_smoke.sh and chaos_soak.sh."""
+    server._shutdown.set()
+    server._loop.stop_accepting()
+    for c in server._loop.connections():
+        c.abort()  # RST, queued replies dropped — wire-identical to kill -9
+    server._loop.close(flush_timeout_s=0.5)
+    server.batcher.stop(drain=False, timeout=5)
 
 
 def _drain_all(router, servers, killed=()):
@@ -760,3 +775,73 @@ def test_bundle_mtime_attests_only_successful_reloads(tmp_path):
             )
     finally:
         srv.drain()
+
+
+# ------------------------------------------------ idle connections are free
+@pytest.fixture
+def armed_ledger():
+    """``--debug-guards``' conservation ledger: an imbalance raises out of
+    the drain."""
+    flowledger.enable()
+    yield
+    flowledger.reset()
+
+
+@pytest.mark.parametrize("conns", [50, 300])
+@pytest.mark.parametrize("front", ["server", "router"])
+def test_idle_connections_cost_no_threads(front, conns, armed_ledger):
+    """``netio.FrameLoop``'s reason to exist: a front end holds its client
+    connections on ONE event-loop thread, so the thread count does not grow
+    with the connection count (a thread-per-connection front end grows by
+    ``conns`` here). Beside the idle population an interactive client keeps
+    getting right answers, and the front end's flow identity is exact after
+    the drain. No clock is asserted."""
+    server = _server()
+    router = _router([server]) if front == "router" else None
+    target = router if router is not None else server
+    idle = []
+    try:
+        with PolicyClient("127.0.0.1", target.port) as c:
+            ref = _ref(PARAMS)
+            # every constant-count thread (replica link reader, prober,
+            # batcher) exists before the empty front end is counted
+            for _ in range(8):
+                c.act(OBS)
+            held_empty = target.healthz()["netio"]["conns_open"]
+            threads_empty = threading.active_count()
+            while len(idle) < conns:
+                # backlog-sized bursts: the accept loop is bounded a tick
+                idle += [
+                    socket.create_connection(
+                        ("127.0.0.1", target.port), timeout=15
+                    )
+                    for _ in range(min(64, conns - len(idle)))
+                ]
+                _wait(
+                    lambda: target.healthz()["netio"]["conns_open"]
+                    >= held_empty + len(idle),
+                    msg=f"{len(idle)} idle connections accepted",
+                )
+            growth = threading.active_count() - threads_empty
+            assert growth <= 4, (
+                f"{growth} threads for {conns} idle connections: the loop "
+                "must hold them on O(1) threads"
+            )
+            for _ in range(20):
+                np.testing.assert_allclose(
+                    c.act(OBS), ref, rtol=1e-5, atol=1e-6
+                )
+            held = c.healthz()["netio"]["conns_open"]
+        assert held >= held_empty + conns
+    finally:
+        for s in idle:
+            s.close()
+        if router is not None:
+            router.drain()
+        server.drain()
+    # after the drain (a replica books a reply once it is written): every
+    # request answered ok, none shed or failed, and the identity exact
+    snap = target.stats.snapshot()
+    assert snap["requests_total"] == snap["replies_ok"] == 28
+    family = "router" if router is not None else "serve-stats"
+    assert flowledger.check(family, snap) is True

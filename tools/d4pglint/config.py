@@ -40,7 +40,6 @@ DEFAULT_PATHS = (
     "tools",
     "benchmarks",
     "train.py",
-    "bench.py",
     "__graft_entry__.py",
 )
 

@@ -1,13 +1,14 @@
-"""Schema checks for committed benchmark artifacts and metrics logs.
+"""Schema checks for committed artifacts and metrics logs.
 
-Two machine-readable surfaces downstream tooling (plots, regression
-smokes, the bench comparison scripts) parses:
+Two machine-readable surfaces downstream tooling (plots, the soak and
+static-analysis freshness tests) parses:
 
-- ``benchmarks/*.json`` — one JSON document per microbench: either a
-  single object carrying a ``backend`` key, or a list of row objects
-  each carrying a ``bench`` key (the mfu sweep shape). A truncated or
-  hand-mangled artifact should fail lint, not a plot script three PRs
-  later.
+- ``benchmarks/*.json`` — one JSON object per artifact (the composition
+  matrix, the lock-order graph, the flow identities, the league and
+  flywheel soak records; none is a timing). Every object but the two
+  static-analysis graphs carries a ``backend`` key, and each has its own
+  shape check below. A truncated or hand-mangled artifact should fail
+  lint, not a plot script three PRs later.
 - ``metrics.jsonl`` — append-only rows from
   :class:`d4pg_tpu.runtime.MetricsLogger`: every line a JSON object with
   an int ``step``, a numeric ``t``, and numeric values throughout
@@ -34,281 +35,15 @@ def check_benchmark_json(path: str) -> list[str]:
             doc = json.load(f)
     except (OSError, ValueError) as e:
         return [f"{path}: unreadable/invalid JSON ({e})"]
-    if isinstance(doc, dict):
-        if not doc:
-            errs.append(f"{path}: empty object")
-        elif "backend" not in doc:
-            errs.append(
-                f"{path}: benchmark object missing 'backend' (which "
-                "hardware produced this number?)"
-            )
-    elif isinstance(doc, list):
-        if not doc:
-            errs.append(f"{path}: empty list")
-        for i, row in enumerate(doc):
-            if not isinstance(row, dict):
-                errs.append(f"{path}[{i}]: row is not an object")
-            elif "bench" not in row:
-                errs.append(f"{path}[{i}]: sweep row missing 'bench'")
-    else:
-        errs.append(f"{path}: top level must be an object or list of objects")
-    return errs
-
-
-def check_router_microbench(path: str) -> list[str]:
-    """Shape check for ``benchmarks/router_microbench.json`` beyond the
-    generic benchmark rule: the regression smoke and the ROADMAP
-    availability headline parse these exact fields, so a hand-edited or
-    half-regenerated artifact must fail lint, not the smoke."""
-    errs = []
-    try:
-        with open(path, encoding="utf-8") as f:
-            doc = json.load(f)
-    except (OSError, ValueError) as e:
-        return [f"{path}: unreadable/invalid JSON ({e})"]
-    for key in ("backend", "scaling", "scaling_2_over_1", "availability",
-                "ratio_repeats"):
-        if key not in doc:
-            errs.append(f"{path}: missing top-level key {key!r}")
-    scaling = doc.get("scaling")
-    if not (isinstance(scaling, list) and len(scaling) >= 2):
-        errs.append(f"{path}: 'scaling' must list >= 2 replica-count rows")
-    else:
-        for i, row in enumerate(scaling):
-            for key in ("replicas", "throughput_rps", "p99_ms",
-                        "identity_ok", "submitted"):
-                if key not in row:
-                    errs.append(f"{path}: scaling[{i}] missing {key!r}")
-    avail = doc.get("availability")
-    if not isinstance(avail, dict):
-        errs.append(f"{path}: 'availability' must be an object")
-    else:
-        for key in ("availability", "identity_ok", "lost", "submitted",
-                    "router_retries", "router_ejections", "p99_ms"):
-            if key not in avail:
-                errs.append(f"{path}: availability missing {key!r}")
-        if avail.get("identity_ok") is not True:
-            errs.append(
-                f"{path}: availability.identity_ok is not true — the "
-                "committed artifact must never attest a silent loss"
-            )
-    return errs
-
-
-def check_multitenant_microbench(path: str) -> list[str]:
-    """Shape check for ``benchmarks/multitenant_microbench.json`` beyond
-    the generic benchmark rule: the ISSUE-12 acceptance parses these
-    exact fields — and a committed artifact can never attest a broken
-    isolation claim (``isolation_ok``), a broken per-tenant accounting
-    identity, or rps that failed to scale with the autoscaled replica
-    count."""
-    errs = []
-    try:
-        with open(path, encoding="utf-8") as f:
-            doc = json.load(f)
-    except (OSError, ValueError) as e:
-        return [f"{path}: unreadable/invalid JSON ({e})"]
-    for key in ("backend", "isolation", "autoscale_scaling",
-                "ratio_repeats", "infer_delay_ms"):
-        if key not in doc:
-            errs.append(f"{path}: missing top-level key {key!r}")
-    iso = doc.get("isolation")
-    if not isinstance(iso, dict):
-        errs.append(f"{path}: 'isolation' must be an object")
-    else:
-        for key in ("isolation_ok", "interactive_p99_ms", "slo_ms",
-                    "bulk_shed_rate", "tenants", "tenant_identity_ok",
-                    "router_identity_ok"):
-            if key not in iso:
-                errs.append(f"{path}: isolation missing {key!r}")
-        if iso.get("isolation_ok") is not True:
-            errs.append(
-                f"{path}: isolation.isolation_ok is "
-                f"{iso.get('isolation_ok')!r} — a committed artifact can "
-                "never attest a bulk flood moving interactive p99 past "
-                "its SLO"
-            )
-        if iso.get("tenant_identity_ok") is not True or (
-            iso.get("router_identity_ok") is not True
-        ):
-            errs.append(
-                f"{path}: per-tenant/router accounting identity not "
-                "attested true"
-            )
-        for name, row in (iso.get("tenants") or {}).items():
-            if row.get("requests") != row.get("answered"):
-                errs.append(
-                    f"{path}: tenants[{name!r}] requests "
-                    f"({row.get('requests')}) != answered "
-                    f"({row.get('answered')}) — identity broken in the "
-                    "committed rows"
-                )
-    scal = doc.get("autoscale_scaling")
-    if not isinstance(scal, dict):
-        errs.append(f"{path}: 'autoscale_scaling' must be an object")
-    else:
-        for key in ("rps_1_replica", "rps_2_replicas", "scaling_2_over_1",
-                    "scale_ups", "identity_ok"):
-            if key not in scal:
-                errs.append(f"{path}: autoscale_scaling missing {key!r}")
-        if scal.get("identity_ok") is not True:
-            errs.append(
-                f"{path}: autoscale_scaling.identity_ok not attested true"
-            )
-        if not (
-            isinstance(scal.get("scaling_2_over_1"), (int, float))
-            and scal["scaling_2_over_1"] > 1.0
-        ):
-            errs.append(
-                f"{path}: autoscale_scaling.scaling_2_over_1 is "
-                f"{scal.get('scaling_2_over_1')!r} — the committed "
-                "artifact must show rps scaling with replica count"
-            )
-    return errs
-
-
-def check_shard_microbench(path: str) -> list[str]:
-    """Shape check for ``benchmarks/shard_microbench.json`` beyond the
-    generic benchmark rule: the ISSUE-9 acceptance parses these exact
-    fields — dp=1 vs dp>1 grad-steps/s, per-step transfer bytes (which
-    MUST be 0 for device placement: a committed artifact can never attest
-    the sharded megastep paying per-step traffic), and the ensemble/MoG
-    wide-shape capacity row."""
-    errs = []
-    try:
-        with open(path, encoding="utf-8") as f:
-            doc = json.load(f)
-    except (OSError, ValueError) as e:
-        return [f"{path}: unreadable/invalid JSON ({e})"]
-    for key in ("backend", "device_count", "on_chip_recipe", "megastep_dp1"):
-        if key not in doc:
-            errs.append(f"{path}: missing top-level key {key!r}")
-    dp_rows = [
-        (k, v) for k, v in doc.items()
-        if k.startswith("megastep_dp") and isinstance(v, dict)
-    ]
-    if len(dp_rows) < 2:
+    if not isinstance(doc, dict):
+        errs.append(f"{path}: top level must be an object")
+    elif not doc:
+        errs.append(f"{path}: empty object")
+    elif "backend" not in doc:
         errs.append(
-            f"{path}: needs a dp=1 AND a dp>1 megastep row "
-            f"(found {[k for k, _ in dp_rows]})"
+            f"{path}: object missing 'backend' (which "
+            "hardware produced this record?)"
         )
-    for name, row in dp_rows:
-        for key in ("steps_per_sec", "transfer_bytes_per_grad_step", "dp",
-                    "steps_per_sec_repeats"):
-            if key not in row:
-                errs.append(f"{path}: {name} missing {key!r}")
-        if row.get("transfer_bytes_per_grad_step", 1) != 0:
-            errs.append(
-                f"{path}: {name}.transfer_bytes_per_grad_step is "
-                f"{row.get('transfer_bytes_per_grad_step')!r}, must be 0 — "
-                "device placement's zero-transfer contract"
-            )
-    if not any(v.get("dp", 1) > 1 for _, v in dp_rows):
-        errs.append(f"{path}: no megastep row with dp > 1")
-    # ISSUE 14: the zero-bytes contract extends to PRIORITIZED replay —
-    # a device-PER megastep row must exist, span the mesh (dp > 1), and
-    # attest zero per-grad-step transfer bytes (the priority structure is
-    # on-chip; any traffic here means the tree leaked back to the host).
-    per_rows = [
-        (k, v) for k, v in doc.items()
-        if k.startswith("megastep_per_") and isinstance(v, dict)
-    ]
-    if not per_rows:
-        errs.append(
-            f"{path}: needs a device-PER megastep row (megastep_per_dp*) — "
-            "the ISSUE-14 zero-transfer-with-PER contract"
-        )
-    for name, row in per_rows:
-        for key in ("steps_per_sec", "transfer_bytes_per_grad_step", "dp",
-                    "per", "steps_per_sec_repeats"):
-            if key not in row:
-                errs.append(f"{path}: {name} missing {key!r}")
-        if row.get("per") is not True:
-            errs.append(f"{path}: {name}.per must be true")
-        if row.get("transfer_bytes_per_grad_step", 1) != 0:
-            errs.append(
-                f"{path}: {name}.transfer_bytes_per_grad_step is "
-                f"{row.get('transfer_bytes_per_grad_step')!r}, must be 0 — "
-                "device-resident PER's zero-transfer contract"
-            )
-    if per_rows and not any(v.get("dp", 1) > 1 for _, v in per_rows):
-        errs.append(f"{path}: no device-PER megastep row with dp > 1")
-    ens = doc.get("ensemble_mog_wide")
-    if not isinstance(ens, dict):
-        errs.append(f"{path}: missing 'ensemble_mog_wide' capacity row")
-    else:
-        for key in ("ensemble", "mixtures", "hidden", "tp", "steps_per_sec"):
-            if key not in ens:
-                errs.append(f"{path}: ensemble_mog_wide missing {key!r}")
-        if ens.get("ensemble", 0) < 2:
-            errs.append(f"{path}: ensemble_mog_wide.ensemble must be >= 2")
-    return errs
-
-
-def check_mfu_sweep(path: str) -> list[str]:
-    """Shape check for ``benchmarks/mfu_sweep_results.json`` beyond the
-    generic benchmark rule: the ISSUE-16 acceptance parses the
-    large-batch recipe row — the REAL ``--p-replay`` training shape must
-    be committed at the MXU-filling batch, at ZERO per-grad-step transfer
-    bytes, with the on-chip ≥2×-flagship-MFU proxy and the ready-to-run
-    recipe command. An artifact regenerated without ``--large-batch`` /
-    ``--large-batch-only`` (dropping the row), or one attesting the fused
-    tier paying per-step traffic, must fail lint."""
-    errs = []
-    try:
-        with open(path, encoding="utf-8") as f:
-            doc = json.load(f)
-    except (OSError, ValueError) as e:
-        return [f"{path}: unreadable/invalid JSON ({e})"]
-    if not isinstance(doc, list):
-        return [f"{path}: must be a list of sweep rows"]
-    lb = [
-        r for r in doc
-        if isinstance(r, dict)
-        and str(r.get("config", "")).startswith("large_batch")
-    ]
-    if not lb:
-        return [
-            f"{path}: missing the large-batch recipe row "
-            "(config 'large_batch_*') — regenerate with "
-            "`python benchmarks/mfu_sweep.py --large-batch-only`"
-        ]
-    for row in lb:
-        name = row.get("config")
-        for key in ("batch", "batch_scale", "compute_dtype", "backend",
-                    "steps_per_sec", "transfer_bytes_per_grad_step",
-                    "recipe", "mfu_onchip_proxy"):
-            if key not in row:
-                errs.append(f"{path}: {name} missing {key!r}")
-        if row.get("transfer_bytes_per_grad_step", 1) != 0:
-            errs.append(
-                f"{path}: {name}.transfer_bytes_per_grad_step is "
-                f"{row.get('transfer_bytes_per_grad_step')!r}, must be 0 — "
-                "the fused large-batch tier keeps device placement's "
-                "zero-transfer contract"
-            )
-        if row.get("batch", 0) < 2048:
-            errs.append(
-                f"{path}: {name}.batch is {row.get('batch')!r} — the "
-                "recipe row exists to commit an MXU-filling shape "
-                "(B >= 2048)"
-            )
-        proxy = row.get("mfu_onchip_proxy")
-        if isinstance(proxy, dict):
-            ratio = proxy.get("ratio_vs_flagship")
-            if not (isinstance(ratio, (int, float)) and ratio >= 2.0):
-                errs.append(
-                    f"{path}: {name}.mfu_onchip_proxy.ratio_vs_flagship "
-                    f"is {ratio!r} — the committed shape must sit at "
-                    ">= 2x the flagship MFU"
-                )
-        elif "mfu_onchip_proxy" in row:
-            errs.append(f"{path}: {name}.mfu_onchip_proxy must be an object")
-        if "--fused-descent" not in str(row.get("recipe", "")):
-            errs.append(
-                f"{path}: {name}.recipe must be the ready-to-run "
-                "fused-tier train.py command (expected '--fused-descent')"
-            )
     return errs
 
 
@@ -521,184 +256,6 @@ def check_flow_identities(path: str, root: str | None = None) -> list[str]:
                 "with `python -m tools.d4pglint.wholeprog.flowcheck "
                 "--write`"
             )
-    return errs
-
-
-def check_multihost_microbench(path: str) -> list[str]:
-    """Shape + invariants for ``benchmarks/multihost_microbench.json`` —
-    the ISSUE-17 acceptance artifact. Three refusals beyond the generic
-    rule: a BROKEN bit-exactness attestation (any flag not literally
-    true, or recorded mismatches), a NONZERO per-grad-step transfer
-    byte row (the zero-transfer steady state is the contract, per
-    topology), and writer scaling ≤ 1 (per-host ingest that does not
-    scale out is not per-host ingest)."""
-    errs = []
-    try:
-        with open(path, encoding="utf-8") as f:
-            doc = json.load(f)
-    except (OSError, ValueError) as e:
-        return [f"{path}: unreadable/invalid JSON ({e})"]
-    for key in ("backend", "topologies", "bit_exact",
-                "transfer_bytes_per_grad_step", "ingest_scaling"):
-        if key not in doc:
-            errs.append(f"{path}: missing top-level key {key!r}")
-    be = doc.get("bit_exact")
-    if not isinstance(be, dict):
-        errs.append(f"{path}: 'bit_exact' must be an object")
-    else:
-        for key in ("train_state", "adam_moments", "ring", "per_tree",
-                    "det_pmean", "fold_in_draws"):
-            if be.get(key) is not True:
-                errs.append(
-                    f"{path}: bit_exact.{key} is not true — the committed "
-                    "artifact must never attest a mesh that diverges from "
-                    "the single-process oracle"
-                )
-        if be.get("mismatches"):
-            errs.append(
-                f"{path}: bit_exact.mismatches is non-empty: "
-                f"{be['mismatches']!r}"
-            )
-        if not isinstance(be.get("dispatches"), int) or be["dispatches"] < 2:
-            errs.append(
-                f"{path}: bit_exact.dispatches must be an int >= 2 (one "
-                "dispatch cannot show drift ACCUMULATING)"
-            )
-    tb = doc.get("transfer_bytes_per_grad_step")
-    if not isinstance(tb, dict):
-        errs.append(
-            f"{path}: 'transfer_bytes_per_grad_step' must be an object"
-        )
-    else:
-        rows = {k: v for k, v in tb.items() if k.startswith("procs_")}
-        if not rows:
-            errs.append(
-                f"{path}: transfer_bytes_per_grad_step has no per-topology "
-                "'procs_*' rows"
-            )
-        for k, v in rows.items():
-            if v != 0:
-                errs.append(
-                    f"{path}: transfer_bytes_per_grad_step.{k} = {v!r} — "
-                    "the steady-state dispatch budget is exactly zero"
-                )
-    sc = doc.get("ingest_scaling")
-    if not isinstance(sc, dict):
-        errs.append(f"{path}: 'ingest_scaling' must be an object")
-    else:
-        for key in ("writers", "writers_1_windows_per_sec",
-                    "writers_2_aggregate_windows_per_sec", "scaling_x",
-                    "methodology", "bench_host_cores"):
-            if key not in sc:
-                errs.append(f"{path}: ingest_scaling missing {key!r}")
-        one = sc.get("writers_1_windows_per_sec")
-        agg = sc.get("writers_2_aggregate_windows_per_sec")
-        if not (isinstance(one, (int, float)) and one > 0):
-            errs.append(
-                f"{path}: ingest_scaling.writers_1_windows_per_sec must be "
-                "> 0"
-            )
-        scaling = sc.get("scaling_x")
-        if not isinstance(scaling, (int, float)) or scaling <= 1.0:
-            errs.append(
-                f"{path}: ingest_scaling.scaling_x = {scaling!r} — writer "
-                "scaling <= 1 means per-host ingest did not scale out; "
-                "refuse the artifact"
-            )
-        elif (isinstance(one, (int, float)) and one > 0
-              and isinstance(agg, (int, float))
-              and abs(scaling - agg / one) > 1e-6 * max(scaling, 1.0)):
-            errs.append(
-                f"{path}: ingest_scaling.scaling_x {scaling!r} does not "
-                "equal aggregate/single — a hand-edited headline"
-            )
-    return errs
-
-
-def check_c10k_microbench(path: str) -> list[str]:
-    """Shape + invariants for ``benchmarks/c10k_microbench.json`` — the
-    ISSUE-20 acceptance artifact. Three refusals beyond the generic
-    rule: a broken accounting identity (``identity.ok`` not literally
-    true, or any recorded ``router`` flow-verdict not ok), fewer than
-    10000 held connections (the C10k floor IS the headline), and thread
-    growth past the constant budget (thread count O(conns) means the
-    event-loop claim regressed to thread-per-connection — refuse the
-    artifact, whatever the other numbers say)."""
-    errs = []
-    try:
-        with open(path, encoding="utf-8") as f:
-            doc = json.load(f)
-    except (OSError, ValueError) as e:
-        return [f"{path}: unreadable/invalid JSON ({e})"]
-    for key in ("backend", "conns_target", "held_connections", "slo_ms",
-                "threads", "interactive", "identity", "netio", "router_rc"):
-        if key not in doc:
-            errs.append(f"{path}: missing top-level key {key!r}")
-    ident = doc.get("identity")
-    if not isinstance(ident, dict) or ident.get("ok") is not True:
-        errs.append(
-            f"{path}: identity.ok is not true — the committed artifact "
-            "must never attest a broken accounting identity"
-        )
-    elif any(v.get("ok") is not True for v in ident.get("verdicts", [])):
-        errs.append(
-            f"{path}: a recorded flow-verdict is not ok: "
-            f"{ident['verdicts']!r}"
-        )
-    held = doc.get("held_connections")
-    if not isinstance(held, int) or held < 10000:
-        errs.append(
-            f"{path}: held_connections = {held!r} — the committed "
-            "artifact must hold >= 10000 concurrent connections"
-        )
-    th = doc.get("threads")
-    if not isinstance(th, dict):
-        errs.append(f"{path}: 'threads' must be an object")
-    else:
-        for key in ("threads_baseline", "threads_at_max", "growth",
-                    "growth_budget"):
-            if key not in th:
-                errs.append(f"{path}: threads missing {key!r}")
-        growth = th.get("growth")
-        budget = th.get("growth_budget")
-        if not isinstance(budget, int) or budget > 8:
-            errs.append(
-                f"{path}: threads.growth_budget = {budget!r} — the budget "
-                "itself must stay a small constant (<= 8), or 'O(1) "
-                "threads' stops meaning anything"
-            )
-        if not isinstance(growth, int) or (
-            isinstance(budget, int) and growth > budget
-        ):
-            errs.append(
-                f"{path}: threads.growth = {growth!r} past budget "
-                f"{budget!r} — thread count grew with connections; the "
-                "event-loop front-end regressed to thread-per-connection"
-            )
-    inter = doc.get("interactive")
-    if not isinstance(inter, dict):
-        errs.append(f"{path}: 'interactive' must be an object")
-    else:
-        p99 = inter.get("p99_ms")
-        slo = doc.get("slo_ms")
-        if not (isinstance(p99, (int, float)) and p99 > 0):
-            errs.append(f"{path}: interactive.p99_ms must be > 0")
-        elif isinstance(slo, (int, float)) and p99 > slo:
-            errs.append(
-                f"{path}: interactive.p99_ms {p99!r} > slo_ms {slo!r} — "
-                "interactive latency beside the held population is the "
-                "other half of the headline"
-            )
-        if inter.get("error"):
-            errs.append(
-                f"{path}: interactive.error = {inter['error']!r} — a "
-                "client died during the committed run"
-            )
-    if doc.get("router_rc") != 0:
-        errs.append(
-            f"{path}: router_rc = {doc.get('router_rc')!r} — the router "
-            "must drain rc 0 after the run"
-        )
     return errs
 
 
@@ -1036,35 +593,22 @@ def check_tree(root: str) -> list[str]:
     errs = []
     for path in sorted(glob.glob(os.path.join(root, "benchmarks", "*.json"))):
         if os.path.basename(path) == "lock_order_graph.json":
-            # not a microbench artifact: its own schema (and acyclicity
-            # pin + freshness vs the current code) replaces the generic
-            # backend-key rule
+            # its own schema (and acyclicity pin + freshness vs the
+            # current code) replaces the generic backend-key rule
             errs.extend(check_lock_order_graph(path, root))
             continue
         if os.path.basename(path) == "flow_identities.json":
             # same contract as the lock graph: its own schema + a
-            # freshness pin vs the current code, not a microbench
+            # freshness pin vs the current code
             errs.extend(check_flow_identities(path, root))
             continue
         errs.extend(check_benchmark_json(path))
-        if os.path.basename(path) == "router_microbench.json":
-            errs.extend(check_router_microbench(path))
-        if os.path.basename(path) == "multitenant_microbench.json":
-            errs.extend(check_multitenant_microbench(path))
-        if os.path.basename(path) == "shard_microbench.json":
-            errs.extend(check_shard_microbench(path))
-        if os.path.basename(path) == "mfu_sweep_results.json":
-            errs.extend(check_mfu_sweep(path))
         if os.path.basename(path) == "composition_matrix.json":
             errs.extend(check_composition_matrix(path))
         if os.path.basename(path) == "league_soak.json":
             errs.extend(check_league_soak(path))
         if os.path.basename(path) == "flywheel_soak.json":
             errs.extend(check_flywheel_soak(path))
-        if os.path.basename(path) == "multihost_microbench.json":
-            errs.extend(check_multihost_microbench(path))
-        if os.path.basename(path) == "c10k_microbench.json":
-            errs.extend(check_c10k_microbench(path))
     for path in sorted(
         glob.glob(os.path.join(root, "runs", "**", "metrics.jsonl"),
                   recursive=True)
