@@ -325,6 +325,50 @@ def _critic_value(config: D4PGConfig, support, head: jax.Array) -> jax.Array:
     raise ValueError(kind)
 
 
+def _step_metrics(
+    config: D4PGConfig, critic_loss, actor_loss, priorities, batch_q_mean
+) -> dict:
+    """A grad step's logged scalars, before any cross-shard mean."""
+    n_stack = _stacked_critics(config)
+    step_metrics = {
+        # Per-critic scale: the stacked loss SUMS its members (right for
+        # the gradient), but the logged metric must stay comparable to
+        # single-critic runs.
+        "critic_loss": critic_loss / n_stack if n_stack else critic_loss,
+        "actor_loss": actor_loss,
+        "priority_mean": jnp.mean(priorities),
+        # From the loss aux, NOT -actor_loss: with action_l2 the loss
+        # carries the penalty term and would understate E[Q].
+        "q_mean": batch_q_mean,
+    }
+    if config.dist.kind == "categorical":
+        # Support-saturation monitor: fraction of the categorical support
+        # [v_min, v_max] the mean Q occupies. The Humanoid v1500 study
+        # (runs/humanoid_ondevice_v1500) found q_mean pinned at v_max
+        # costing ~15% of final return — and nothing in the curves showed
+        # it. Values creeping toward 1.0 mean the support is clipping the
+        # value distribution; widen v_max. Categorical head only: the
+        # scalar and MoG heads are unbounded, so the ratio would be an
+        # alarm with no referent there.
+        step_metrics["q_support_frac"] = (batch_q_mean - config.dist.v_min) / (
+            config.dist.v_max - config.dist.v_min
+        )
+    return step_metrics
+
+
+def synced_trees(config: D4PGConfig, state: TrainState) -> tuple:
+    """The two trees a grad step hands to its cross-shard sync, as far as
+    their shapes go: the critic's gradients, then the actor's with the step
+    metrics (``parallel.dp.describe_sync`` reads them; ``Trainer`` logs the
+    result once under ``--dp``)."""
+    scalar = jax.ShapeDtypeStruct((), jnp.float32)
+    metrics = jax.eval_shape(
+        partial(_step_metrics, config), scalar, scalar,
+        jax.ShapeDtypeStruct((1,), jnp.float32), scalar,
+    )
+    return state.critic_params, (state.actor_params, metrics)
+
+
 def train_step(
     config: D4PGConfig,
     state: TrainState,
@@ -350,7 +394,8 @@ def train_step(
         Adam update. ``None`` → single-device semantics.
       sync_fn: overrides the cross-shard combine entirely (a ``tree ->
         tree`` callable). The sharded megastep passes the DETERMINISTIC
-        mean (``parallel.dp.det_pmean``: all_gather + fixed-order sum),
+        mean (``parallel.dp.det_pmean``: the tree packed into one buffer,
+        shards exchanged, summed in fixed order, gathered back),
         whose bits a single-device vmap oracle can replay exactly —
         ``pmean``'s backend AllReduce cannot be (its accumulation order is
         the backend's choice). ``None`` keeps the pmean/axis_name path.
@@ -706,7 +751,13 @@ def train_step(
         (actor_loss, batch_q_mean), actor_grads = jax.value_and_grad(
             actor_loss_fn, has_aux=True
         )(state.actor_params)
-    actor_grads = _sync(actor_grads)
+    step_metrics = _step_metrics(
+        config, critic_loss, actor_loss, priorities, batch_q_mean
+    )
+    # One sync for both: every step metric is known when the actor's
+    # gradients are, so they ride in the same buffer (the values are what a
+    # sync of their own gave).
+    actor_grads, metrics = _sync((actor_grads, step_metrics))
     with phase("agent.optimizer"):
         actor_updates, actor_opt_state = actor_opt.update(
             actor_grads, state.actor_opt_state
@@ -730,31 +781,6 @@ def train_step(
         actor_opt_state=actor_opt_state,
         critic_opt_state=critic_opt_state,
     )
-    n_stack = _stacked_critics(config)
-    step_metrics = {
-        # Per-critic scale: the stacked loss SUMS its members (right for
-        # the gradient), but the logged metric must stay comparable to
-        # single-critic runs.
-        "critic_loss": critic_loss / n_stack if n_stack else critic_loss,
-        "actor_loss": actor_loss,
-        "priority_mean": jnp.mean(priorities),
-        # From the loss aux, NOT -actor_loss: with action_l2 the loss
-        # carries the penalty term and would understate E[Q].
-        "q_mean": batch_q_mean,
-    }
-    if config.dist.kind == "categorical":
-        # Support-saturation monitor: fraction of the categorical support
-        # [v_min, v_max] the mean Q occupies. The Humanoid v1500 study
-        # (runs/humanoid_ondevice_v1500) found q_mean pinned at v_max
-        # costing ~15% of final return — and nothing in the curves showed
-        # it. Values creeping toward 1.0 mean the support is clipping the
-        # value distribution; widen v_max. Categorical head only: the
-        # scalar and MoG heads are unbounded, so the ratio would be an
-        # alarm with no referent there.
-        step_metrics["q_support_frac"] = (batch_q_mean - config.dist.v_min) / (
-            config.dist.v_max - config.dist.v_min
-        )
-    metrics = _sync(step_metrics)
     if descent is not None:
         return new_state, metrics, priorities, descent_idx
     return new_state, metrics, priorities

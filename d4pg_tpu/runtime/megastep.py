@@ -616,7 +616,8 @@ def make_megastep_uniform_oracle(config: D4PGConfig, k: int, batch: int,
     ``ring_lanes`` is a DeviceRing whose row fields carry a leading
     ``[n_shards]`` lane axis and whose ``size`` stays the global scalar.
     Because the body's only cross-shard arithmetic is ``det_pmean``
-    (all_gather + fixed-order sum — exact under both harnesses), the
+    (data-moving collectives + a fixed-order sum — exact under both
+    harnesses), the
     oracle's TrainState is BYTE-IDENTICAL to the mesh path's, which is
     the acceptance contract tests/test_sharded_megastep.py pins."""
     body = partial(

@@ -558,6 +558,19 @@ class Trainer:
                     from d4pg_tpu.parallel import apply_fns
 
                     self.state = apply_fns(self._state_shard_fns, self.state)
+                    # Static per (gradient trees, dp): how each of a grad
+                    # step's syncs crosses the chips (parallel/dp.py:
+                    # det_pmean's buffers).
+                    from d4pg_tpu.agent.d4pg import synced_trees
+                    from d4pg_tpu.parallel.dp import describe_sync
+
+                    print(
+                        "[parallel] gradient sync: "
+                        + json.dumps([
+                            describe_sync(tree, config.dp)
+                            for tree in synced_trees(agent_cfg, self.state)
+                        ])
+                    )
                     if config.prioritized:
                         self._megastep = make_megastep_device_per_sharded(
                             agent_cfg, K, config.batch_size,

@@ -230,6 +230,7 @@ MEGASTEP_FUNCTIONS = (
     # into every sharded dispatch, so a host coercion here would smuggle
     # a sync into the zero-transfer loop exactly like the bodies above.
     "d4pg_tpu/parallel/dp.py::det_pmean",
+    "d4pg_tpu/parallel/dp.py::_det_mean_buffer",
 )
 
 # numpy allocators flagged inside hot-path functions (np.asarray is
