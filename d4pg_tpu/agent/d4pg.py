@@ -113,18 +113,27 @@ def _check_torso(config: D4PGConfig) -> None:
             "projection (the fused tier's descent is not wired through it)")
 
 
-def _encoders(config: D4PGConfig, batch):
+# what a torso pass under an indexer reports of its choices (models/torso.py)
+CHOICES = ("keys", "experts", "load", "dropped")
+
+
+def _encoders(config: D4PGConfig, batch, emit_choices: bool = False):
     """``(encode, head_of)``: how ``train_step`` turns critic parameters
     and a batch's observations into what the actor and critic networks
-    read. The MLP/conv networks read the rows themselves (both are the
-    identity: no op is added to their program). A torso is part of the
-    critic's parameters and reads ``[B, T, O]`` windows under the batch's
-    ``mask``; its routing counts are dropped here."""
+    read, ``encode(params, obs) -> (features, extras)``. The MLP/conv
+    networks read the rows themselves (both are the identity: no op is added
+    to their program). A torso is part of the critic's parameters and reads
+    ``[B, T, O]`` windows under the batch's ``mask``; its routing counts are
+    dropped here, and ``extras`` keeps what an indexer adds: its
+    ``index_loss`` and, with ``emit_choices``, the pass's :data:`CHOICES`."""
     if config.torso is None:
-        return (lambda params, obs: obs), (lambda params: params)
+        return (lambda params, obs: (obs, {})), (lambda params: params)
 
     def encode(params, obs):
-        return torso_apply(config.torso, params["torso"], obs, batch["mask"])[0]
+        h, stats = torso_apply(config.torso, params["torso"], obs, batch["mask"],
+                               emit_choices)
+        keep = ("index_loss",) + (CHOICES if emit_choices else ())
+        return h, {k: stats[k] for k in keep if k in stats}
 
     return encode, (lambda params: params["head"])
 
@@ -376,6 +385,7 @@ def train_step(
     axis_name: str | None = None,
     sync_fn=None,
     descent=None,
+    emit_choices: bool = False,
 ):
     """One full D4PG SGD step (the reference §3.2 hot loop, fused).
 
@@ -411,6 +421,14 @@ def train_step(
         megastep body applies ``lane_draw``'s fill clamp). Under stacked
         critics every member computes the identical descent; member 0's
         is returned.
+      emit_choices: static, and only the benchmark's comparison sets it
+        (the megastep never does): with a torso whose attention runs under
+        an indexer, the same step returns a fourth element, what its two
+        torso passes chose — ``{"keys": [2, L, B, T, T] bool, "experts":
+        [2, L, B·T, k] int32, "load": [2, L, held], "dropped": [2, L]}``,
+        the critic's pass on s first, the target's on s′ second — read from
+        inside the step (a separate forward is another fusion and may round
+        a near-tie the other way).
 
     Returns:
       (new_state, metrics, priorities[B] — local shard under shard_map),
@@ -438,7 +456,7 @@ def train_step(
     actor, critic = build_networks(config)
     actor_opt, critic_opt = make_optimizers(config)
     support = support_of(config)
-    encode, head_of = _encoders(config, batch)
+    encode, head_of = _encoders(config, batch, emit_choices)
 
     # ---- bf16 hot-path dtype policy ----
     # Master weights, Adam moments, Polyak targets and every loss reduction
@@ -488,7 +506,7 @@ def train_step(
 
     # ---- target: y = Φ(r + γ_eff · Z_target(s', μ_target(s'))) ----
     with phase("agent.networks"):
-        next_feat = encode(tgt_critic_params, batch["next_obs"])
+        next_feat, target_extras = encode(tgt_critic_params, batch["next_obs"])
         next_action = actor.apply(tgt_actor_params, next_feat)
         if config.critic_ensemble:
             # REDQ in-target minimization, distributionally: back up whichever
@@ -605,7 +623,7 @@ def train_step(
             proj = jax.lax.stop_gradient(proj)
 
             def critic_loss_fn(critic_params):
-                feat = encode(critic_params, batch["obs"])
+                feat, extras = encode(critic_params, batch["obs"])
                 pred = critic.apply(head_of(critic_params), feat, batch["action"])
                 with phase("ops.projection_loss"):
                     loss, per_sample_ce = categorical_td_loss(pred, proj, weights)
@@ -619,8 +637,12 @@ def train_step(
                         )
                     else:
                         per_sample = per_sample_ce
+                if "index_loss" in extras:
+                    # the indexer's alignment loss, weight 1: its gradient
+                    # reaches the indexer's leaves alone, and theirs is it
+                    loss = loss + extras["index_loss"]
                 if config.torso is not None:    # the actor reads this pass's features
-                    return loss, (per_sample, jax.lax.stop_gradient(feat))
+                    return loss, (per_sample, jax.lax.stop_gradient(feat), extras)
                 return loss, per_sample
     elif config.dist.kind == "scalar":
         # Plain DDPG TD(0)/TD(n) target (BASELINE.json config 1).
@@ -700,7 +722,7 @@ def train_step(
     if descent is not None:
         priorities, descent_idx = loss_aux
     elif config.torso is not None:
-        priorities, feat = loss_aux
+        priorities, feat, extras = loss_aux
     else:
         priorities = loss_aux
     critic_grads = _sync(critic_grads)
@@ -754,6 +776,8 @@ def train_step(
     step_metrics = _step_metrics(
         config, critic_loss, actor_loss, priorities, batch_q_mean
     )
+    if config.torso is not None and "index_loss" in extras:
+        step_metrics["index_loss"] = extras["index_loss"]    # part of critic_loss
     # One sync for both: every step metric is known when the actor's
     # gradients are, so they ride in the same buffer (the values are what a
     # sync of their own gave).
@@ -783,6 +807,9 @@ def train_step(
     )
     if descent is not None:
         return new_state, metrics, priorities, descent_idx
+    if emit_choices:
+        return new_state, metrics, priorities, {
+            k: jnp.stack([extras[k], target_extras[k]]) for k in CHOICES}
     return new_state, metrics, priorities
 
 
@@ -802,7 +829,7 @@ def gather_batches(store, idx: jax.Array, torso=None) -> dict:
     from d4pg_tpu.replay.device_ring import ROW_FIELDS, DeviceRing
 
     if torso is not None:
-        return gather_windows(store, idx, torso.window, torso.row_stride)
+        return gather_windows(store, idx, torso.window, torso.row_stride, torso.span)
     with phase("replay.row_gather"):
         def rows(k):
             if isinstance(store, DeviceRing):  # wide fields are stored packed
@@ -814,7 +841,8 @@ def gather_batches(store, idx: jax.Array, torso=None) -> dict:
     return batches
 
 
-def gather_windows(store, idx: jax.Array, window: int, stride: int) -> dict:
+def gather_windows(store, idx: jax.Array, window: int, stride: int,
+                   span: str = "episode") -> dict:
     """[K, B] batches whose observations are the WINDOW of the ``window``
     ring rows that end at each drawn slot: ``obs`` / ``next_obs`` ``[K, B,
     T, O]`` (rows ``idx − (T−1−j)·stride``, ``stride`` = the writer's env
@@ -826,7 +854,10 @@ def gather_windows(store, idx: jax.Array, window: int, stride: int) -> dict:
     *before* row 0 can be beyond it — they are never wrapped around to), or
     when a row between it and the window's end, the end excluded, has
     ``discount == 0``: an episode ended there, the position belongs to the
-    episode before. The last position is always valid."""
+    episode before. The last position is always valid. With ``span ==
+    "stream"`` an episode's end cuts nothing: the window is the stream's
+    last ``window`` rows, whatever episodes they belong to, and only the
+    rows before the ring's first are masked."""
     from d4pg_tpu.replay.device_ring import ROW_FIELDS
 
     with phase("replay.row_gather"):
@@ -836,10 +867,13 @@ def gather_windows(store, idx: jax.Array, window: int, stride: int) -> dict:
         pos = jnp.maximum(pos, 0)
         batches = {k: store.rows(k, pos if k in ("obs", "next_obs") else idx)
                    for k in ROW_FIELDS}
-        ended = (store.rows("discount", pos) == 0.0) & inside
-        ended = ended.at[..., -1].set(False)
-        later_end = jnp.flip(jnp.cumsum(jnp.flip(ended, -1), -1), -1) > 0
-        batches["mask"] = inside & ~later_end
+        if span == "stream":
+            batches["mask"] = inside
+        else:
+            ended = (store.rows("discount", pos) == 0.0) & inside
+            ended = ended.at[..., -1].set(False)
+            later_end = jnp.flip(jnp.cumsum(jnp.flip(ended, -1), -1), -1) > 0
+            batches["mask"] = inside & ~later_end
         batches["weights"] = jnp.ones(idx.shape, jnp.float32)
     return batches
 

@@ -1,14 +1,28 @@
-"""A sequence torso over a window of observations: MLA attention blocks
-with a dense SwiGLU layer first and routed-expert layers after it.
+"""A sequence torso over a window of observations, made of stated parts.
 
 Tokens are timesteps: ``x_t = o_t W_in + b_in`` stands where a language
 model's embedding stands, positions are 0…T−1 of the window, attention is
 causal, and the torso's output is the last position's state after the final
-norm. The layer equations are the DeepSeek-V3 ones at whatever widths
-:class:`TorsoConfig` gives (pre-norm residual blocks, multi-head latent
-attention with a decoupled rotary key shared by the heads, sigmoid router
-scores with a selection bias, top-k, renormalised and scaled gates, a shared
-expert beside the routed ones).
+norm. Every block is pre-norm residual: ``x += Attn(RMSNorm(x))``, ``x +=
+FFN(RMSNorm(x))``. What a block is made of is stated by its configuration,
+one preset a published model:
+
+  attention   ``latent`` — multi-head latent attention with a decoupled
+              rotary key shared by the heads (DeepSeek-V3's; ``TorsoConfig``)
+              ``grouped_query_indexed`` — grouped-query attention whose keys
+              are chosen per query by a learned indexer, which has a loss of
+              its own (DeepSeek-V3.2's sparse attention at a Qwen3-MoE
+              block's sizes; ``IndexedTorsoConfig``)
+  router      ``sigmoid_bias`` — sigmoid scores, a selection bias, top-k,
+              renormalised and scaled gates
+              ``softmax`` — softmax over all experts, top-k, renormalised
+  FFN         ``first_k_dense_replace`` leading dense SwiGLU layers (0…n),
+              routed-expert layers after them, ``n_shared_experts`` (0 | 1)
+              beside the routed ones
+
+The two attention kinds have widths of their own, so each has its class;
+what they share is ``TorsoShape``. (``TorsoConfig`` keeps its name and its
+24 fields: the accepted benchmark's configuration file states them.)
 
 The expert layer is **told which experts it holds** (``experts_first``,
 ``experts_held``): it routes over all ``n_routed_experts``, keeps the pairs
@@ -35,38 +49,46 @@ from __future__ import annotations
 import dataclasses
 import math
 from functools import partial
+from typing import ClassVar
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from d4pg_tpu.utils.profiling import phase
 
 MASKED = -1e30   # finite: a window position with no valid key stays finite
+# One window's score tile — heads x a query chunk x every key, float32 — may
+# not pass this: :func:`validate` refuses the combination that would build
+# it, whatever the batch chunking does (``in_chunks`` splits B, and B = 1
+# falls through it).
+MAX_SCORE_TILE_BYTES = 2 ** 30
+# What an indexed block under ``jax.checkpoint`` keeps of its forward pass:
+# the discrete choices (experts a token, keys a query) — recomputed, a choice
+# may flip on a rounding and the backward pass would differentiate another
+# function than the forward pass ran — and each query chunk's attention
+# output, so that the block's recomputation skips the chunk's forward (its
+# own backward recomputes it once, not twice).
+KEPT = "torso_kept"
 
 
-@dataclasses.dataclass(frozen=True)
-class TorsoConfig:
-    """Static sizes of the torso. Field names follow the published
-    ``config.json`` keys of the architecture where there is one."""
+@dataclasses.dataclass(frozen=True, kw_only=True)
+class TorsoShape:
+    """What every torso states, whatever its attention. Field names follow
+    the published ``config.json`` keys of the architecture where it has one."""
 
-    name: str = "glm47_flash"
-    hidden_size: int = 2048
-    num_hidden_layers: int = 47        # every block, the leading dense ones included
-    first_k_dense_replace: int = 1
-    num_attention_heads: int = 20
-    q_lora_rank: int = 768
-    kv_lora_rank: int = 512
-    qk_nope_head_dim: int = 192
-    qk_rope_head_dim: int = 64
-    v_head_dim: int = 256
-    rope_theta: float = 1_000_000.0
-    intermediate_size: int = 10240
-    moe_intermediate_size: int = 1536
-    n_routed_experts: int = 64         # the router's width
-    n_shared_experts: int = 1
-    num_experts_per_tok: int = 4
-    routed_scaling_factor: float = 1.8
-    rms_norm_eps: float = 1e-5
+    name: str
+    hidden_size: int
+    num_hidden_layers: int             # every block, the leading dense ones included
+    first_k_dense_replace: int
+    num_attention_heads: int
+    rope_theta: float
+    intermediate_size: int
+    moe_intermediate_size: int
+    n_routed_experts: int              # the router's width
+    n_shared_experts: int
+    num_experts_per_tok: int
+    rms_norm_eps: float
     # the share of each expert layer this learner holds
     experts_first: int = 0
     experts_held: int = 64
@@ -77,15 +99,16 @@ class TorsoConfig:
     # expert loop's work follows the routed pairs (1,024-row blocks were
     # slower on the chip and no steadier over seeds, PERF.md section 6)
     expert_block_rows: int = 256
-    batch_chunks: int = 4         # attention and the dense SwiGLU run on B/4 windows at a time
+    batch_chunks: int = 4         # latent attention and the dense SwiGLU run on B/4 windows at a time
 
     @property
     def num_moe_layers(self) -> int:
         return self.num_hidden_layers - self.first_k_dense_replace
 
-    @property
-    def qk_head_dim(self) -> int:
-        return self.qk_nope_head_dim + self.qk_rope_head_dim
+    def score_tile_bytes(self) -> int:
+        """One window's largest float32 score tile: heads x a query chunk x
+        every key."""
+        return 4 * self.num_attention_heads * (self.window // self.query_chunks) * self.window
 
     def padded_pairs(self, tokens: int) -> int:
         """Rows of the dispatch buffer: every pair that can land on a held
@@ -96,32 +119,125 @@ class TorsoConfig:
         return blocks * self.expert_block_rows
 
 
-# The published widths (zai-org/GLM-4.7-Flash config.json, model_type
-# glm4_moe_lite) and a toy of the same structure for CPU tests and
-# rehearsals. Depth, the experts held and the window are flags of train.py.
+@dataclasses.dataclass(frozen=True, kw_only=True)
+class TorsoConfig(TorsoShape):
+    """Latent attention under a sigmoid router with a selection bias."""
+
+    attention: ClassVar[str] = "latent"
+    router: ClassVar[str] = "sigmoid_bias"
+    span: ClassVar[str] = "episode"        # a window ends where its episode began
+    query_chunks: ClassVar[int] = 1        # one window's scores are one tile
+
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    routed_scaling_factor: float
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclasses.dataclass(frozen=True, kw_only=True)
+class IndexedTorsoConfig(TorsoShape):
+    """Grouped-query attention over the keys a learned indexer chooses,
+    under a softmax router."""
+
+    attention: ClassVar[str] = "grouped_query_indexed"
+    router: ClassVar[str] = "softmax"
+
+    num_key_value_heads: int
+    head_dim: int
+    index_n_heads: int
+    index_head_dim: int
+    index_topk: int                 # keys a query attends to: its ``index_topk`` best
+    # queries of one window are taken ``window / query_chunks`` at a time,
+    # each chunk against the keys up to its end (the source's q_chunk_size)
+    query_chunks: int = 1
+    # "episode": a window ends where its episode began. "stream": it spans
+    # the episodes of its stream (the context is the stream's history).
+    span: str = "episode"
+    batch_chunks: int = 1
+
+
+# One preset a published model (its widths under its own key names) and a
+# toy of the same structure for CPU tests and rehearsals. Depth, the experts
+# held, the window and its span are flags of train.py.
 TORSO_PRESETS = {
-    "glm47_flash": TorsoConfig(),
+    # zai-org/GLM-4.7-Flash config.json, model_type glm4_moe_lite
+    "glm47_flash": TorsoConfig(
+        name="glm47_flash", hidden_size=2048, num_hidden_layers=47,
+        first_k_dense_replace=1, num_attention_heads=20, q_lora_rank=768,
+        kv_lora_rank=512, qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+        rope_theta=1_000_000.0, intermediate_size=10240, moe_intermediate_size=1536,
+        n_routed_experts=64, n_shared_experts=1, num_experts_per_tok=4,
+        routed_scaling_factor=1.8, rms_norm_eps=1e-5,
+    ),
     "glm47_flash_tiny": TorsoConfig(
         name="glm47_flash_tiny", hidden_size=32, num_hidden_layers=3,
-        num_attention_heads=2, q_lora_rank=12, kv_lora_rank=8,
-        qk_nope_head_dim=6, qk_rope_head_dim=4, v_head_dim=8,
+        first_k_dense_replace=1, num_attention_heads=2, q_lora_rank=12, kv_lora_rank=8,
+        qk_nope_head_dim=6, qk_rope_head_dim=4, v_head_dim=8, rope_theta=1_000_000.0,
         intermediate_size=48, moe_intermediate_size=16, n_routed_experts=8,
-        num_experts_per_tok=2, experts_held=8, window=4, expert_block_rows=8,
+        n_shared_experts=1, num_experts_per_tok=2, routed_scaling_factor=1.8,
+        rms_norm_eps=1e-5, experts_held=8, window=4, expert_block_rows=8,
+    ),
+    # Kwai-Keye/Keye-VL-2.0-30B-A3B config.json, model_type KeyeVL2: the
+    # language model's block (num_experts -> n_routed_experts, sa_config's
+    # indexer_* and topk -> index_*); no dense layer, no shared expert
+    "keye_vl2": IndexedTorsoConfig(
+        name="keye_vl2", hidden_size=2048, num_hidden_layers=48,
+        first_k_dense_replace=0, num_attention_heads=32, num_key_value_heads=4,
+        head_dim=128, rope_theta=10_000_000.0, intermediate_size=6144,
+        moe_intermediate_size=768, n_routed_experts=128, n_shared_experts=0,
+        num_experts_per_tok=8, rms_norm_eps=1e-6, index_n_heads=16, index_head_dim=64,
+        index_topk=2048, experts_held=128, window=8192, query_chunks=16,
+        # experts of width 768: a 256-row block is as much slicing of its
+        # expert's weights and read-modify-write of their gradients as matrix
+        # products; 512 rows were faster on the chip and steadier over seeds
+        # (PERF.md section 6)
+        expert_block_rows=512,
+    ),
+    # index_topk smaller than the window, so the choice is live
+    "keye_vl2_tiny": IndexedTorsoConfig(
+        name="keye_vl2_tiny", hidden_size=32, num_hidden_layers=2,
+        first_k_dense_replace=0, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=8, rope_theta=10_000_000.0, intermediate_size=48,
+        moe_intermediate_size=12, n_routed_experts=16, n_shared_experts=0,
+        num_experts_per_tok=4, rms_norm_eps=1e-6, index_n_heads=8, index_head_dim=4,
+        index_topk=6, experts_held=16, window=16, query_chunks=4, expert_block_rows=8,
     ),
 }
 
 
-def validate(cfg: TorsoConfig) -> None:
+def validate(cfg: TorsoShape) -> None:
     if not 0 <= cfg.experts_first <= cfg.experts_first + cfg.experts_held <= cfg.n_routed_experts:
         raise ValueError(
             f"torso holds experts [{cfg.experts_first}, "
             f"{cfg.experts_first + cfg.experts_held}) of {cfg.n_routed_experts}")
-    if cfg.experts_held < 1 or cfg.num_moe_layers < 1 or cfg.first_k_dense_replace < 1:
-        raise ValueError("torso needs a dense layer, an expert layer and a held expert")
-    if cfg.qk_rope_head_dim % 2:
-        raise ValueError("qk_rope_head_dim must be even")
-    if cfg.n_shared_experts != 1:
-        raise ValueError("one shared expert is what the layer computes")
+    if cfg.experts_held < 1 or cfg.num_moe_layers < 1 or cfg.first_k_dense_replace < 0:
+        raise ValueError("torso needs an expert layer and a held expert")
+    if cfg.n_shared_experts not in (0, 1):
+        raise ValueError("no shared expert or one is what the layer computes")
+    if cfg.window % cfg.query_chunks or cfg.span not in ("episode", "stream"):
+        raise ValueError(
+            f"window {cfg.window} in {cfg.query_chunks} query chunks, span {cfg.span!r}")
+    if cfg.score_tile_bytes() > MAX_SCORE_TILE_BYTES:
+        raise ValueError(
+            f"one window's score tile would be {cfg.score_tile_bytes() / 2 ** 30:.1f} GiB "
+            f"({cfg.num_attention_heads} heads x {cfg.window // cfg.query_chunks} queries "
+            f"x {cfg.window} keys, float32): batch chunks do not split a window, "
+            "raise query_chunks" + (
+                "" if cfg.attention != "latent" else " (latent attention has none)"))
+    if cfg.attention == "latent":
+        if cfg.qk_rope_head_dim % 2:
+            raise ValueError("qk_rope_head_dim must be even")
+    else:
+        if cfg.head_dim % 2 or cfg.index_head_dim % 2:
+            raise ValueError("head_dim and index_head_dim must be even")
+        if cfg.num_attention_heads % cfg.num_key_value_heads or cfg.index_topk < 1:
+            raise ValueError("query heads in whole groups a key-value head, index_topk >= 1")
 
 
 # ------------------------------------------------------------------ init
@@ -130,20 +246,47 @@ def _uniform(key, shape, fan_in):
     return jax.random.uniform(key, shape, jnp.float32, -bound, bound)
 
 
-def _block_init(cfg: TorsoConfig, key, moe: bool) -> dict:
+def _attention_init(cfg: TorsoShape, ks) -> dict:
     d, h = cfg.hidden_size, cfg.num_attention_heads
-    ks = iter(jax.random.split(key, 16))
-    attn = {
-        "q_a": _uniform(next(ks), (d, cfg.q_lora_rank), d),
-        "q_a_norm": jnp.ones((cfg.q_lora_rank,), jnp.float32),
-        "q_b": _uniform(next(ks), (cfg.q_lora_rank, h * cfg.qk_head_dim), cfg.q_lora_rank),
-        "kv_a": _uniform(next(ks), (d, cfg.kv_lora_rank + cfg.qk_rope_head_dim), d),
-        "kv_a_norm": jnp.ones((cfg.kv_lora_rank,), jnp.float32),
-        "kv_b": _uniform(
-            next(ks), (cfg.kv_lora_rank, h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
-            cfg.kv_lora_rank),
-        "o": _uniform(next(ks), (h * cfg.v_head_dim, d), h * cfg.v_head_dim),
+    if cfg.attention == "latent":
+        return {"attn": {
+            "q_a": _uniform(next(ks), (d, cfg.q_lora_rank), d),
+            "q_a_norm": jnp.ones((cfg.q_lora_rank,), jnp.float32),
+            "q_b": _uniform(next(ks), (cfg.q_lora_rank, h * cfg.qk_head_dim), cfg.q_lora_rank),
+            "kv_a": _uniform(next(ks), (d, cfg.kv_lora_rank + cfg.qk_rope_head_dim), d),
+            "kv_a_norm": jnp.ones((cfg.kv_lora_rank,), jnp.float32),
+            "kv_b": _uniform(
+                next(ks), (cfg.kv_lora_rank, h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+                cfg.kv_lora_rank),
+            "o": _uniform(next(ks), (h * cfg.v_head_dim, d), h * cfg.v_head_dim),
+        }}
+    kv, hd, ih, ihd = (cfg.num_key_value_heads, cfg.head_dim, cfg.index_n_heads,
+                       cfg.index_head_dim)
+    return {
+        "attn": {
+            "q": _uniform(next(ks), (d, h * hd), d),
+            "q_norm": jnp.ones((hd,), jnp.float32),
+            "k": _uniform(next(ks), (d, kv * hd), d),
+            "k_norm": jnp.ones((hd,), jnp.float32),
+            "v": _uniform(next(ks), (d, kv * hd), d),
+            "o": _uniform(next(ks), (h * hd, d), h * hd),
+        },
+        # the indexer: its inputs are cut from the graph, so these leaves take
+        # their gradient from its own loss alone (Adam and Polyak as any leaf)
+        "indexer": {
+            "q": _uniform(next(ks), (d, ih * ihd), d),
+            "k": _uniform(next(ks), (d, ihd), d),
+            "k_norm": {"scale": jnp.ones((ihd,), jnp.float32),
+                       "bias": jnp.zeros((ihd,), jnp.float32)},
+            "w": _uniform(next(ks), (d, ih), d),
+        },
     }
+
+
+def _block_init(cfg: TorsoShape, key, moe: bool) -> dict:
+    d = cfg.hidden_size
+    ks = iter(jax.random.split(key, 16))
+    attention = _attention_init(cfg, ks)
 
     def swiglu(width, lead=()):
         return {
@@ -153,25 +296,25 @@ def _block_init(cfg: TorsoConfig, key, moe: bool) -> dict:
         }
 
     if moe:
-        ffn = {
-            "router": _uniform(next(ks), (d, cfg.n_routed_experts), d),
+        ffn = {"router": _uniform(next(ks), (d, cfg.n_routed_experts), d)}
+        if cfg.router == "sigmoid_bias":
             # the selection bias of noaux_tc: a buffer that enters the choice
             # only. Held at its initial value (its update rate is not in the
             # published config); it takes no gradient.
-            "router_bias": jnp.zeros((cfg.n_routed_experts,), jnp.float32),
-            "experts": swiglu(cfg.moe_intermediate_size, (cfg.experts_held,)),
-            "shared": swiglu(cfg.moe_intermediate_size * cfg.n_shared_experts),
-        }
+            ffn["router_bias"] = jnp.zeros((cfg.n_routed_experts,), jnp.float32)
+        ffn["experts"] = swiglu(cfg.moe_intermediate_size, (cfg.experts_held,))
+        if cfg.n_shared_experts:
+            ffn["shared"] = swiglu(cfg.moe_intermediate_size * cfg.n_shared_experts)
     else:
         ffn = swiglu(cfg.intermediate_size)
     return {
         "attn_norm": jnp.ones((d,), jnp.float32),
         "ffn_norm": jnp.ones((d,), jnp.float32),
-        "attn": attn, "ffn": ffn,
+        **attention, "ffn": ffn,
     }
 
 
-def torso_init(cfg: TorsoConfig, key, obs_dim: int) -> dict:
+def torso_init(cfg: TorsoShape, key, obs_dim: int) -> dict:
     """``embed`` (the observation projection), ``layers`` (one dict a block,
     the leading dense ones first), ``final_norm``."""
     validate(cfg)
@@ -194,10 +337,10 @@ def rms_norm(x, weight, eps):
     return x * jax.lax.rsqrt(var + eps) * weight
 
 
-def rope_tables(cfg: TorsoConfig, positions: int):
+def rope_tables(theta: float, rope: int, positions: int):
     """cos/sin ``[T, rope/2]``: frequency ``theta^(-2i/rope)`` for pair i."""
-    half = cfg.qk_rope_head_dim // 2
-    inv = cfg.rope_theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    half = rope // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angle = jnp.arange(positions, dtype=jnp.float32)[:, None] * inv[None, :]
     return jnp.cos(angle), jnp.sin(angle)
 
@@ -241,6 +384,124 @@ def mla(cfg: TorsoConfig, p: dict, x, bias, cos, sin):
     return out @ p["o"]
 
 
+def layer_norm(x, p, eps):
+    mean = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
+    return (x - mean) * jax.lax.rsqrt(var + eps) * p["scale"] + p["bias"]
+
+
+def index_scores(q, k, w):
+    """``I[b, t, s] = Σ_j w[b, t, j] · ReLU(q[b, t, j] · k[b, s])``: the
+    lightning indexer's score of key s for query t (``q [B, Tq, J, d]``, one
+    key ``k [B, Ts, d]`` for all index heads). Exact zeros are made +0."""
+    hit = jax.nn.relu(jnp.einsum("btjd,bsd->btjs", q, k))
+    scores = jnp.einsum("btj,btjs->bts", w, hit)
+    return jnp.where(scores == 0.0, 0.0, scores)
+
+
+def kth_largest(scores, k: int):
+    """The k-th largest entry of each row of ``scores [..., n]`` (n ≥ k),
+    exactly: a radix select on the floats' bits, most significant first —
+    32 counting passes over the row, no sort (PERF.md section 6 has what a
+    row sort and ``lax.top_k`` cost at ``[512, 8192]``)."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.uint32)
+    sign = jnp.uint32(0x80000000)
+    order = jnp.where(bits >= sign, ~bits, bits | sign)     # unsigned order = float order
+
+    def body(i, prefix):
+        trial = prefix | (sign >> i.astype(jnp.uint32))
+        enough = jnp.sum(order >= trial[..., None], axis=-1, dtype=jnp.int32) >= k
+        return jnp.where(enough, trial, prefix)
+
+    kth = jax.lax.fori_loop(0, 32, body, jnp.zeros(scores.shape[:-1], jnp.uint32))
+    return jax.lax.bitcast_convert_type(
+        jnp.where(kth >= sign, kth & ~sign, ~kth), jnp.float32)
+
+
+def choose_keys(scores, see, k: int):
+    """``[B, Tq, Ts]`` bool: for each query the ``k`` keys of largest score
+    among those it may ``see`` — all of them when there are fewer — as
+    ``jax.lax.top_k`` orders them (ties to the lower position)."""
+    if scores.shape[-1] <= k:
+        return see
+    scores = jnp.where(see, scores, -jnp.inf)
+    kth = kth_largest(scores, k)[..., None]
+    above, ties = scores > kth, scores == kth
+    room = k - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
+    return (above | (ties & (jnp.cumsum(ties, axis=-1, dtype=jnp.int32) <= room))) & see
+
+
+def _attend(cfg: IndexedTorsoConfig, q, k, v, member, scores, valid_q):
+    """One query chunk against the keys up to its end: softmax over the
+    chosen keys only, and the chunk's part of the indexer's alignment loss —
+    ``KL(p ‖ softmax over the chosen keys of the index scores)`` summed over
+    its valid queries, ``p`` the attention's probabilities summed over the
+    heads and normalised, cut from the graph."""
+    b, tq, h, hd = q.shape
+    kv = cfg.num_key_value_heads
+    with phase("agent.attention"):
+        logits = jnp.einsum("btkgd,bskd->bkgts", q.reshape(b, tq, kv, h // kv, hd), k)
+        logits = jnp.where(member[:, None, None], logits / math.sqrt(hd), MASKED)
+        probs = jax.nn.softmax(logits, axis=-1)
+        out = jnp.einsum("bkgts,bskd->btkgd", probs, v).reshape(b, tq, h * hd)
+    with phase("agent.indexer"):
+        p = jax.lax.stop_gradient(jnp.sum(probs, axis=(1, 2)))
+        p = p / jnp.sum(p, axis=-1, keepdims=True)
+        log_index = jax.nn.log_softmax(jnp.where(member, scores, MASKED), axis=-1)
+        kl = jnp.sum(jnp.where(p > 0.0, p * (jnp.log(jnp.where(p > 0.0, p, 1.0)) - log_index),
+                               0.0), axis=-1)
+        loss = jnp.sum(jnp.where(valid_q, kl, 0.0))
+    return out, loss
+
+
+def indexed_attention(cfg: IndexedTorsoConfig, p: dict, index: dict, x, valid, emit: bool):
+    """Grouped-query attention on ``[B, T, D]`` over the keys the indexer
+    chooses: ``(out [B, T, D], index_loss, keys [B, T, T] bool | None)``.
+
+    The queries are taken ``T / query_chunks`` at a time against the keys up
+    to the chunk's end (block-causal: a window's ``[heads, T, T]`` scores
+    never exist), every chunk dense with the keys outside the query's choice
+    at ``MASKED`` — no row is moved. The indexer reads its input cut from
+    the graph; the choice (kept by the block's checkpoint, never recomputed)
+    carries no gradient; ``index_loss`` is the mean over valid queries of the
+    alignment loss, whose gradient reaches ``index`` alone."""
+    b, t, _ = x.shape
+    h, kv, hd, eps = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim, cfg.rms_norm_eps
+    with phase("agent.attention"):
+        cos, sin = rope_tables(cfg.rope_theta, hd, t)
+        q = apply_rope(rms_norm((x @ p["q"]).reshape(b, t, h, hd), p["q_norm"], eps), cos, sin)
+        k = apply_rope(rms_norm((x @ p["k"]).reshape(b, t, kv, hd), p["k_norm"], eps), cos, sin)
+        v = (x @ p["v"]).reshape(b, t, kv, hd)
+    with phase("agent.indexer"):
+        cut = jax.lax.stop_gradient(x)
+        j, d = cfg.index_n_heads, cfg.index_head_dim
+        cos, sin = rope_tables(cfg.rope_theta, d, t)
+        q_i = apply_rope((cut @ index["q"]).reshape(b, t, j, d), cos, sin)
+        k_i = apply_rope(layer_norm(cut @ index["k"], index["k_norm"], eps), cos, sin)
+        w_i = (cut @ index["w"]) * (j ** -0.5 * d ** -0.5)
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    size = t // cfg.query_chunks
+    outs, masks, loss = [], [], 0.0
+    for lo in range(0, t, size):
+        hi = lo + size
+        see = causal[lo:hi, :hi][None] & valid[:, None, :hi]
+        with phase("agent.indexer"):
+            scores = jax.checkpoint(index_scores)(q_i[:, lo:hi], k_i[:, :hi], w_i[:, lo:hi])
+            member = checkpoint_name(
+                choose_keys(jax.lax.stop_gradient(scores), see, cfg.index_topk), KEPT)
+        out, part = jax.checkpoint(partial(_attend, cfg))(
+            q[:, lo:hi], k[:, :hi], v[:, :hi], member, scores, valid[:, lo:hi])
+        outs.append(checkpoint_name(out, KEPT))
+        loss = loss + part
+        if emit:
+            masks.append(jnp.pad(member, ((0, 0), (0, 0), (0, t - hi))))
+    with phase("agent.attention"):
+        out = jnp.concatenate(outs, axis=1) @ p["o"]
+    with phase("agent.indexer"):
+        loss = loss / jnp.maximum(jnp.sum(valid), 1)
+    return out, loss, jnp.concatenate(masks, axis=1) if emit else None
+
+
 def swiglu(p: dict, x):
     return (jax.nn.silu(x @ p["gate"]) * (x @ p["up"])) @ p["down"]
 
@@ -259,9 +520,17 @@ def in_chunks(fn, chunks: int, *rows):
 
 
 # ------------------------------------------------------ the routed experts
-def route(cfg: TorsoConfig, p: dict, x):
-    """``(chosen [N, k] int32, gates [N, k])``: sigmoid scores, the top-k of
-    score + bias, gates = scale · score / Σ chosen scores."""
+def route(cfg: TorsoShape, p: dict, x):
+    """``(chosen [N, k] int32, gates [N, k])``. ``sigmoid_bias``: sigmoid
+    scores, the top-k of score + bias, gates = scale · score / Σ chosen
+    scores. ``softmax``: probabilities over all experts, their top-k, gates =
+    probability / Σ chosen probabilities."""
+    if cfg.router == "softmax":
+        scores = jax.nn.softmax(x @ p["router"], axis=-1)
+        _, chosen = jax.lax.top_k(jax.lax.stop_gradient(scores), cfg.num_experts_per_tok)
+        chosen = checkpoint_name(chosen, KEPT)
+        picked = jnp.take_along_axis(scores, chosen, axis=-1)
+        return chosen, picked / jnp.sum(picked, axis=-1, keepdims=True)
     scores = jax.nn.sigmoid(x @ p["router"])
     _, chosen = jax.lax.top_k(
         jax.lax.stop_gradient(scores + p["router_bias"]), cfg.num_experts_per_tok)
@@ -270,7 +539,7 @@ def route(cfg: TorsoConfig, p: dict, x):
     return chosen, gates
 
 
-def dispatch_plan(cfg: TorsoConfig, chosen):
+def dispatch_plan(cfg: TorsoShape, chosen):
     """Where each (token, choice) pair goes in the by-expert layout.
 
     Returns ``slot [N, k]`` (row of the dispatch buffer, or ``P`` — one past
@@ -393,9 +662,10 @@ def _routed_bwd(rows, res, dy):
 routed_experts.defvjp(_routed_fwd, _routed_bwd)
 
 
-def expert_layer(cfg: TorsoConfig, p: dict, x):
+def expert_layer(cfg: TorsoShape, p: dict, x, chosen_too: bool = False):
     """The whole expert layer on ``[N, D]`` tokens: held routed experts +
-    the shared expert. Also returns ``(load [held], dropped)``."""
+    the shared expert where there is one. Also returns ``(load [held],
+    dropped)`` — and the experts each token chose ``[N, k]``, if asked."""
     chosen, gates = route(cfg, p, x)
     *plan, load = dispatch_plan(cfg, chosen)
     slot, slot_token = plan[:2]
@@ -404,38 +674,71 @@ def expert_layer(cfg: TorsoConfig, p: dict, x):
                        p["experts"], tuple(plan))
     placed = jnp.sum(slot_token < x.shape[0], dtype=jnp.int32)
     dropped = jnp.sum(held, dtype=jnp.int32) - placed
-    return y + swiglu(p["shared"], x), (load, dropped)
+    if cfg.n_shared_experts:
+        y = y + swiglu(p["shared"], x)
+    return y, ((load, dropped, chosen) if chosen_too else (load, dropped))
 
 
 # ------------------------------------------------------------- the torso
-def _block(cfg: TorsoConfig, moe: bool, x, p, bias, cos, sin):
+def _ffn(cfg: TorsoShape, moe: bool, x, p, chosen_too: bool = False):
     b, t, d = x.shape
-    with phase("agent.attention"):
-        x = x + in_chunks(
-            lambda xc, bc: mla(cfg, p["attn"], xc, bc, cos, sin), cfg.batch_chunks,
-            rms_norm(x, p["attn_norm"], cfg.rms_norm_eps), bias)
     normed = rms_norm(x, p["ffn_norm"], cfg.rms_norm_eps)
     if not moe:
         return x + in_chunks(partial(swiglu, p["ffn"]), cfg.batch_chunks, normed), None
     with phase("agent.experts"):
-        y, stats = expert_layer(cfg, p["ffn"], normed.reshape(b * t, d))
+        y, stats = expert_layer(cfg, p["ffn"], normed.reshape(b * t, d), chosen_too)
     return x + y.reshape(b, t, d), stats
 
 
-def torso_apply(cfg: TorsoConfig, params: dict, obs, valid):
+def _block(cfg: TorsoConfig, moe: bool, x, p, bias, cos, sin):
+    with phase("agent.attention"):
+        x = x + in_chunks(
+            lambda xc, bc: mla(cfg, p["attn"], xc, bc, cos, sin), cfg.batch_chunks,
+            rms_norm(x, p["attn_norm"], cfg.rms_norm_eps), bias)
+    return _ffn(cfg, moe, x, p)
+
+
+def _indexed_block(cfg: IndexedTorsoConfig, moe: bool, emit: bool, x, p, valid):
+    with phase("agent.attention"):
+        normed = rms_norm(x, p["attn_norm"], cfg.rms_norm_eps)
+    out, index_loss, keys = indexed_attention(cfg, p["attn"], p["indexer"], normed, valid, emit)
+    x, stats = _ffn(cfg, moe, x + out, p, chosen_too=True)
+    load_dropped, experts = (stats[:2], stats[2]) if moe else (None, None)
+    return x, (load_dropped, index_loss, keys, experts if emit else None)
+
+
+def torso_apply(cfg: TorsoShape, params: dict, obs, valid, emit_choices: bool = False):
     """``obs [B, T, O]``, ``valid [B, T]`` bool → ``(h [B, D], stats)``:
     the last position's state after the final norm, and the expert layers'
-    routing counts ``{"load": [L, held] int32, "dropped": [L] int32}``."""
+    routing counts ``{"load": [L, held] int32, "dropped": [L] int32}``.
+    Under an indexer ``stats`` also holds ``index_loss`` (the layers'
+    alignment losses added) and, with ``emit_choices``, what the pass chose:
+    ``keys [L, B, T, T]`` bool and ``experts [L_moe, B·T, k]`` int32."""
     x = obs @ params["embed"]["kernel"] + params["embed"]["bias"]
-    bias = attention_bias(valid)
-    cos, sin = rope_tables(cfg, obs.shape[1])
+    indexed = cfg.attention == "grouped_query_indexed"
+    if not indexed:
+        bias = attention_bias(valid)
+        cos, sin = rope_tables(cfg.rope_theta, cfg.qk_rope_head_dim, obs.shape[1])
 
-    stats = []
+    stats, index_loss, keys, experts = [], 0.0, [], []
     for i, p in enumerate(params["layers"]):
         moe = i >= cfg.first_k_dense_replace
-        x, layer_stats = jax.checkpoint(partial(_block, cfg, moe))(x, p, bias, cos, sin)
+        if indexed:
+            x, (layer_stats, loss, layer_keys, layer_experts) = jax.checkpoint(
+                partial(_indexed_block, cfg, moe, emit_choices),
+                policy=jax.checkpoint_policies.save_only_these_names(KEPT))(x, p, valid)
+            index_loss = index_loss + loss
+            keys.append(layer_keys)
+            experts += [layer_experts] if moe else []
+        else:
+            x, layer_stats = jax.checkpoint(partial(_block, cfg, moe))(x, p, bias, cos, sin)
         if moe:
             stats.append(layer_stats)
     load, dropped = (jnp.stack(part) for part in zip(*stats))
     h = rms_norm(x[:, -1], params["final_norm"], cfg.rms_norm_eps)
-    return h, {"load": load, "dropped": dropped}
+    stats = {"load": load, "dropped": dropped}
+    if indexed:
+        stats["index_loss"] = index_loss
+        if emit_choices:
+            stats.update(keys=jnp.stack(keys), experts=jnp.stack(experts))
+    return h, stats

@@ -43,7 +43,9 @@ def policy_state_fns(config: D4PGConfig, noise_fns):
     """``(init, reset)`` of what a collecting policy carries from step to
     step: the noise state, and with a torso each env's last ``window``
     observations and how many of them belong to the running episode (a
-    torso policy acts on the window; an episode's start empties it)."""
+    torso policy acts on the window; an episode's start empties it, unless
+    the torso's windows span their stream: then the history is kept across
+    resets and only the noise starts anew)."""
     noise_init, _, noise_reset = noise_fns
     if config.torso is None:
         return noise_init, noise_reset
@@ -53,6 +55,8 @@ def policy_state_fns(config: D4PGConfig, noise_fns):
         return noise_init(), window, jnp.zeros((), jnp.int32)
 
     def reset(state):
+        if config.torso.span == "stream":
+            return (noise_reset(state[0]),) + tuple(state[1:])
         return noise_reset(state[0]), jnp.zeros_like(state[1]), jnp.zeros_like(state[2])
 
     return init, reset

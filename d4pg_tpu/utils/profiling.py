@@ -45,9 +45,10 @@ PHASES = (
     "ops.projection_loss",  # projection, cross-entropy, priority signal
     "agent.optimizer",      # both Adam updates, both Polyak updates
     "parallel.sync",        # train_step's _sync: det_pmean / pmean
-    # inside agent.networks, a sequence torso's two parts (models/torso.py)
-    "agent.attention",      # MLA: projections, rotary, scores, output
+    # inside agent.networks, a sequence torso's parts (models/torso.py)
+    "agent.attention",      # projections, norms, rotary, scores, softmax, output
     "agent.experts",        # router, dispatch plan, expert blocks, shared expert, combine
+    "agent.indexer",        # index projections and scores, the top-k, the alignment loss
 )
 # One path component of an instruction's ``op_name``, no "/" in it:
 # ``jit(lane)/while/body/closed_call/jvp(ph:agent.networks)/...``. An

@@ -41,6 +41,7 @@ TIMED = {"gather", "scatter", "dot", "all-gather", "all-reduce",
 COMMON = {"replay.draw", "replay.row_gather", "agent.networks",
           "ops.projection_loss", "agent.optimizer"}
 TORSO = {"agent.attention", "agent.experts"}     # opened by models/torso.py only
+INDEXER = {"agent.indexer"}                      # and only where attention runs under one
 
 
 def _cfg(**kw) -> D4PGConfig:
@@ -80,13 +81,12 @@ def _device_per_fused():
     return ms.make_megastep_device_per_fused(cfg, K, B), _shapes(cfg, 1, per=True)
 
 
-def _device_per_torso():
+def _device_per_torso(preset="glm47_flash_tiny"):
     import dataclasses
 
     from d4pg_tpu.models.torso import TORSO_PRESETS
 
-    torso = dataclasses.replace(TORSO_PRESETS["glm47_flash_tiny"], experts_first=2,
-                                experts_held=4)
+    torso = dataclasses.replace(TORSO_PRESETS[preset], experts_first=2, experts_held=4)
     cfg = _cfg(torso=torso)
     return ms.make_megastep_device_per(cfg, K, B), _shapes(cfg, 1, per=True)
 
@@ -111,8 +111,11 @@ VARIANTS = {
     "device_per": (_device_per, COMMON | {"replay.write_back"}),
     "device_per_fused": (_device_per_fused, COMMON | {"replay.write_back"}),
     "uniform_sharded": (_uniform_sharded, COMMON | {"parallel.sync"}),
-    "device_per_sharded": (_device_per_sharded, set(PHASES) - TORSO),
+    "device_per_sharded": (_device_per_sharded, set(PHASES) - TORSO - INDEXER),
     "device_per_torso": (_device_per_torso, COMMON | {"replay.write_back"} | TORSO),
+    "device_per_indexed_torso": (
+        lambda: _device_per_torso("keye_vl2_tiny"),
+        COMMON | {"replay.write_back"} | TORSO | INDEXER),
 }
 
 
@@ -152,9 +155,9 @@ def test_every_variant_holds_its_phases(variant):
     assert nested and {phase_of(n) for n in nested} == {"ops.projection_loss"}
     assert any("transpose(jvp(" in n for n in nested)
     if TORSO <= want:
-        # the torso's two parts nest in the networks' scope too, forward,
+        # the torso's parts nest in the networks' scope too, forward,
         # recomputed under jax.checkpoint and backward, and hold its dots
-        for part in TORSO:
+        for part in want & (TORSO | INDEXER):
             names = [n for op, n, _ in instructions if phase_of(n) == part]
             assert any(f"{PHASE_PREFIX}agent.networks" in n for n in names), part
             assert any("transpose(jvp(" in n for n in names), part

@@ -129,6 +129,12 @@ def build_parser() -> argparse.ArgumentParser:
                         "all of them); the router keeps its full width")
     p.add_argument("--torso-window", type=int, default=None, metavar="T",
                    help="history window in ring rows (default: the preset's)")
+    p.add_argument("--torso-span", choices=("episode", "stream"), default=None,
+                   help="what a history window may span: the drawn row's "
+                        "episode (the default), or its whole stream — the "
+                        "context is then the stream's last T rows across "
+                        "episode ends (in-context RL); a torso whose attention "
+                        "runs under an indexer")
     p.add_argument("--twin-critic", action="store_true",
                    help="clipped double-Q (TD3-style) distributional twin "
                         "critics; fixes the single-critic plateau on "
@@ -393,8 +399,14 @@ def config_from_args(args: argparse.Namespace) -> TrainConfig:
             changes.update(experts_first=first, experts_held=count)
         if args.torso_window is not None:
             changes["window"] = args.torso_window
-        agent = dataclasses.replace(
-            agent, torso=dataclasses.replace(TORSO_PRESETS[args.torso], **changes))
+        preset = TORSO_PRESETS[args.torso]
+        if args.torso_span is not None and args.torso_span != preset.span:
+            if "span" not in {f.name for f in dataclasses.fields(preset)}:
+                raise SystemExit(
+                    f"--torso-span {args.torso_span}: --torso {args.torso} states no "
+                    "span (its windows end where the episode began)")
+            changes["span"] = args.torso_span
+        agent = dataclasses.replace(agent, torso=dataclasses.replace(preset, **changes))
     # run-identity log dir (reference main.py:59-66)
     log_dir = args.log_dir or (
         f"runs/{args.env}_{'PER' if args.prioritized else 'UNI'}"
