@@ -41,11 +41,21 @@ the scheme converges to global-mass PER as priorities mix; at ``dp=1``
 it reduces to the host formula term for term.
 
 Backend ladder (the ``ops/pallas_projection.py`` convention): the jnp
-log-depth gather descent here is the reference program; a Pallas
+log-depth descent here is the reference program; a Pallas
 kernel that runs the same walk without the gathers
 (``ops/pallas_tree.py``) is selectable via
 ``TrainConfig.device_tree_backend="pallas"`` with the XLA path kept as
 its equivalence oracle.
+
+Draws (``descend_prefix``) walk the tree one level a step and read each
+step's left child by ONE gather for the whole batch — except on the top
+levels, whose few words every draw of a dispatch shares: there a large
+draw reads them by a fused compare-and-select over the level's static
+slice (``left_by_select``), no gather (``draw_plan``: the static shapes
+decide, no flag; a draw under ``DENSE_DRAW_MIN_DRAWS`` prefixes traces the
+all-gather walk, ``descend_prefix_gather``, op for op). The walk and its
+f32 arithmetic are the same either way, so the leaves are equal for every
+input.
 
 Writes (``set_leaves``: the post-step write-back and the ingest seed)
 are ONE scatter of the leaves, then the ancestors: level ``d`` is rebuilt
@@ -317,12 +327,65 @@ def stratified_prefixes(
     return jnp.minimum(pre, jnp.nextafter(total, jnp.float32(0.0)))
 
 
-def descend_prefix(sums_lane: jax.Array, prefixes: jax.Array) -> jax.Array:
-    """The XLA reference descent: for each prefix mass, the leaf index
-    ``i`` with ``cumsum[0..i-1] <= prefix < cumsum[0..i]`` — one vector
-    gather per tree level for the whole batch (the jnp twin of the host
+# The draw's twin of ``DENSE_REPAIR_RATIO``: on the tree's top levels every
+# draw of a dispatch reads the same few words, and a gather of them is the
+# slowest the walk makes. Step ``l`` of the walk (``2^l`` candidate left
+# children) is read by compare-and-select instead (:func:`left_by_select`)
+# while ``2^l <= DENSE_DRAW_MAX_WORDS``. Set from the v5e
+# (``scripts/tree_descent_levels.py``; PERF.md section 6, PR 33), 8,192
+# draws: a select of 2^9 / 2^12 / 2^13 / 2^14 words costs 5.4 / 25 / 48 / 93
+# us (it doubles a level: the vector unit's pace) against a gather's 58 us
+# from the 2^22-word tree (which XLA holds in on-chip memory) and 231 us on
+# the top levels of the 2^26-word one, 121 at 2^13 words, 113 at 2^14; the
+# whole walk is shortest at 14 dense levels in the small tree (565 us, 1,249
+# all-gather) and at 15 in the large (1,210, 3,920; 1,232 at 14), and at
+# 13-14 for 512-2,048 draws.
+DENSE_DRAW_MAX_WORDS = 8192
+# ... and only for a draw of at least this many prefixes. The same runs: at
+# 512 draws the walk with a dense top takes 69 against 102 us (2^22 words)
+# and 95 against 222 (2^26). At 256 it would still win 17 and 57 us, of a
+# step that draws so few for a model of 0.3-0.8 s a step: nothing — and
+# those programs are then the all-gather walk's, op for op.
+DENSE_DRAW_MIN_DRAWS = 512
+
+
+def draw_plan(width: int, n: int) -> tuple[int, int]:
+    """``(dense_levels, gather_levels)`` of a descent of ``n`` prefixes
+    through a ``[width]`` tree lane: the top ``dense_levels`` steps of the
+    walk read their left children by select (:func:`left_by_select`), the
+    ``gather_levels`` below them by one gather a level. Decided by the two
+    static shapes alone."""
+    depth = (width // 2).bit_length() - 1
+    dense = 0
+    if n >= DENSE_DRAW_MIN_DRAWS:
+        # 2^l <= W  <=>  l < bit_length(W)
+        dense = min(depth, DENSE_DRAW_MAX_WORDS.bit_length())
+    return dense, depth - dense
+
+
+def describe_draw(width: int, n: int) -> dict:
+    """The static line that says how a draw of ``n`` prefixes descends a
+    ``[width]`` lane (``Trainer`` logs it once, beside
+    :func:`describe_repair`)."""
+    dense, gather = draw_plan(width, n)
+    return {
+        "tree_width": width, "draws": n, "dense_levels": dense,
+        "gather_levels": gather, "max_words": DENSE_DRAW_MAX_WORDS,
+        "min_draws": DENSE_DRAW_MIN_DRAWS,
+    }
+
+
+def descend_prefix_gather(
+    sums_lane: jax.Array, prefixes: jax.Array
+) -> jax.Array:
+    """The all-gather descent: for each prefix mass, the leaf index ``i``
+    with ``cumsum[0..i-1] <= prefix < cumsum[0..i]`` — one vector gather
+    per tree level for the whole batch (the jnp twin of the host
     ``SumTree.find_prefixsum_idx``, >= semantics so zero-mass leaves are
-    skipped and boundary prefixes select the next leaf)."""
+    skipped and boundary prefixes select the next leaf). What
+    :func:`descend_prefix` traces when no level is dense, and the oracle
+    its dense top is held to, bit for bit
+    (``tests/test_dense_descent.py``)."""
     width = sums_lane.shape[0]
     half = width // 2
     depth = half.bit_length() - 1
@@ -330,6 +393,54 @@ def descend_prefix(sums_lane: jax.Array, prefixes: jax.Array) -> jax.Array:
     idx = jnp.ones(flat.shape, jnp.int32)
     for _ in range(depth):
         left = sums_lane[2 * idx]
+        go_right = flat >= left
+        flat = flat - jnp.where(go_right, left, jnp.float32(0.0))
+        idx = 2 * idx + go_right.astype(jnp.int32)
+    return (idx - half).reshape(prefixes.shape)
+
+
+def left_by_select(
+    sums_lane: jax.Array, idx: jax.Array, level: int
+) -> jax.Array:
+    """``sums_lane[2 * idx]`` for ``idx`` in ``[2^level, 2^(level+1))``
+    without a gather: the candidates are the static stride-2 slice of the
+    child level (``2^level`` words), each draw keeps its own by a compare
+    against an iota, and the sum over the candidate axis adds ``+0.0`` for
+    every other — exact. The ``[2^level, n]`` compare fuses into the reduce
+    (the candidates on the major axis: an elementwise accumulate, no
+    cross-lane step)."""
+    lo = 2 << level                    # children [lo, 2 lo), parents [lo/2, lo)
+    lefts = jax.lax.slice(sums_lane, (lo,), (2 * lo,), (2,))
+    mine = idx - (lo // 2)
+    which = jax.lax.broadcasted_iota(jnp.int32, (lo // 2, idx.shape[0]), 0)
+    return jnp.sum(
+        jnp.where(which == mine[None, :], lefts[:, None], jnp.float32(0.0)),
+        axis=0,
+    )
+
+
+def descend_prefix(
+    sums_lane: jax.Array, prefixes: jax.Array,
+    dense_levels: int | None = None,
+) -> jax.Array:
+    """The XLA reference descent — :func:`descend_prefix_gather`'s walk,
+    step for step and in f32, so the leaves are its leaves for every input
+    — with the left child read by select instead of by gather on the top
+    ``dense_levels`` steps (default: :func:`draw_plan`'s for this lane and
+    this many prefixes; clamped to the tree's depth)."""
+    width = sums_lane.shape[0]
+    half = width // 2
+    depth = half.bit_length() - 1
+    flat = prefixes.reshape(-1)
+    if dense_levels is None:
+        dense_levels, _ = draw_plan(width, flat.shape[0])
+    dense = min(dense_levels, depth)
+    idx = jnp.ones(flat.shape, jnp.int32)
+    for level in range(depth):
+        if level < dense:
+            left = left_by_select(sums_lane, idx, level)
+        else:
+            left = sums_lane[2 * idx]
         go_right = flat >= left
         flat = flat - jnp.where(go_right, left, jnp.float32(0.0))
         idx = 2 * idx + go_right.astype(jnp.int32)

@@ -512,6 +512,7 @@ class Trainer:
                     # desync).
                     from d4pg_tpu.replay.device_per import (
                         DevicePerSync,
+                        describe_draw,
                         describe_repair,
                     )
 
@@ -521,15 +522,20 @@ class Trainer:
                         mesh=self._mega_mesh,
                     )
                     self._ring_sync.tree_hook = self._dev_per.on_chunk
-                    # Static per (lane width, positions written): which
-                    # levels a write-back repairs position by position and
-                    # which it rebuilds whole (device_per.repair_plan).
+                    # Static per (lane width, positions a dispatch draws and
+                    # writes): which levels a write-back repairs position by
+                    # position and which it rebuilds whole
+                    # (device_per.repair_plan), and which levels a draw reads
+                    # by select and which by gather (device_per.draw_plan).
+                    lane_width = self._dev_per.tree.sums.shape[1]
+                    positions = K * (config.batch_size // (config.dp or 1))
                     print(
                         "[replay] device tree repair: "
-                        + json.dumps(describe_repair(
-                            self._dev_per.tree.sums.shape[1],
-                            K * (config.batch_size // (config.dp or 1)),
-                        ))
+                        + json.dumps(describe_repair(lane_width, positions))
+                    )
+                    print(
+                        "[replay] device tree draw: "
+                        + json.dumps(describe_draw(lane_width, positions))
                     )
                 if self._mega_mesh is not None:
                     # Sharded megastep (ROADMAP item 2): state placed per

@@ -212,3 +212,34 @@ def test_write_back_is_one_scatter_and_an_in_place_dense_rebuild(
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes <= 4 * half + (16 << 20), (
         memory.temp_size_in_bytes, 4 * half)
+
+
+# ------------------------------------------------------ the PER tree's draw
+# ISSUE 33: the top levels of the descent, which every draw of a dispatch
+# shares, are read by a fused compare-and-select over the level's static
+# slice (``replay/device_per.py:left_by_select``). At the cells' own tree
+# sizes the compiled draw phase gathers from the tree once a level below
+# ``draw_plan``'s dense top and once more for the drawn leaves, every select
+# is a fusion whose result is the draws' ``f32[8192]`` — the ``[2^l, 8192]``
+# compare never leaves it — and the program holds no ``tpu_custom_call``.
+@pytest.mark.parametrize("tree_elements", [2 ** 26, 2 ** 22])
+def test_draw_gathers_only_below_the_dense_top(one_chip, tree_elements):
+    from d4pg_tpu.replay import device_per as dper
+
+    draws = WRITE_BACK_K * WRITE_BACK_B
+    dense, gather = dper.draw_plan(tree_elements, draws)
+    assert dense > 0
+    compiled = _per_megastep(one_chip, tree_elements, n_rows=tree_elements // 2)
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    fusions = [line for line in text.splitlines()
+               if "ph:replay.draw" in line and " fusion(" in line]
+    assert fusions, "the draw lost its scope"
+    gathers = [line for line in fusions
+               if "kind=kCustom" in line and re.search(rf"= f32\[{draws}\]", line)]
+    assert len(gathers) == gather + 1, (len(gathers), gather)
+    selects = [line for line in fusions
+               if "reduce_sum" in line and re.search(rf"= f32\[{draws}\]", line)]
+    assert len(selects) >= dense - 1          # level 0 is one word: a broadcast
+    wide = [line for line in fusions if re.search(rf"\[\d+,{draws}\]", line.split(" fusion(")[0])]
+    assert not wide, wide[:3]

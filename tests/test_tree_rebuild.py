@@ -213,10 +213,10 @@ def test_write_back_moves_no_other_leaf_and_keeps_the_root():
     assert got[1] == level[0]
 
 
-def test_trainer_logs_the_tree_repair_once(tmp_path, capsys):
-    """Which way a write-back is repaired is static (lane width, positions
-    a dispatch writes), so the trainer prints it once at start-up, next to
-    the ring's storage line."""
+def test_trainer_logs_the_tree_repair_and_the_draw_once(tmp_path, capsys):
+    """Which way a write-back is repaired and a draw descends is static
+    (lane width, positions a dispatch draws and writes), so the trainer
+    prints both once at start-up, next to the ring's storage line."""
     import json
 
     from d4pg_tpu.runtime.trainer import Trainer
@@ -225,12 +225,22 @@ def test_trainer_logs_the_tree_repair_once(tmp_path, capsys):
     cfg = _trainer_cfg("device", str(tmp_path / "d"))   # B=8, K=2, 512 rows
     t = Trainer(cfg)
     try:
-        described = dper.describe_repair(t._dev_per.tree.sums.shape[1], 2 * 8)
+        width = t._dev_per.tree.sums.shape[1]
+        described = dper.describe_repair(width, 2 * 8)
+        draw = dper.describe_draw(width, 2 * 8)
     finally:
         t.close()
-    lines = [line for line in capsys.readouterr().out.splitlines()
-             if line.startswith("[replay] device tree repair: ")]
-    assert len(lines) == 1
-    assert json.loads(lines[0].split(": ", 1)[1]) == described == {
+    out = capsys.readouterr().out.splitlines()
+
+    def logged(tag):
+        lines = [line for line in out if line.startswith(tag)]
+        assert len(lines) == 1, (tag, lines)
+        return json.loads(lines[0].split(": ", 1)[1])
+
+    assert logged("[replay] device tree repair: ") == described == {
         "tree_width": 1024, "positions": 16, "sparse_levels": 0,
         "dense_levels": 9, "R": dper.DENSE_REPAIR_RATIO}
+    assert logged("[replay] device tree draw: ") == draw == {
+        "tree_width": 1024, "draws": 16, "dense_levels": 0,
+        "gather_levels": 9, "max_words": dper.DENSE_DRAW_MAX_WORDS,
+        "min_draws": dper.DENSE_DRAW_MIN_DRAWS}

@@ -212,6 +212,8 @@ MEGASTEP_FUNCTIONS = (
     "d4pg_tpu/replay/device_per.py::set_leaves",
     "d4pg_tpu/replay/device_per.py::update_leaves_last_wins",
     "d4pg_tpu/replay/device_per.py::stratified_prefixes",
+    "d4pg_tpu/replay/device_per.py::descend_prefix_gather",
+    "d4pg_tpu/replay/device_per.py::left_by_select",
     "d4pg_tpu/replay/device_per.py::descend_prefix",
     "d4pg_tpu/replay/device_per.py::lane_draw",
     "d4pg_tpu/replay/device_per.py::lane_min_leaf",
