@@ -113,7 +113,8 @@ def _check_torso(config: D4PGConfig) -> None:
             "projection (the fused tier's descent is not wired through it)")
 
 
-# what a torso pass under an indexer reports of its choices (models/torso.py)
+# what a torso pass reports of its choices (models/torso.py): under an indexer
+# all four, in the hybrid stack all but the keys (it chooses none)
 CHOICES = ("keys", "experts", "load", "dropped")
 
 
@@ -809,7 +810,7 @@ def train_step(
         return new_state, metrics, priorities, descent_idx
     if emit_choices:
         return new_state, metrics, priorities, {
-            k: jnp.stack([extras[k], target_extras[k]]) for k in CHOICES}
+            k: jnp.stack([extras[k], target_extras[k]]) for k in CHOICES if k in extras}
     return new_state, metrics, priorities
 
 

@@ -13,16 +13,24 @@ one preset a published model:
               are chosen per query by a learned indexer, which has a loss of
               its own (DeepSeek-V3.2's sparse attention at a Qwen3-MoE
               block's sizes; ``IndexedTorsoConfig``)
+              ``gated_delta_hybrid`` — a mixer chosen by the layer's index:
+              Gated DeltaNet (a causal depthwise convolution, then the gated
+              delta rule's state along the time axis, ``ops/gated_delta.py``)
+              and, every ``full_attention_interval``-th layer, grouped-query
+              attention with an output gate and a partial rotary turn; norms
+              are zero-centred (Qwen3-Next's; ``HybridTorsoConfig``)
   router      ``sigmoid_bias`` — sigmoid scores, a selection bias, top-k,
               renormalised and scaled gates
               ``softmax`` — softmax over all experts, top-k, renormalised
   FFN         ``first_k_dense_replace`` leading dense SwiGLU layers (0…n),
               routed-expert layers after them, ``n_shared_experts`` (0 | 1)
-              beside the routed ones
+              beside the routed ones, behind a sigmoid gate of its own where
+              the kind says so
 
-The two attention kinds have widths of their own, so each has its class;
-what they share is ``TorsoShape``. (``TorsoConfig`` keeps its name and its
-24 fields: the accepted benchmark's configuration file states them.)
+The attention kinds have widths of their own, so each has its class and
+states what it needs (``check_parts``); what they share is ``TorsoShape``.
+(``TorsoConfig`` keeps its name and its 24 fields: the accepted benchmark's
+configuration file states them.)
 
 The expert layer is **told which experts it holds** (``experts_first``,
 ``experts_held``): it routes over all ``n_routed_experts``, keeps the pairs
@@ -55,6 +63,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from d4pg_tpu.ops.gated_delta import gated_delta_chunked
 from d4pg_tpu.utils.profiling import phase
 
 MASKED = -1e30   # finite: a window position with no valid key stays finite
@@ -101,14 +110,30 @@ class TorsoShape:
     expert_block_rows: int = 256
     batch_chunks: int = 4         # latent attention and the dense SwiGLU run on B/4 windows at a time
 
+    zero_centred_norms: ClassVar[bool] = False   # n(x) = x/rms(x) · (1 + w), w from 0
+    shared_expert_gate: ClassVar[bool] = False   # σ(x · w_sg) in front of the shared expert
+
     @property
     def num_moe_layers(self) -> int:
         return self.num_hidden_layers - self.first_k_dense_replace
 
+    @property
+    def shared_width(self) -> int:
+        return self.moe_intermediate_size * self.n_shared_experts
+
+    def mixer(self, layer: int) -> str:
+        """The kind of layer ``layer``'s token mixer."""
+        return self.attention
+
     def score_tile_bytes(self) -> int:
         """One window's largest float32 score tile: heads x a query chunk x
-        every key."""
+        every key, of the layers that build one."""
         return 4 * self.num_attention_heads * (self.window // self.query_chunks) * self.window
+
+    def check_parts(self) -> None:
+        raise ValueError(
+            f"{type(self).__name__} states no attention: a torso is a TorsoConfig, an "
+            "IndexedTorsoConfig or a HybridTorsoConfig")
 
     def padded_pairs(self, tokens: int) -> int:
         """Rows of the dispatch buffer: every pair that can land on a held
@@ -139,6 +164,10 @@ class TorsoConfig(TorsoShape):
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
+    def check_parts(self) -> None:
+        if self.qk_rope_head_dim % 2:
+            raise ValueError("qk_rope_head_dim must be even")
+
 
 @dataclasses.dataclass(frozen=True, kw_only=True)
 class IndexedTorsoConfig(TorsoShape):
@@ -160,6 +189,78 @@ class IndexedTorsoConfig(TorsoShape):
     # the episodes of its stream (the context is the stream's history).
     span: str = "episode"
     batch_chunks: int = 1
+
+    def check_parts(self) -> None:
+        if self.head_dim % 2 or self.index_head_dim % 2:
+            raise ValueError("head_dim and index_head_dim must be even")
+        if self.num_attention_heads % self.num_key_value_heads or self.index_topk < 1:
+            raise ValueError("query heads in whole groups a key-value head, index_topk >= 1")
+
+
+@dataclasses.dataclass(frozen=True, kw_only=True)
+class HybridTorsoConfig(TorsoShape):
+    """Gated DeltaNet layers with a gated grouped-query attention layer every
+    ``full_attention_interval``-th, under a softmax router with a gated
+    shared expert."""
+
+    attention: ClassVar[str] = "gated_delta_hybrid"
+    router: ClassVar[str] = "softmax"
+    zero_centred_norms: ClassVar[bool] = True
+    shared_expert_gate: ClassVar[bool] = True
+
+    full_attention_interval: int
+    # the attention layers
+    num_key_value_heads: int
+    head_dim: int
+    partial_rotary_factor: float
+    # the Gated DeltaNet layers
+    linear_num_key_heads: int
+    linear_num_value_heads: int
+    linear_key_head_dim: int
+    linear_value_head_dim: int
+    linear_conv_kernel_dim: int
+    shared_expert_intermediate_size: int
+    # tokens of one chunk of the delta rule's scan (ops/gated_delta.py)
+    delta_chunk: int = 64
+    query_chunks: int = 1
+    span: str = "episode"
+    batch_chunks: int = 1
+
+    @property
+    def shared_width(self) -> int:
+        return self.shared_expert_intermediate_size * self.n_shared_experts
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * self.partial_rotary_factor)
+
+    def mixer(self, layer: int) -> str:
+        return "attention" if (layer + 1) % self.full_attention_interval == 0 else "linear"
+
+    def score_tile_bytes(self) -> int:
+        """A DeltaNet layer has no score tile; the attention layers' is
+        ``[heads, T / query_chunks, T]`` — where the depth kept holds one."""
+        if "attention" not in map(self.mixer, range(self.num_hidden_layers)):
+            return 0
+        return super().score_tile_bytes()
+
+    def check_parts(self) -> None:
+        if self.first_k_dense_replace:
+            raise ValueError("the hybrid stack has no leading dense layer")
+        if self.full_attention_interval < 1 or self.window % self.delta_chunk:
+            raise ValueError(
+                f"window {self.window} in whole chunks of {self.delta_chunk} tokens, an "
+                f"attention layer every {self.full_attention_interval}-th")
+        if self.rotary_dim % 2 or not 0 < self.rotary_dim <= self.head_dim:
+            raise ValueError(
+                f"the rotary turn covers {self.rotary_dim} of {self.head_dim} head dims: "
+                "an even count, at most all")
+        if (self.num_attention_heads % self.num_key_value_heads
+                or self.linear_num_value_heads % self.linear_num_key_heads):
+            raise ValueError(
+                "query heads in whole groups a key-value head, value heads a key head")
+        if self.linear_conv_kernel_dim < 1:
+            raise ValueError("linear_conv_kernel_dim >= 1")
 
 
 # One preset a published model (its widths under its own key names) and a
@@ -208,6 +309,34 @@ TORSO_PRESETS = {
         num_experts_per_tok=4, rms_norm_eps=1e-6, index_n_heads=8, index_head_dim=4,
         index_topk=6, experts_held=16, window=16, query_chunks=4, expert_block_rows=8,
     ),
+    # Qwen/Qwen3-Next-80B-A3B-Instruct config.json, model_type qwen3_next
+    # (num_experts -> n_routed_experts; one shared expert behind its gate)
+    "qwen3_next": HybridTorsoConfig(
+        name="qwen3_next", hidden_size=2048, num_hidden_layers=48,
+        first_k_dense_replace=0, full_attention_interval=4, num_attention_heads=16,
+        num_key_value_heads=2, head_dim=256, partial_rotary_factor=0.25,
+        rope_theta=10_000_000.0, linear_num_key_heads=16, linear_num_value_heads=32,
+        linear_key_head_dim=128, linear_value_head_dim=128, linear_conv_kernel_dim=4,
+        intermediate_size=5120, moe_intermediate_size=512, n_routed_experts=512,
+        n_shared_experts=1, shared_expert_intermediate_size=512, num_experts_per_tok=10,
+        rms_norm_eps=1e-6, experts_held=512, window=8192, query_chunks=16,
+        # 160 rows an expert are live (8,192 x 10 / 512): the expert layer's
+        # forward and backward took 29.15 / 28.20 / 29.31 ms at 128 / 256 /
+        # 512 rows a block on the chip (PERF.md section 6, PR 34)
+        expert_block_rows=256,
+    ),
+    # three DeltaNet layers and the attention layer; four chunks a window
+    "qwen3_next_tiny": HybridTorsoConfig(
+        name="qwen3_next_tiny", hidden_size=32, num_hidden_layers=4,
+        first_k_dense_replace=0, full_attention_interval=4, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=8, partial_rotary_factor=0.25,
+        rope_theta=10_000_000.0, linear_num_key_heads=2, linear_num_value_heads=4,
+        linear_key_head_dim=8, linear_value_head_dim=8, linear_conv_kernel_dim=4,
+        intermediate_size=48, moe_intermediate_size=12, n_routed_experts=16,
+        n_shared_experts=1, shared_expert_intermediate_size=12, num_experts_per_tok=4,
+        rms_norm_eps=1e-6, experts_held=16, window=16, query_chunks=4, delta_chunk=4,
+        expert_block_rows=8,
+    ),
 }
 
 
@@ -220,6 +349,7 @@ def validate(cfg: TorsoShape) -> None:
         raise ValueError("torso needs an expert layer and a held expert")
     if cfg.n_shared_experts not in (0, 1):
         raise ValueError("no shared expert or one is what the layer computes")
+    cfg.check_parts()             # what the kind's own fields must hold
     if cfg.window % cfg.query_chunks or cfg.span not in ("episode", "stream"):
         raise ValueError(
             f"window {cfg.window} in {cfg.query_chunks} query chunks, span {cfg.span!r}")
@@ -230,14 +360,20 @@ def validate(cfg: TorsoShape) -> None:
             f"x {cfg.window} keys, float32): batch chunks do not split a window, "
             "raise query_chunks" + (
                 "" if cfg.attention != "latent" else " (latent attention has none)"))
-    if cfg.attention == "latent":
-        if cfg.qk_rope_head_dim % 2:
-            raise ValueError("qk_rope_head_dim must be even")
-    else:
-        if cfg.head_dim % 2 or cfg.index_head_dim % 2:
-            raise ValueError("head_dim and index_head_dim must be even")
-        if cfg.num_attention_heads % cfg.num_key_value_heads or cfg.index_topk < 1:
-            raise ValueError("query heads in whole groups a key-value head, index_topk >= 1")
+
+
+def describe_mixers(cfg: TorsoShape) -> dict:
+    """What the stack is made of, from the configuration alone: the layers'
+    mixers in order and, where the delta rule runs, its chunk length, the
+    chunks a window and the bytes of state a window carries through them."""
+    mixers = [cfg.mixer(i) for i in range(cfg.num_hidden_layers)]
+    out = {"mixers": mixers, "window": cfg.window}
+    if "linear" in mixers:
+        state = 4 * (cfg.linear_num_value_heads * cfg.linear_key_head_dim
+                     * cfg.linear_value_head_dim)
+        out.update(chunk=cfg.delta_chunk, chunks_per_window=cfg.window // cfg.delta_chunk,
+                   state_bytes_per_window=state * mixers.count("linear"))
+    return out
 
 
 # ------------------------------------------------------------------ init
@@ -283,10 +419,46 @@ def _attention_init(cfg: TorsoShape, ks) -> dict:
     }
 
 
-def _block_init(cfg: TorsoShape, key, moe: bool) -> dict:
+def _hybrid_mixer_init(cfg: HybridTorsoConfig, ks, kind: str) -> dict:
+    """A Gated DeltaNet layer's leaves under ``lin`` (the source's names:
+    in_proj_qkvz, in_proj_ba, conv1d, A_log, dt_bias, norm, out_proj) or a
+    gated attention layer's under ``attn`` (q carries the output gate)."""
+    d = cfg.hidden_size
+    if kind == "attention":
+        h, kv, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+        return {"attn": {
+            "q": _uniform(next(ks), (d, h * hd * 2), d),
+            "q_norm": _norm_init(cfg, hd),
+            "k": _uniform(next(ks), (d, kv * hd), d),
+            "k_norm": _norm_init(cfg, hd),
+            "v": _uniform(next(ks), (d, kv * hd), d),
+            "o": _uniform(next(ks), (h * hd, d), h * hd),
+        }}
+    hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
+    key_dim, value_dim = hk * cfg.linear_key_head_dim, hv * cfg.linear_value_head_dim
+    width = cfg.linear_conv_kernel_dim
+    # the source's: A = U(0, 16) and a step dt log-uniform in [1e-3, 1e-1],
+    # stored through the inverse of the softplus that reads it
+    a = jax.random.uniform(next(ks), (hv,), jnp.float32, 1e-6, 16.0)
+    dt = jnp.exp(jax.random.uniform(next(ks), (hv,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+    return {"lin": {
+        "in_qkvz": _uniform(next(ks), (d, 2 * key_dim + 2 * value_dim), d),
+        "in_ba": _uniform(next(ks), (d, 2 * hv), d),
+        "conv": _uniform(next(ks), (2 * key_dim + value_dim, width), width),
+        "A_log": jnp.log(a),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "norm": jnp.ones((cfg.linear_value_head_dim,), jnp.float32),
+        "out": _uniform(next(ks), (value_dim, d), value_dim),
+    }}
+
+
+def _block_init(cfg: TorsoShape, key, moe: bool, layer: int = 0) -> dict:
     d = cfg.hidden_size
     ks = iter(jax.random.split(key, 16))
-    attention = _attention_init(cfg, ks)
+    if cfg.attention == "gated_delta_hybrid":
+        attention = _hybrid_mixer_init(cfg, ks, cfg.mixer(layer))
+    else:
+        attention = _attention_init(cfg, ks)
 
     def swiglu(width, lead=()):
         return {
@@ -304,14 +476,21 @@ def _block_init(cfg: TorsoShape, key, moe: bool) -> dict:
             ffn["router_bias"] = jnp.zeros((cfg.n_routed_experts,), jnp.float32)
         ffn["experts"] = swiglu(cfg.moe_intermediate_size, (cfg.experts_held,))
         if cfg.n_shared_experts:
-            ffn["shared"] = swiglu(cfg.moe_intermediate_size * cfg.n_shared_experts)
+            ffn["shared"] = swiglu(cfg.shared_width)
+            if cfg.shared_expert_gate:
+                ffn["shared_gate"] = _uniform(next(ks), (d, 1), d)
     else:
         ffn = swiglu(cfg.intermediate_size)
     return {
-        "attn_norm": jnp.ones((d,), jnp.float32),
-        "ffn_norm": jnp.ones((d,), jnp.float32),
+        "attn_norm": _norm_init(cfg, d), "ffn_norm": _norm_init(cfg, d),
         **attention, "ffn": ffn,
     }
+
+
+def _norm_init(cfg: TorsoShape, width: int):
+    """A block norm's weight where training starts: the scale is ``w``, or
+    ``1 + w`` where the kind's norms are zero-centred."""
+    return (jnp.zeros if cfg.zero_centred_norms else jnp.ones)((width,), jnp.float32)
 
 
 def torso_init(cfg: TorsoShape, key, obs_dim: int) -> dict:
@@ -325,9 +504,9 @@ def torso_init(cfg: TorsoShape, key, obs_dim: int) -> dict:
             "bias": _uniform(k_b, (cfg.hidden_size,), obs_dim),
         },
         "layers": [
-            _block_init(cfg, k, moe=i >= cfg.first_k_dense_replace)
+            _block_init(cfg, k, moe=i >= cfg.first_k_dense_replace, layer=i)
             for i, k in enumerate(jax.random.split(k_layers, cfg.num_hidden_layers))],
-        "final_norm": jnp.ones((cfg.hidden_size,), jnp.float32),
+        "final_norm": _norm_init(cfg, cfg.hidden_size),
     }
 
 
@@ -335,6 +514,11 @@ def torso_init(cfg: TorsoShape, key, obs_dim: int) -> dict:
 def rms_norm(x, weight, eps):
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     return x * jax.lax.rsqrt(var + eps) * weight
+
+
+def block_norm(cfg: TorsoShape, x, weight):
+    """The kind's RMSNorm: scale ``w``, or ``1 + w`` where zero-centred."""
+    return rms_norm(x, 1.0 + weight if cfg.zero_centred_norms else weight, cfg.rms_norm_eps)
 
 
 def rope_tables(theta: float, rope: int, positions: int):
@@ -431,19 +615,26 @@ def choose_keys(scores, see, k: int):
     return (above | (ties & (jnp.cumsum(ties, axis=-1, dtype=jnp.int32) <= room))) & see
 
 
+def _chunk_attention(kv: int, q, k, v, member):
+    """Grouped-query softmax attention of one query chunk ``q [B, Tq, H,
+    d]`` over the keys ``member [B, Tq, Ts]`` admits (``k, v [B, Ts, kv,
+    d]``): ``(out [B, Tq, H·d], probs [B, kv, H/kv, Tq, Ts])``."""
+    b, tq, h, hd = q.shape
+    with phase("agent.attention"):
+        logits = jnp.einsum("btkgd,bskd->bkgts", q.reshape(b, tq, kv, h // kv, hd), k)
+        logits = jnp.where(member[:, None, None], logits / math.sqrt(hd), MASKED)
+        probs = jax.nn.softmax(logits, axis=-1)
+        out = jnp.einsum("bkgts,bskd->btkgd", probs, v).reshape(b, tq, h * hd)
+    return out, probs
+
+
 def _attend(cfg: IndexedTorsoConfig, q, k, v, member, scores, valid_q):
     """One query chunk against the keys up to its end: softmax over the
     chosen keys only, and the chunk's part of the indexer's alignment loss —
     ``KL(p ‖ softmax over the chosen keys of the index scores)`` summed over
     its valid queries, ``p`` the attention's probabilities summed over the
     heads and normalised, cut from the graph."""
-    b, tq, h, hd = q.shape
-    kv = cfg.num_key_value_heads
-    with phase("agent.attention"):
-        logits = jnp.einsum("btkgd,bskd->bkgts", q.reshape(b, tq, kv, h // kv, hd), k)
-        logits = jnp.where(member[:, None, None], logits / math.sqrt(hd), MASKED)
-        probs = jax.nn.softmax(logits, axis=-1)
-        out = jnp.einsum("bkgts,bskd->btkgd", probs, v).reshape(b, tq, h * hd)
+    out, probs = _chunk_attention(cfg.num_key_value_heads, q, k, v, member)
     with phase("agent.indexer"):
         p = jax.lax.stop_gradient(jnp.sum(probs, axis=(1, 2)))
         p = p / jnp.sum(p, axis=-1, keepdims=True)
@@ -500,6 +691,101 @@ def indexed_attention(cfg: IndexedTorsoConfig, p: dict, index: dict, x, valid, e
     with phase("agent.indexer"):
         loss = loss / jnp.maximum(jnp.sum(valid), 1)
     return out, loss, jnp.concatenate(masks, axis=1) if emit else None
+
+
+def gated_attention(cfg: HybridTorsoConfig, p: dict, x, valid):
+    """Grouped-query attention on ``[B, T, D]`` whose query projection
+    carries an output gate: ``(q, gate) = x W_q`` per head, zero-centred
+    norms on q and k per head, the rotary turn on the first ``rotary_dim``
+    head dims, causal softmax over the valid keys, ``(attn ⊙ σ(gate)) W_o``.
+    The queries run ``T / query_chunks`` at a time through the indexed
+    kind's chunk path, every causal valid key a member."""
+    b, t, _ = x.shape
+    h, kv, hd, rot = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim, cfg.rotary_dim
+    with phase("agent.attention"):
+        cos, sin = rope_tables(cfg.rope_theta, rot, t)
+        turn = lambda y: jnp.concatenate(  # noqa: E731
+            [apply_rope(y[..., :rot], cos, sin), y[..., rot:]], axis=-1)
+        q_gate = (x @ p["q"]).reshape(b, t, h, 2 * hd)
+        q = turn(block_norm(cfg, q_gate[..., :hd], p["q_norm"]))
+        gate = q_gate[..., hd:].reshape(b, t, h * hd)
+        k = turn(block_norm(cfg, (x @ p["k"]).reshape(b, t, kv, hd), p["k_norm"]))
+        v = (x @ p["v"]).reshape(b, t, kv, hd)
+    causal = jnp.tril(jnp.ones((t, t), bool))
+    size = t // cfg.query_chunks
+    outs = []
+    for lo in range(0, t, size):
+        hi = lo + size
+        see = causal[lo:hi, :hi][None] & valid[:, None, :hi]
+        out = jax.checkpoint(lambda *a: _chunk_attention(kv, *a)[0])(
+            q[:, lo:hi], k[:, :hi], v[:, :hi], see)
+        outs.append(checkpoint_name(out, KEPT))
+    with phase("agent.attention"):
+        return (jnp.concatenate(outs, axis=1) * jax.nn.sigmoid(gate)) @ p["o"]
+
+
+@jax.custom_vjp
+def causal_conv(x, weight):
+    """Depthwise convolution over time of ``x [B, T, C]`` with ``weight [C,
+    W]``, zeros before the window: ``y_t = Σ_j weight[:, j] · x_{t−(W−1)+j}``.
+    Its backward pass is written out (the same shifted sum run forward in
+    time, and one reduction a tap): autodiff's pads each tap's ``[T, C]``
+    product by itself, W of them held at once."""
+    t, width = x.shape[1], weight.shape[1]
+    padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+    return sum(padded[:, j:j + t] * weight[:, j] for j in range(width))
+
+
+def _conv_fwd(x, weight):
+    return causal_conv(x, weight), (x, weight)
+
+
+def _conv_bwd(res, dy):
+    x, weight = res
+    t, width = x.shape[1], weight.shape[1]
+    ahead = jnp.pad(dy, ((0, 0), (0, width - 1), (0, 0)))
+    dx = sum(ahead[:, width - 1 - j:width - 1 - j + t] * weight[:, j] for j in range(width))
+    padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+    d_weight = jnp.stack(
+        [jnp.sum(padded[:, j:j + t] * dy, axis=(0, 1)) for j in range(width)], axis=1)
+    return dx, d_weight
+
+
+causal_conv.defvjp(_conv_fwd, _conv_bwd)
+
+
+def gated_delta_net(cfg: HybridTorsoConfig, p: dict, x, valid):
+    """Gated DeltaNet on the block's normed input ``[B, T, D]``: ``(q, k, v,
+    z) = x W_qkvz`` laid out by key head, ``(b, a) = x W_ba``; ``(q, k, v) ←
+    SiLU(conv(q ‖ k ‖ v))``; per value head (key head ``h // r``) ``q̂ =
+    q/‖q‖ · dk^-½``, ``k̂ = k/‖k‖``, ``β = σ(b)``, ``g = −exp(A_log) ·
+    softplus(a + dt_bias)``, the gated delta rule in chunks; then ``y = (w ⊙
+    o / rms(o)) ⊙ SiLU(z)`` per head and ``W_out``. Positions outside the
+    window (a prefix) have their input set to zero: they write nothing."""
+    b, t, _ = x.shape
+    hk, hv, dk, dv = (cfg.linear_num_key_heads, cfg.linear_num_value_heads,
+                      cfg.linear_key_head_dim, cfg.linear_value_head_dim)
+    r = hv // hk
+    with phase("agent.linear_attention"):
+        x = jnp.where(valid[..., None], x, 0.0)
+        qkvz = (x @ p["in_qkvz"]).reshape(b, t, hk, 2 * dk + 2 * r * dv)
+        ba = (x @ p["in_ba"]).reshape(b, t, hk, 2 * r)
+        z = qkvz[..., 2 * dk + r * dv:].reshape(b, t, hv, dv)
+        mixed = jnp.concatenate(
+            [qkvz[..., :dk].reshape(b, t, hk * dk), qkvz[..., dk:2 * dk].reshape(b, t, hk * dk),
+             qkvz[..., 2 * dk:2 * dk + r * dv].reshape(b, t, hv * dv)], axis=-1)
+        mixed = jax.nn.silu(causal_conv(mixed, p["conv"]))
+        unit = lambda y: y * jax.lax.rsqrt(  # noqa: E731
+            jnp.sum(jnp.square(y), axis=-1, keepdims=True) + 1e-6)
+        q = unit(mixed[..., :hk * dk].reshape(b, t, hk, dk)) * dk ** -0.5
+        k = unit(mixed[..., hk * dk:2 * hk * dk].reshape(b, t, hk, dk))
+        v = mixed[..., 2 * hk * dk:].reshape(b, t, hv, dv)
+        beta = jax.nn.sigmoid(ba[..., :r].reshape(b, t, hv))
+        g = -jnp.exp(p["A_log"]) * jax.nn.softplus(ba[..., r:].reshape(b, t, hv) + p["dt_bias"])
+        out, _ = gated_delta_chunked(
+            jnp.repeat(q, r, axis=2), jnp.repeat(k, r, axis=2), v, g, beta, cfg.delta_chunk)
+        y = rms_norm(out, p["norm"], cfg.rms_norm_eps) * jax.nn.silu(z)
+        return y.reshape(b, t, hv * dv) @ p["out"]
 
 
 def swiglu(p: dict, x):
@@ -674,7 +960,9 @@ def expert_layer(cfg: TorsoShape, p: dict, x, chosen_too: bool = False):
                        p["experts"], tuple(plan))
     placed = jnp.sum(slot_token < x.shape[0], dtype=jnp.int32)
     dropped = jnp.sum(held, dtype=jnp.int32) - placed
-    if cfg.n_shared_experts:
+    if cfg.shared_expert_gate and cfg.n_shared_experts:
+        y = y + jax.nn.sigmoid(x @ p["shared_gate"]) * swiglu(p["shared"], x)
+    elif cfg.n_shared_experts:
         y = y + swiglu(p["shared"], x)
     return y, ((load, dropped, chosen) if chosen_too else (load, dropped))
 
@@ -682,7 +970,7 @@ def expert_layer(cfg: TorsoShape, p: dict, x, chosen_too: bool = False):
 # ------------------------------------------------------------- the torso
 def _ffn(cfg: TorsoShape, moe: bool, x, p, chosen_too: bool = False):
     b, t, d = x.shape
-    normed = rms_norm(x, p["ffn_norm"], cfg.rms_norm_eps)
+    normed = block_norm(cfg, x, p["ffn_norm"])
     if not moe:
         return x + in_chunks(partial(swiglu, p["ffn"]), cfg.batch_chunks, normed), None
     with phase("agent.experts"):
@@ -707,16 +995,32 @@ def _indexed_block(cfg: IndexedTorsoConfig, moe: bool, emit: bool, x, p, valid):
     return x, (load_dropped, index_loss, keys, experts if emit else None)
 
 
+def _hybrid_mixer(cfg: HybridTorsoConfig, kind: str, x, p, valid):
+    """``x + mixer(n₁(x))``; ``p`` holds ``attn_norm`` and the mixer's leaves."""
+    normed = block_norm(cfg, x, p["attn_norm"])
+    if kind == "attention":
+        return x + gated_attention(cfg, p["attn"], normed, valid)
+    return x + gated_delta_net(cfg, p["lin"], normed, valid)
+
+
+def _hybrid_ffn(cfg: HybridTorsoConfig, emit: bool, x, p):
+    x, (load, dropped, experts) = _ffn(cfg, True, x, p, chosen_too=True)
+    return x, ((load, dropped), experts if emit else None)
+
+
 def torso_apply(cfg: TorsoShape, params: dict, obs, valid, emit_choices: bool = False):
     """``obs [B, T, O]``, ``valid [B, T]`` bool → ``(h [B, D], stats)``:
     the last position's state after the final norm, and the expert layers'
     routing counts ``{"load": [L, held] int32, "dropped": [L] int32}``.
     Under an indexer ``stats`` also holds ``index_loss`` (the layers'
     alignment losses added) and, with ``emit_choices``, what the pass chose:
-    ``keys [L, B, T, T]`` bool and ``experts [L_moe, B·T, k]`` int32."""
+    ``keys [L, B, T, T]`` bool and ``experts [L_moe, B·T, k]`` int32. The
+    hybrid stack has no index loss and no keys to choose: with
+    ``emit_choices`` it adds ``experts`` alone."""
     x = obs @ params["embed"]["kernel"] + params["embed"]["bias"]
     indexed = cfg.attention == "grouped_query_indexed"
-    if not indexed:
+    hybrid = cfg.attention == "gated_delta_hybrid"
+    if not (indexed or hybrid):
         bias = attention_bias(valid)
         cos, sin = rope_tables(cfg.rope_theta, cfg.qk_rope_head_dim, obs.shape[1])
 
@@ -730,13 +1034,30 @@ def torso_apply(cfg: TorsoShape, params: dict, obs, valid, emit_choices: bool = 
             index_loss = index_loss + loss
             keys.append(layer_keys)
             experts += [layer_experts] if moe else []
+        elif hybrid:
+            # Two checkpoints a block, the mixer's and the expert layer's:
+            # each is recomputed once, as one around both would be, and the
+            # backward pass holds one part's intermediates at a time (a
+            # DeltaNet layer's and the dispatch buffers together pass a
+            # chip). They keep the expert choices and the attention chunks'
+            # outputs (KEPT), as the indexed block's checkpoint does.
+            keep = jax.checkpoint_policies.save_only_these_names(KEPT)
+            mixer = {k: v for k, v in p.items() if k not in ("ffn", "ffn_norm")}
+            x = jax.checkpoint(partial(_hybrid_mixer, cfg, cfg.mixer(i)), policy=keep)(
+                x, mixer, valid)
+            x, (layer_stats, layer_experts) = jax.checkpoint(
+                partial(_hybrid_ffn, cfg, emit_choices), policy=keep)(
+                    x, {"ffn": p["ffn"], "ffn_norm": p["ffn_norm"]})
+            experts.append(layer_experts)
         else:
             x, layer_stats = jax.checkpoint(partial(_block, cfg, moe))(x, p, bias, cos, sin)
         if moe:
             stats.append(layer_stats)
     load, dropped = (jnp.stack(part) for part in zip(*stats))
-    h = rms_norm(x[:, -1], params["final_norm"], cfg.rms_norm_eps)
+    h = block_norm(cfg, x[:, -1], params["final_norm"])
     stats = {"load": load, "dropped": dropped}
+    if hybrid and emit_choices:
+        stats["experts"] = jnp.stack(experts)
     if indexed:
         stats["index_loss"] = index_loss
         if emit_choices:

@@ -477,6 +477,12 @@ class Trainer:
                 "[replay] device ring storage: "
                 + json.dumps(self._ring.describe_storage())
             )
+            if config.agent.torso is not None:
+                from d4pg_tpu.models.torso import describe_mixers
+
+                # Static: the stack's mixers in order and, where the delta
+                # rule runs, its chunks and the state a window carries.
+                print("[torso] mixers: " + json.dumps(describe_mixers(config.agent.torso)))
             if self._mega_mesh is not None and self._procs > 1:
                 # Multi-host: each process's host buffer feeds only its
                 # LOCAL dp shards through make_array_from_callback staging;

@@ -49,6 +49,7 @@ PHASES = (
     "agent.attention",      # projections, norms, rotary, scores, softmax, output
     "agent.experts",        # router, dispatch plan, expert blocks, shared expert, combine
     "agent.indexer",        # index projections and scores, the top-k, the alignment loss
+    "agent.linear_attention",   # Gated DeltaNet: projections, convolution, decays, the chunked scan, output gate
 )
 # One path component of an instruction's ``op_name``, no "/" in it:
 # ``jit(lane)/while/body/closed_call/jvp(ph:agent.networks)/...``. An

@@ -42,6 +42,7 @@ COMMON = {"replay.draw", "replay.row_gather", "agent.networks",
           "ops.projection_loss", "agent.optimizer"}
 TORSO = {"agent.attention", "agent.experts"}     # opened by models/torso.py only
 INDEXER = {"agent.indexer"}                      # and only where attention runs under one
+LINEAR = {"agent.linear_attention"}              # and only in the hybrid stack's DeltaNet layers
 
 
 def _cfg(**kw) -> D4PGConfig:
@@ -111,11 +112,14 @@ VARIANTS = {
     "device_per": (_device_per, COMMON | {"replay.write_back"}),
     "device_per_fused": (_device_per_fused, COMMON | {"replay.write_back"}),
     "uniform_sharded": (_uniform_sharded, COMMON | {"parallel.sync"}),
-    "device_per_sharded": (_device_per_sharded, set(PHASES) - TORSO - INDEXER),
+    "device_per_sharded": (_device_per_sharded, set(PHASES) - TORSO - INDEXER - LINEAR),
     "device_per_torso": (_device_per_torso, COMMON | {"replay.write_back"} | TORSO),
     "device_per_indexed_torso": (
         lambda: _device_per_torso("keye_vl2_tiny"),
         COMMON | {"replay.write_back"} | TORSO | INDEXER),
+    "device_per_hybrid_torso": (
+        lambda: _device_per_torso("qwen3_next_tiny"),
+        COMMON | {"replay.write_back"} | TORSO | LINEAR),
 }
 
 

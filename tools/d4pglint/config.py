@@ -205,6 +205,12 @@ MEGASTEP_FUNCTIONS = (
     "d4pg_tpu/models/torso.py::dispatch_plan",
     "d4pg_tpu/models/torso.py::_routed_fwd",
     "d4pg_tpu/models/torso.py::_routed_bwd",
+    # the hybrid stack's mixers and the gated delta rule (ISSUE 34)
+    "d4pg_tpu/models/torso.py::gated_delta_net",
+    "d4pg_tpu/models/torso.py::gated_attention",
+    "d4pg_tpu/models/torso.py::causal_conv",
+    "d4pg_tpu/ops/gated_delta.py::gated_delta_chunked",
+    "d4pg_tpu/ops/gated_delta.py::unit_lower_inverse",
     # The device priority tree's traced primitives (replay/device_per.py):
     # every one is traced into the megastep or the per-flush tree seed.
     "d4pg_tpu/replay/device_per.py::repair_ancestors",

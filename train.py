@@ -134,7 +134,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "episode (the default), or its whole stream — the "
                         "context is then the stream's last T rows across "
                         "episode ends (in-context RL); a torso whose attention "
-                        "runs under an indexer")
+                        "runs under an indexer, or the hybrid stack")
     p.add_argument("--twin-critic", action="store_true",
                    help="clipped double-Q (TD3-style) distributional twin "
                         "critics; fixes the single-critic plateau on "
