@@ -15,17 +15,32 @@ One head holds a state ``S ∈ R^{dk×dv}``, ``S₀ = 0``, and a token does
 ``gated_delta_chunked``    the window in chunks of ``chunk`` tokens. Inside
     a chunk the writes are untangled at once: with ``γ`` the chunk's
     cumulative log-decays and ``L = strict_tril(β k kᵀ ⊙ e^{γ_i − γ_j})``,
-    ``(I + L)⁻¹`` by forward substitution turns values and keys into what
-    each token writes given the state the chunk began with; a ``lax.scan``
-    over the chunks carries ``S`` and does matrix products only. XLA tier:
-    no kernel (PERF.md section 7 has what a fused one would save).
+    ``(I + L)⁻¹`` turns values and keys into what each token writes given
+    the state the chunk began with; a ``lax.scan`` over the chunks carries
+    ``S`` and does matrix products only. XLA tier: no kernel (PERF.md
+    section 7 has what a fused one would save).
 
-``matmul_precision``: the Gram product ``β k kᵀ``, the forward substitution
-and the inverse's backward pass are float32 at ``highest`` whatever the
-program's default — a ``chunk × chunk`` system a head whose error every
-later token of the chunk inherits, under 1% of the layer's FLOPs. The
-decays are elementwise float32. Every other product (the inverse applied,
-the scan's) runs at the caller's precision.
+``unit_lower_inverse``  ``(I + L)⁻¹`` in blocks, by the system's size alone
+    (``inverse_plan``): where ``chunk = 16 · 2^m`` the 16 × 16 diagonal
+    blocks are inverted by forward substitution — 16 sequential row steps
+    over the blocks alone, every system and block at once — and joined two
+    and two, ``[[A, 0], [M, B]]⁻¹ = [[A⁻¹, 0], [−B⁻¹ M A⁻¹, B⁻¹]]``, 16 → 32
+    → 64: two products a level; any other size runs the row form over the
+    whole system (``inverse_by_rows``: ``chunk`` steps over all of it, 17.9
+    ms against 2.1 at the cell's 4,096 systems of 64 × 64, PERF.md section
+    6, PR 36). Not the product form ``(I − L)(I + L²)(I + L⁴)…``: the powers
+    of a non-normal nilpotent ``L`` grow before they cancel, and on
+    strongly correlated keys with β ≈ 0.95 — adjacent rows of one stream —
+    it reads an error of 3e+10 of the inverse's scale where both forms
+    here read 3e-7 (``tests/test_torso_linear.py`` holds the case).
+
+``matmul_precision``: the Gram product ``β k kᵀ``, the inversion (its row
+steps elementwise, its joins' products) and the inverse's backward pass
+are float32 at ``highest`` whatever the program's default — a ``chunk ×
+chunk`` system a head whose error every later token of the chunk inherits,
+under 1% of the layer's FLOPs. The decays are elementwise float32. Every
+other product (the inverse applied, the scan's) runs at the caller's
+precision.
 """
 
 from __future__ import annotations
@@ -52,12 +67,22 @@ def gated_delta_recurrent(q, k, v, g, beta):
     return jnp.moveaxis(out, 0, 1), state
 
 
-@jax.custom_vjp
-def unit_lower_inverse(lower):
-    """``(I + L)⁻¹`` for strictly lower-triangular ``L [..., C, C]``, by
-    forward substitution a row at a time (exact float32 multiply-adds, C
-    sequential steps over every system at once). The loop is not
-    differentiated: the backward pass is two products with the inverse."""
+BLOCK = 16      # rows of a diagonal block: the only sequential steps of a blocked inverse
+
+
+def inverse_plan(c: int) -> tuple[int, int]:
+    """``(block, join_levels)`` for a ``c × c`` system, from its size alone:
+    ``(16, m)`` where ``c = 16 · 2^m`` with ``m ≥ 1`` (64 → ``(16, 2)``), and
+    ``(c, 0)`` — one block, the row form — for every other ``c``."""
+    blocks = c // BLOCK
+    if blocks < 2 or c % BLOCK or blocks & (blocks - 1):
+        return c, 0
+    return BLOCK, blocks.bit_length() - 1
+
+
+def inverse_by_rows(lower):
+    """``(I + L)⁻¹`` a row at a time: ``C`` sequential steps, each a
+    multiply-reduce over every system's whole inverse-so-far."""
     c = lower.shape[-1]
     eye = jnp.eye(c, dtype=lower.dtype)
 
@@ -67,6 +92,45 @@ def unit_lower_inverse(lower):
         return jax.lax.dynamic_update_index_in_dim(inverse, x_i, i, axis=-2)
 
     return jax.lax.fori_loop(0, c, row, jnp.zeros_like(lower))
+
+
+def diagonal_blocks(lower, block: int):
+    """``[..., C, C]`` → its ``C / block`` diagonal blocks ``[..., n, b, b]``,
+    by plain slices (a reshape to ``[n, b, n, b]`` makes XLA lay the whole of
+    ``L`` out a second time, PERF.md section 6, PR 36)."""
+    return jnp.stack([lower[..., j:j + block, j:j + block]
+                      for j in range(0, lower.shape[-1], block)], axis=-3)
+
+
+def join_inverses(inverses, lower):
+    """One level of ``[[A, 0], [M, B]]⁻¹ = [[A⁻¹, 0], [−B⁻¹ M A⁻¹, B⁻¹]]``:
+    ``inverses [..., 2n, s, s]`` of the diagonal blocks of ``lower
+    [..., 2ns, 2ns]`` → ``[..., n, 2s, 2s]``, two products at ``highest``."""
+    n, s = inverses.shape[-3] // 2, inverses.shape[-1]
+    pairs = inverses.reshape(inverses.shape[:-3] + (n, 2, s, s))
+    a, b = pairs[..., 0, :, :], pairs[..., 1, :, :]
+    m = jnp.stack([lower[..., (2 * p + 1) * s:(2 * p + 2) * s, 2 * p * s:(2 * p + 1) * s]
+                   for p in range(n)], axis=-3)
+    corner = -jnp.matmul(jnp.matmul(b, m, precision=HIGHEST), a, precision=HIGHEST)
+    top = jnp.concatenate([a, jnp.zeros_like(a)], axis=-1)
+    return jnp.concatenate([top, jnp.concatenate([corner, b], axis=-1)], axis=-2)
+
+
+@jax.custom_vjp
+def unit_lower_inverse(lower):
+    """``(I + L)⁻¹`` for strictly lower-triangular ``L [..., C, C]`` in exact
+    float32 multiply-adds, every system at once, by ``inverse_plan(C)``: the
+    diagonal blocks by forward substitution, then joined two and two. The
+    steps are not differentiated: the backward pass is two products with
+    the inverse."""
+    block, levels = inverse_plan(lower.shape[-1])
+    with jax.named_scope("delta_solve"):
+        if not levels:
+            return inverse_by_rows(lower)
+        inverses = inverse_by_rows(diagonal_blocks(lower, block))
+        for _ in range(levels):
+            inverses = join_inverses(inverses, lower)
+        return inverses[..., 0, :, :]
 
 
 def _inverse_fwd(lower):

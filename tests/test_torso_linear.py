@@ -47,13 +47,22 @@ def _window(cfg=TINY, b=3, obs_dim=5, seed=1):
 
 
 # ------------------------------------------------------------ the delta rule
-@pytest.mark.parametrize("chunk", [1, 4, 16])
-def test_chunked_scan_is_the_recurrence_values_and_gradients(chunk):
-    args = _rule_inputs()
+# T = 16 never reaches the blocked inverse (chunks ≤ 16 keep the row form);
+# T = 128 in chunks of 32 and 64 does, with one and two join levels, and the
+# scan hands a state across four and two chunks. The longer window gets 5e-6
+# where the short one has 2e-6: the final state sums 128 decayed writes and
+# not 16, in another order than the recurrence (read on the CPU: state
+# 1.2e-6 at chunk 64 against 2.4e-7 at T = 16, outputs 2.4e-7 against 6.7e-8).
+# The gradients keep their limit: 1.3e-6 of the largest element read, 4.5e-7 at
+# T = 16, against 2e-5.
+@pytest.mark.parametrize("chunk, t", [(1, 16), (4, 16), (16, 16), (32, 128), (64, 128)])
+def test_chunked_scan_is_the_recurrence_values_and_gradients(chunk, t):
+    args = _rule_inputs(t=t)
+    atol = 2e-6 if t == 16 else 5e-6
     out, state = gd.gated_delta_recurrent(*args)
     got, got_state = gd.gated_delta_chunked(*args, chunk=chunk)
-    np.testing.assert_allclose(got, out, atol=2e-6)
-    np.testing.assert_allclose(got_state, state, atol=2e-6)
+    np.testing.assert_allclose(got, out, atol=atol)
+    np.testing.assert_allclose(got_state, state, atol=atol)
 
     def loss(form):
         def f(*a):
@@ -82,16 +91,123 @@ def test_the_rule_is_its_three_lines_by_hand():
     np.testing.assert_allclose(got_state[0, 0], state, atol=1e-6)
 
 
-def test_unit_lower_inverse_and_its_backward_pass():
-    lower = jnp.tril(jax.random.normal(jax.random.PRNGKey(0), (3, 2, 6, 6)), -1)
+# ------------------------------------------------- the chunk's (I + L)⁻¹
+def _parent_row_form(lower):
+    """PR 34's ``unit_lower_inverse``, to the letter: the oracle of the bits."""
+    c = lower.shape[-1]
+    eye = jnp.eye(c, dtype=lower.dtype)
+
+    def row(i, inverse):
+        l_i = jax.lax.dynamic_index_in_dim(lower, i, axis=-2, keepdims=False)
+        x_i = eye[i] - jnp.sum(l_i[..., :, None] * inverse, axis=-2)
+        return jax.lax.dynamic_update_index_in_dim(inverse, x_i, i, axis=-2)
+
+    return jax.lax.fori_loop(0, c, row, jnp.zeros_like(lower))
+
+
+def _systems(c, lead=(3, 2), seed=0):
+    return jnp.tril(jax.random.normal(jax.random.PRNGKey(seed), lead + (c, c)), -1) * c ** -0.5
+
+
+@pytest.mark.parametrize("c", [1, 3, 6, 16, 32, 64])
+def test_unit_lower_inverse_and_its_backward_pass(c):
+    """Values and the custom backward pass against a float64 inverse."""
+    lower = _systems(c)
     inverse = gd.unit_lower_inverse(lower)
-    np.testing.assert_allclose(inverse @ (jnp.eye(6) + lower),
-                               jnp.broadcast_to(jnp.eye(6), lower.shape), atol=1e-5)
-    f = lambda fn: (lambda x: jnp.sum(jnp.sin(fn(x))))  # noqa: E731
-    want = jax.grad(f(lambda x: jnp.linalg.inv(jnp.eye(6) + jnp.tril(x, -1))))(lower)
-    np.testing.assert_allclose(jax.grad(f(gd.unit_lower_inverse))(lower), want, atol=2e-5)
+    want = np.linalg.inv(np.eye(c) + np.asarray(lower, np.float64))
+    scale = np.abs(want).max()
+    np.testing.assert_allclose(inverse, want, atol=2e-6 * scale)
+    if c >= 32:       # exactly unit lower-triangular, to the bit: the joins keep the zeros
+        assert np.array_equal(np.triu(inverse, 1), np.zeros_like(inverse))
+        assert not np.signbit(np.triu(inverse, 1)).any()
+        assert np.array_equal(np.diagonal(inverse, axis1=-2, axis2=-1),
+                              np.ones(inverse.shape[:-1], np.float32))
+    # f = Σ sin X: X̄ = cos X, and L̄ = −Xᵀ X̄ Xᵀ on the strict lower triangle
+    t = np.swapaxes(want, -1, -2)
+    want_grad = np.tril(-t @ np.cos(want) @ t, -1)
+    got = jax.grad(lambda x: jnp.sum(jnp.sin(gd.unit_lower_inverse(x))))(lower)
+    np.testing.assert_allclose(got, want_grad, atol=2e-5 * max(np.abs(want_grad).max(), 1.0))
+
+
+def test_a_window_that_is_not_whole_chunks_is_refused():
     with pytest.raises(ValueError, match="whole chunks"):
         gd.gated_delta_chunked(*_rule_inputs(), chunk=5)
+
+
+@pytest.mark.parametrize("c, plan", [(1, (1, 0)), (4, (4, 0)), (6, (6, 0)), (16, (16, 0)),
+                                     (32, (16, 1)), (48, (48, 0)), (64, (16, 2))])
+def test_the_inverse_plan_follows_the_size_alone(c, plan):
+    """The statement that the blocked form engaged: static, by shape."""
+    assert gd.inverse_plan(c) == plan
+
+
+@pytest.mark.parametrize("c", [1, 4, 6, 16])
+def test_small_systems_keep_the_row_form_to_the_bit(c):
+    """One block: the tiny preset (chunk 4) and every chunk ≤ 16 trace the
+    parent's loop, and the blocked sizes' base case is that loop too."""
+    lower = _systems(c, seed=c)
+    want = _parent_row_form(lower)
+    assert np.array_equal(gd.unit_lower_inverse(lower), want)
+    assert np.array_equal(gd.inverse_by_rows(lower), want)
+
+
+def _product_form(lower):
+    """``(I − L)(I + L²)(I + L⁴)…``: exact in exact arithmetic (``L`` is
+    nilpotent), and what the blocked form must never be simplified to."""
+    c = lower.shape[-1]
+    eye = jnp.eye(c, dtype=lower.dtype)
+    inverse, power = eye - lower, lower @ lower
+    while c > 2:
+        inverse, power, c = inverse @ (eye + power), power @ power, c // 2
+    return inverse
+
+
+def test_the_blocked_inverse_on_correlated_keys_where_the_product_form_fails():
+    """Adjacent rows of one stream: unit keys a few degrees apart, β ≈ 0.95,
+    slow decay, so every entry of ``L`` is ≈ 0.9 and its powers grow to
+    1e+17 before they cancel. The blocked form reads the row form's error
+    (3.5e-7 and 3.1e-7 of the inverse's scale); the product form 3e+10."""
+    c, ks = 64, jax.random.split(jax.random.PRNGKey(5), 3)
+    k = jax.random.normal(ks[0], (8, 1, 128)) + 0.1 * jax.random.normal(ks[1], (8, c, 128))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    beta = 0.95 + 0.04 * jax.random.uniform(ks[2], (8, c))
+    gamma = jnp.cumsum(jnp.full((8, c), -1e-3), axis=-1)
+    decay = jnp.exp(gamma[..., :, None] - gamma[..., None, :])
+    lower = jnp.tril(jnp.einsum("sik,sjk->sij", k * beta[..., None], k) * decay, -1)
+    assert float(lower[:, 1, 0].min()) > 0.85
+    want = np.linalg.inv(np.eye(c) + np.asarray(lower, np.float64))
+    error = lambda x: np.abs(np.asarray(x, np.float64) - want).max() / np.abs(want).max()  # noqa: E731
+    assert error(gd.unit_lower_inverse(lower)) <= 2e-6
+    assert error(gd.inverse_by_rows(lower)) <= 2e-6
+    assert error(_product_form(lower)) > 1e3
+
+
+def _loop_trips(jaxpr) -> list:
+    """Trip counts of every loop in a jaxpr, nested calls included (a
+    ``fori_loop`` of static bounds is a ``scan``; a ``while`` counts as ∞)."""
+    trips = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            trips.append(eqn.params["length"])
+        elif eqn.primitive.name == "while":
+            trips.append(float("inf"))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else (value,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    trips += _loop_trips(inner)
+    return trips
+
+
+def test_the_blocked_inverse_holds_no_loop_over_the_whole_system():
+    """At 64 × 64 the only sequential steps are the base case's 16 (the
+    parent's jaxpr holds one loop of 64)."""
+    lower = _systems(64, lead=(2,))
+    assert _loop_trips(jax.make_jaxpr(_parent_row_form)(lower).jaxpr) == [64]
+    trips = _loop_trips(jax.make_jaxpr(gd.unit_lower_inverse)(lower).jaxpr)
+    assert trips and max(trips) <= 16, trips
+    grad = jax.make_jaxpr(jax.grad(lambda x: jnp.sum(gd.unit_lower_inverse(x))))(lower)
+    assert max(_loop_trips(grad.jaxpr)) <= 16          # the loop is not differentiated
 
 
 # ------------------------------------------- the two mixers against the reference

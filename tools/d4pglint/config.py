@@ -211,6 +211,9 @@ MEGASTEP_FUNCTIONS = (
     "d4pg_tpu/models/torso.py::causal_conv",
     "d4pg_tpu/ops/gated_delta.py::gated_delta_chunked",
     "d4pg_tpu/ops/gated_delta.py::unit_lower_inverse",
+    "d4pg_tpu/ops/gated_delta.py::inverse_by_rows",
+    "d4pg_tpu/ops/gated_delta.py::diagonal_blocks",
+    "d4pg_tpu/ops/gated_delta.py::join_inverses",
     # The device priority tree's traced primitives (replay/device_per.py):
     # every one is traced into the megastep or the per-flush tree seed.
     "d4pg_tpu/replay/device_per.py::repair_ancestors",
