@@ -506,7 +506,7 @@ def train_step(
         )
 
     # ---- target: y = Φ(r + γ_eff · Z_target(s', μ_target(s'))) ----
-    with phase("agent.networks"):
+    with phase("agent.networks"), phase("agent.networks.target"):
         next_feat, target_extras = encode(tgt_critic_params, batch["next_obs"])
         next_action = actor.apply(tgt_actor_params, next_feat)
         if config.critic_ensemble:

@@ -560,11 +560,12 @@ def mla(cfg: TorsoConfig, p: dict, x, bias, cos, sin):
     k_rope = apply_rope(kv_a[..., cfg.kv_lora_rank:], cos, sin)     # one for all heads
     kv = (c_kv @ p["kv_b"]).reshape(b, t, h, nope + vd)
     k_nope, v = kv[..., :nope], kv[..., nope:]
-    scores = (jnp.einsum("bthd,bshd->bhts", q_nope, k_nope)
-              + jnp.einsum("bthd,bsd->bhts", q_rope, k_rope))
-    scores = scores / math.sqrt(nope + rope) + bias[:, None]
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhts,bshd->bthd", probs, v).reshape(b, t, h * vd)
+    with phase("agent.attention.scores"):
+        scores = (jnp.einsum("bthd,bshd->bhts", q_nope, k_nope)
+                  + jnp.einsum("bthd,bsd->bhts", q_rope, k_rope))
+        scores = scores / math.sqrt(nope + rope) + bias[:, None]
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("bhts,bshd->bthd", probs, v).reshape(b, t, h * vd)
     return out @ p["o"]
 
 
@@ -620,7 +621,7 @@ def _chunk_attention(kv: int, q, k, v, member):
     d]`` over the keys ``member [B, Tq, Ts]`` admits (``k, v [B, Ts, kv,
     d]``): ``(out [B, Tq, H·d], probs [B, kv, H/kv, Tq, Ts])``."""
     b, tq, h, hd = q.shape
-    with phase("agent.attention"):
+    with phase("agent.attention"), phase("agent.attention.scores"):
         logits = jnp.einsum("btkgd,bskd->bkgts", q.reshape(b, tq, kv, h // kv, hd), k)
         logits = jnp.where(member[:, None, None], logits / math.sqrt(hd), MASKED)
         probs = jax.nn.softmax(logits, axis=-1)
@@ -677,9 +678,11 @@ def indexed_attention(cfg: IndexedTorsoConfig, p: dict, index: dict, x, valid, e
         hi = lo + size
         see = causal[lo:hi, :hi][None] & valid[:, None, :hi]
         with phase("agent.indexer"):
-            scores = jax.checkpoint(index_scores)(q_i[:, lo:hi], k_i[:, :hi], w_i[:, lo:hi])
-            member = checkpoint_name(
-                choose_keys(jax.lax.stop_gradient(scores), see, cfg.index_topk), KEPT)
+            with phase("agent.indexer.scores"):
+                scores = jax.checkpoint(index_scores)(q_i[:, lo:hi], k_i[:, :hi], w_i[:, lo:hi])
+            with phase("agent.indexer.select"):
+                member = checkpoint_name(
+                    choose_keys(jax.lax.stop_gradient(scores), see, cfg.index_topk), KEPT)
         out, part = jax.checkpoint(partial(_attend, cfg))(
             q[:, lo:hi], k[:, :hi], v[:, :hi], member, scores, valid[:, lo:hi])
         outs.append(checkpoint_name(out, KEPT))
@@ -881,7 +884,10 @@ def _expert_blocks(rows, xs, block_expert, live_blocks, w):
         y = swiglu(_expert_weights(w, block_expert[i]), x)
         return jax.lax.dynamic_update_slice_in_dim(ys, y, i * rows, 0)
 
-    return jax.lax.fori_loop(0, live_blocks, body, jnp.zeros_like(xs))
+    with phase("agent.experts.dispatch"):
+        ys = jnp.zeros_like(xs)
+    with phase("agent.experts.blocks"):
+        return jax.lax.fori_loop(0, live_blocks, body, ys)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -893,11 +899,13 @@ def routed_experts(rows, x, gates, w, plan):
 
 def _routed_fwd(rows, x, gates, w, plan):
     slot, slot_token, _, block_expert, live_blocks = plan
-    xs = _rows(x, slot_token)
+    with phase("agent.experts.dispatch"):
+        xs = _rows(x, slot_token)
     ys = _expert_blocks(rows, xs, block_expert, live_blocks, w)
-    y = jnp.zeros_like(x)
-    for k in range(gates.shape[1]):       # one [N, D] gather a choice, not [N, k, D]
-        y = y + gates[:, k, None] * _rows(ys, slot[:, k])
+    with phase("agent.experts.dispatch"):
+        y = jnp.zeros_like(x)
+        for k in range(gates.shape[1]):   # one [N, D] gather a choice, not [N, k, D]
+            y = y + gates[:, k, None] * _rows(ys, slot[:, k])
     return y, (xs, gates, w, plan)
 
 
@@ -908,8 +916,9 @@ def _routed_bwd(rows, res, dy):
     xs, gates, w, plan = res
     slot, slot_token, slot_choice, block_expert, live_blocks = plan
     k = gates.shape[1]
-    slot_gate = _rows(gates.reshape(-1), slot_token * k + slot_choice)
-    dy_rows = _rows(dy, slot_token)                           # [P, D], not yet gated
+    with phase("agent.experts.dispatch"):
+        slot_gate = _rows(gates.reshape(-1), slot_token * k + slot_choice)
+        dy_rows = _rows(dy, slot_token)                       # [P, D], not yet gated
 
     def body(i, carry):
         d_xs, d_slot_gate, d_w = carry
@@ -935,14 +944,16 @@ def _routed_bwd(rows, res, dy):
                 jax.lax.dynamic_update_slice_in_dim(d_slot_gate, d_gate, i * rows, 0),
                 d_w)
 
-    d_xs, d_slot_gate, d_w = jax.lax.fori_loop(
-        0, live_blocks, body,
-        (jnp.zeros_like(xs), jnp.zeros_like(slot_gate),
-         jax.tree_util.tree_map(jnp.zeros_like, w)))
-    dx = jnp.zeros_like(dy)
-    for j in range(k):
-        dx = dx + _rows(d_xs, slot[:, j])
-    return dx, _rows(d_slot_gate, slot), d_w, None
+    with phase("agent.experts.dispatch"):
+        empty = jnp.zeros_like(xs), jnp.zeros_like(slot_gate)
+    with phase("agent.experts.blocks"):
+        d_xs, d_slot_gate, d_w = jax.lax.fori_loop(
+            0, live_blocks, body, (*empty, jax.tree_util.tree_map(jnp.zeros_like, w)))
+    with phase("agent.experts.dispatch"):
+        dx = jnp.zeros_like(dy)
+        for j in range(k):
+            dx = dx + _rows(d_xs, slot[:, j])
+        return dx, _rows(d_slot_gate, slot), d_w, None
 
 
 routed_experts.defvjp(_routed_fwd, _routed_bwd)
@@ -952,8 +963,9 @@ def expert_layer(cfg: TorsoShape, p: dict, x, chosen_too: bool = False):
     """The whole expert layer on ``[N, D]`` tokens: held routed experts +
     the shared expert where there is one. Also returns ``(load [held],
     dropped)`` — and the experts each token chose ``[N, k]``, if asked."""
-    chosen, gates = route(cfg, p, x)
-    *plan, load = dispatch_plan(cfg, chosen)
+    with phase("agent.experts.route"):
+        chosen, gates = route(cfg, p, x)
+        *plan, load = dispatch_plan(cfg, chosen)
     slot, slot_token = plan[:2]
     held = slot < slot_token.shape[0]              # the pair's expert is held here
     y = routed_experts(cfg.expert_block_rows, x, jnp.where(held, gates, 0.0),

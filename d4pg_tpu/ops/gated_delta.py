@@ -48,6 +48,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from d4pg_tpu.utils.profiling import phase
+
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -124,7 +126,7 @@ def unit_lower_inverse(lower):
     steps are not differentiated: the backward pass is two products with
     the inverse."""
     block, levels = inverse_plan(lower.shape[-1])
-    with jax.named_scope("delta_solve"):
+    with phase("agent.linear_attention.solve"):
         if not levels:
             return inverse_by_rows(lower)
         inverses = inverse_by_rows(diagonal_blocks(lower, block))
@@ -182,7 +184,8 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int = 64):
         state = state * last_i + jnp.swapaxes(k_i, -1, -2) @ new
         return state, out
 
-    state, out = jax.lax.scan(
-        step, jnp.zeros((b, h, dk, v.shape[-1]), q.dtype),
-        (writes, reads, within, q_in, k_out, last))
+    with phase("agent.linear_attention.scan"):
+        state, out = jax.lax.scan(
+            step, jnp.zeros((b, h, dk, v.shape[-1]), q.dtype),
+            (writes, reads, within, q_in, k_out, last))
     return jnp.moveaxis(out, (0, 2), (1, 3)).reshape(b, t, h, -1), state

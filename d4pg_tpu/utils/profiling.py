@@ -37,19 +37,35 @@ from d4pg_tpu.analysis import lockwitness
 # read by exactly one per-layer metric of the benchmark, ``<name>_ms``
 # (PERF.md section 3 has the span -> metric table). A scope is HLO metadata:
 # it changes no instruction and costs nothing on the device.
+#
+# A name of three components, ``<layer>.<phase>.<part>``, is a sub-phase: a
+# part of ``<layer>.<phase>``, opened only where that phase is already the
+# innermost one. A reader of two-component tokens (``cellbench/scopes.py``)
+# stops at the second dot and books every instruction as before; a reader of
+# whole tokens (``cellbench/reducers/span_ms.py``) sees the parts, and what
+# no part holds is the phase's own remainder.
 PHASES = (
     "replay.draw",          # key split, stratified prefixes, descent, IS weights
     "replay.row_gather",    # agent.d4pg.gather_batches
     "replay.write_back",    # write_back_lane, max-priority reduce
     "agent.networks",       # target forwards, both losses forward and backward
+    "agent.networks.target",    # the target encoder / torso, actor and critic forwards
     "ops.projection_loss",  # projection, cross-entropy, priority signal
     "agent.optimizer",      # both Adam updates, both Polyak updates
     "parallel.sync",        # train_step's _sync: det_pmean / pmean
     # inside agent.networks, a sequence torso's parts (models/torso.py)
     "agent.attention",      # projections, norms, rotary, scores, softmax, output
+    "agent.attention.scores",   # q·k, scale, mask, softmax, P·v: the score tile's whole life
     "agent.experts",        # router, dispatch plan, expert blocks, shared expert, combine
+    "agent.experts.route",      # router product, top-k, renormalisation, the dispatch plan
+    "agent.experts.dispatch",   # every access over the padded_pairs-row buffer outside the block loops
+    "agent.experts.blocks",     # the loops over live blocks and their bodies
     "agent.indexer",        # index projections and scores, the top-k, the alignment loss
+    "agent.indexer.scores",     # each chunk's index scores and their recomputation
+    "agent.indexer.select",     # the radix select and its tie rule
     "agent.linear_attention",   # Gated DeltaNet: projections, convolution, decays, the chunked scan, output gate
+    "agent.linear_attention.solve",  # ops/gated_delta.py: each chunk's (I + L)^-1
+    "agent.linear_attention.scan",   # ops/gated_delta.py: the lax.scan over chunks
 )
 # One path component of an instruction's ``op_name``, no "/" in it:
 # ``jit(lane)/while/body/closed_call/jvp(ph:agent.networks)/...``. An
@@ -165,8 +181,3 @@ class StageTimers:
                 k: v * 1e3 / (per if per else max(self._n[k], 1))
                 for k, v in self._acc.items()
             }
-
-    def reset(self) -> None:
-        with self._lock:
-            self._acc.clear()
-            self._n.clear()

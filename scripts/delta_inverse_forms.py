@@ -17,17 +17,16 @@ median of 12. Off the TPU it checks both forms against a float64 inverse at
 a small size and prints no time.
 
     chiprun -- python scripts/delta_inverse_forms.py [out.json]
-    python scripts/delta_inverse_forms.py solve_ms <trace.xplane.pb> [mark]
 
-The second form reads a traced run of the cell: device ms of the ops under
-the plain ``delta_solve`` scope (or whose ``op_name`` matches ``mark``).
+(In a traced run of the cell the solve is the sub-phase
+``agent.linear_attention.solve``: ``python -m cellbench.reducers.span_ms
+<xplane.pb>`` reads it.)
 """
 
 from __future__ import annotations
 
 import json
 import os
-import re
 import statistics
 import sys
 import tempfile
@@ -43,6 +42,7 @@ from d4pg_tpu.ops import gated_delta as gd  # noqa: E402
 
 REPS = 12
 CHUNK = 64
+SOLVE = "ph:agent.linear_attention.solve"
 
 
 def joined(inverses, lower):
@@ -100,12 +100,10 @@ def under(patch, fn):
             setattr(gd, name, value)
 
 
-def solve_ops(xplane_path, mark="delta_solve"):
-    """Device self time of the ops whose ``op_name`` matches ``mark`` (the
-    plain scope around the inversion: no ``ph:`` token, so no phase metric
-    sees it; a tree from before the scope is read by its row loop,
-    ``linear_attention.*while/body/closed_call``), first device:
-    ``(total ms, [[op, ms, executions]])``."""
+def solve_ops(xplane_path):
+    """Device self time of the ops under the sub-phase around the inversion
+    (``SOLVE`` in their ``op_name``), first device: ``(total ms, [[op, ms,
+    executions]])``."""
     plane = next((p for p in scopes._planes(xplane_path)
                   if trace.DEVICE_PLANE.match(p.name)), None)
     if plane is None:
@@ -115,7 +113,7 @@ def solve_ops(xplane_path, mark="delta_solve"):
     by_op: dict = {}
     for (key, _, _), (_, self_ns, _) in zip(events, trace.self_times(events)):
         name, op_name = names.get(key, ("", ""))
-        if re.search(mark, op_name):
+        if SOLVE in op_name:
             short = trace.hlo_category(name)[0]
             ms, n = by_op.get(short, (0.0, 0))
             by_op[short] = (ms + self_ns / 1e6, n + 1)
@@ -184,10 +182,6 @@ def forms_in_the_rule(args, timed):
 
 
 def main():
-    if len(sys.argv) > 2 and sys.argv[1] == "solve_ms":
-        total, ops = solve_ops(sys.argv[2], *sys.argv[3:4])
-        print(json.dumps({"ms_in_the_trace": total, "ops": ops[:20]}, indent=1))
-        return
     device = jax.devices()[0]
     on_tpu = device.platform == "tpu"
     shape = (128, 1, 32, CHUNK, CHUNK) if on_tpu else (4, 1, 2, CHUNK, CHUNK)

@@ -13,6 +13,7 @@ hold that they change no bit.
 
 from __future__ import annotations
 
+import functools
 import os
 import re
 
@@ -20,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from cellbench.reducers.span_ms import PASSES, pass_of, spans_of
 from cellbench.scopes import TOKEN, phase_of
 from d4pg_tpu.agent.d4pg import create_train_state
 from d4pg_tpu.agent.state import D4PGConfig, DistConfig
@@ -43,6 +45,10 @@ COMMON = {"replay.draw", "replay.row_gather", "agent.networks",
 TORSO = {"agent.attention", "agent.experts"}     # opened by models/torso.py only
 INDEXER = {"agent.indexer"}                      # and only where attention runs under one
 LINEAR = {"agent.linear_attention"}              # and only in the hybrid stack's DeltaNet layers
+# <layer>.<phase>.<part>: parts of a phase, which the two-component reader
+# books to the phase (utils/profiling.py)
+SUB_PHASES = tuple(p for p in PHASES if p.count(".") == 2)
+MAIN_PHASES = set(PHASES) - set(SUB_PHASES)
 
 
 def _cfg(**kw) -> D4PGConfig:
@@ -112,7 +118,7 @@ VARIANTS = {
     "device_per": (_device_per, COMMON | {"replay.write_back"}),
     "device_per_fused": (_device_per_fused, COMMON | {"replay.write_back"}),
     "uniform_sharded": (_uniform_sharded, COMMON | {"parallel.sync"}),
-    "device_per_sharded": (_device_per_sharded, set(PHASES) - TORSO - INDEXER - LINEAR),
+    "device_per_sharded": (_device_per_sharded, MAIN_PHASES - TORSO - INDEXER - LINEAR),
     "device_per_torso": (_device_per_torso, COMMON | {"replay.write_back"} | TORSO),
     "device_per_indexed_torso": (
         lambda: _device_per_torso("keye_vl2_tiny"),
@@ -135,11 +141,17 @@ def _instructions(text: str) -> list:
     return out
 
 
+@functools.lru_cache(maxsize=None)
+def _compiled(variant: str) -> list:
+    """A variant's instructions: one compile for every test that reads them."""
+    mega, shapes = VARIANTS[variant][0]()
+    return _instructions(mega.lower(*shapes).compile().as_text())
+
+
 @pytest.mark.parametrize("variant", VARIANTS)
 def test_every_variant_holds_its_phases(variant):
-    make, want = VARIANTS[variant]
-    mega, shapes = make()
-    instructions = _instructions(mega.lower(*shapes).compile().as_text())
+    want = VARIANTS[variant][1]
+    instructions = _compiled(variant)
     assert len(instructions) > 100, "the pattern no longer reads the compiled text"
     held = {phase_of(name) for _, name, _ in instructions} - {""}
     assert held == want
@@ -169,10 +181,80 @@ def test_every_variant_holds_its_phases(variant):
             assert any(op == "dot" and phase_of(n) == part for op, n, _ in instructions), part
 
 
-# Plain scopes: marks inside a phase for a reader of ``op_name`` (PERF.md
-# section 3), opened with ``jax.named_scope`` itself so that they carry no
-# ``ph:`` token and no phase metric books an instruction differently.
-PLAIN_SCOPES = {"gated_delta.py": ("delta_solve",)}
+# What each torso's compiled program must hold of the sub-phases, beside
+# ``agent.networks.target`` (every megastep's): the parts of its own mixers.
+EXPERT_PARTS = {"agent.experts.route", "agent.experts.dispatch", "agent.experts.blocks"}
+SUB_PHASES_OF = {
+    "device_per": set(),
+    "device_per_torso": EXPERT_PARTS | {"agent.attention.scores"},
+    "device_per_indexed_torso": EXPERT_PARTS | {
+        "agent.attention.scores", "agent.indexer.scores", "agent.indexer.select"},
+    "device_per_hybrid_torso": EXPERT_PARTS | {
+        "agent.attention.scores", "agent.linear_attention.solve",
+        "agent.linear_attention.scan"},
+}
+
+
+@pytest.mark.parametrize("variant", SUB_PHASES_OF)
+def test_sub_phases_lie_inside_their_phase_and_every_pass_is_told(variant):
+    """Whole tokens, as ``cellbench/reducers/span_ms.py`` reads them: each
+    sub-phase is on some instruction's path, right inside its own phase; no
+    phase but ``agent.networks`` nests another (so "under a span" and
+    "booked to it" are the same instructions); and the pass is written on
+    the path — also through the expert layer's custom VJP."""
+    instructions = [(op, name) for op, name, _ in _compiled(variant) if spans_of(name)]
+    held = {t for _, name in instructions for t in spans_of(name)}
+    assert held & set(SUB_PHASES) == SUB_PHASES_OF[variant] | {"agent.networks.target"}
+    for _, name in instructions:
+        tokens = spans_of(name)
+        for outer, inner in zip(tokens, tokens[1:]):
+            if inner in SUB_PHASES:         # opened where its phase is the innermost
+                assert outer.startswith(inner.rsplit(".", 1)[0]), name
+        mains = {t if t in MAIN_PHASES else t.rsplit(".", 1)[0] for t in tokens}
+        assert mains <= {"agent.networks", phase_of(name)}, name
+    under = lambda span: [n for _, n in instructions if span in spans_of(n)]  # noqa: E731
+    assert {pass_of(n) for n in under("agent.networks")} == (
+        set(PASSES) if variant != "device_per" else set(PASSES) - {"recompute"})
+    assert all(pass_of(n) == "target" for n in under("agent.networks.target"))
+    assert any(op == "dot" for op, n in instructions if "agent.networks.target" in spans_of(n))
+    if variant == "device_per":
+        return
+    # through the custom VJP: the dispatch gathers run in all four passes
+    # (the checkpoint runs the forward rule again for its residual, the
+    # gathered rows); the block loop forward, for the target and backward —
+    # its recomputed copy feeds nothing the backward rule reads, and XLA
+    # removes it
+    assert {pass_of(n) for n in under("agent.experts.dispatch")} == set(PASSES)
+    assert {pass_of(n) for n in under("agent.experts.blocks")} == set(PASSES) - {"recompute"}
+    assert any(op == "dot" and pass_of(n) == "backward" and "while/body" in n
+               for op, n in instructions if "agent.experts.blocks" in spans_of(n))
+    assert {pass_of(n) for n in under("agent.attention.scores")} == set(PASSES)
+    if variant == "device_per_indexed_torso":    # chosen once, kept by the checkpoint
+        assert {pass_of(n) for n in under("agent.indexer.select")} == {"target", "forward"}
+    if variant == "device_per_hybrid_torso":     # the inverse's backward is two products
+        assert {pass_of(n) for n in under("agent.linear_attention.solve")} == (
+            set(PASSES) - {"backward"})
+        assert {pass_of(n) for n in under("agent.linear_attention.scan")} == set(PASSES)
+
+
+@pytest.mark.parametrize("sub", SUB_PHASES)
+def test_the_two_component_reader_books_as_before(sub):
+    """``scopes.phase_of`` of a path with sub-phase tokens equals that of the
+    same path without them: every accepted ``…_ms`` metric reads as before."""
+    parent = sub.rsplit(".", 1)[0]
+    assert parent in MAIN_PHASES and TOKEN.match(PHASE_PREFIX + sub).group(1) == parent
+    outer = "" if parent == "agent.networks" else f"{PHASE_PREFIX}agent.networks/"
+    for path in (
+            f"jit(lane)/while/body/closed_call/{outer}jvp({PHASE_PREFIX}{parent})/"
+            f"{PHASE_PREFIX}{sub}/while/body/dot_general:",
+            f"jit(lane)/{PHASE_PREFIX}agent.networks/transpose(jvp({PHASE_PREFIX}agent.networks))"
+            f"/jvp()/checkpoint/rematted_computation/{PHASE_PREFIX}{parent}/{PHASE_PREFIX}{sub}/mul:",
+            f"jit(lane)/{PHASE_PREFIX}{parent}/{PHASE_PREFIX}{sub}/checkpoint/"
+            f"{PHASE_PREFIX}ops.projection_loss/mul:"):
+        without = path.replace(f"{PHASE_PREFIX}{sub}/", "")
+        assert PHASE_PREFIX + sub not in without
+        assert phase_of(path) == phase_of(without) != "", path
+        assert spans_of(path)[-1] in (sub, "ops.projection_loss")
 
 
 def test_phases_lists_what_the_sources_open_and_nothing_else():
@@ -185,17 +267,18 @@ def test_phases_lists_what_the_sources_open_and_nothing_else():
                 with open(os.path.join(base, f)) as src:
                     text = src.read()
                 opened |= set(re.findall(r'\bphase\("([^"]+)"\)', text))
-                for mark in PLAIN_SCOPES.get(f, ()):
-                    text = text.replace(f'jax.named_scope("{mark}")', "")
                 if f != "profiling.py":
                     assert "named_scope(" not in text, f
     assert opened == set(PHASES) and len(set(PHASES)) == len(PHASES)
-    assert all(TOKEN.fullmatch(PHASE_PREFIX + p) and "/" not in p for p in PHASES)
+    assert all(TOKEN.fullmatch(PHASE_PREFIX + p) and "/" not in p for p in MAIN_PHASES)
+    # a sub-phase's first two components are a listed phase: all the
+    # two-component reader sees of it
+    assert all(TOKEN.match(PHASE_PREFIX + p).group(1) in MAIN_PHASES and "/" not in p
+               and p.count(".") == 2 for p in SUB_PHASES)
     with pytest.raises(ValueError, match="unknown phase"):
         phase("replay.drew")
-    for mark in sum(PLAIN_SCOPES.values(), ()):
-        inside = f"jit(lane)/{PHASE_PREFIX}agent.linear_attention/{mark}/while/body/mul"
-        assert not TOKEN.search(mark) and phase_of(inside) == "agent.linear_attention"
+    with pytest.raises(ValueError, match="unknown phase"):
+        phase("agent.experts.combine")
 
 
 @pytest.mark.parametrize("exported", [None, "/x/placed/by/operator"])
