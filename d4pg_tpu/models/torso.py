@@ -38,11 +38,12 @@ that land on its own experts, and computes those — one chip's share of an
 expert-parallel layer, without the exchange. Nothing is dropped: the pairs
 are laid out by expert in blocks of ``expert_block_rows`` rows (each held
 expert's group padded to whole blocks, the buffer sized for the worst case)
-and a loop runs over the *live* blocks only, so the matrix products follow
-the routed pairs. No ``lax.ragged_dot`` (a ``tpu_custom_call`` on the v5e)
-and no scatter of wide rows (XLA:TPU expands it into a ``while`` of one-row
-updates): dispatch, combine and both of their transposes are gathers, which
-is why the routed part carries its own VJP.
+and a loop runs over the *live* blocks only, each gathering its own rows of
+the input (and, backward, of the cotangent), so the matrix products and the
+rows moved into them follow the routed pairs. No ``lax.ragged_dot`` (a
+``tpu_custom_call`` on the v5e) and no scatter of wide rows (XLA:TPU expands
+it into a ``while`` of one-row updates): dispatch, combine and both of their
+transposes are gathers, which is why the routed part carries its own VJP.
 
 Pure functions over a plain dict of arrays, one entry a block, each block
 under ``jax.checkpoint``. The blocks are a Python loop and not a
@@ -61,6 +62,7 @@ from typing import ClassVar
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
 from d4pg_tpu.ops.gated_delta import gated_delta_chunked
@@ -374,6 +376,27 @@ def describe_mixers(cfg: TorsoShape) -> dict:
         out.update(chunk=cfg.delta_chunk, chunks_per_window=cfg.window // cfg.delta_chunk,
                    state_bytes_per_window=state * mixers.count("linear"))
     return out
+
+
+def describe_experts(cfg: TorsoShape, tokens: int) -> dict:
+    """The expert layer's by-expert layout for ``tokens`` tokens, from the
+    configuration alone: the dispatch buffer's rows (``padded_pairs``), the
+    rows of a block, the blocks of the worst case, and the rows each live
+    block gathers inside the loop — what a pass moves is
+    :func:`gathered_rows` of its ``load``, not the buffer."""
+    rows = cfg.expert_block_rows
+    return {"tokens": tokens, "padded_pairs": cfg.padded_pairs(tokens), "block_rows": rows,
+            "worst_case_blocks": cfg.padded_pairs(tokens) // rows,
+            "rows_gathered_a_block": rows}
+
+
+def gathered_rows(cfg: TorsoShape, load) -> np.ndarray:
+    """Rows the block loop gathers a pass, ``Σₑ ⌈loadₑ / R⌉ · R``, from the
+    pairs per held expert ``load [..., held]`` (``torso_apply``'s
+    ``stats["load"]``, on the host): one figure a layer, against
+    ``padded_pairs``."""
+    rows = cfg.expert_block_rows
+    return np.sum(-(-np.asarray(load) // rows) * rows, axis=-1)
 
 
 # ------------------------------------------------------------------ init
@@ -877,17 +900,20 @@ def _expert_weights(w, e):
             for name in ("gate", "up", "down")}
 
 
-def _expert_blocks(rows, xs, block_expert, live_blocks, w):
-    """``ys [P, D]``: each live block of ``xs`` through its expert."""
+def _expert_blocks(rows, x, slot_token, block_expert, live_blocks, w):
+    """``ys [P, D]``: each live block through its expert, its ``rows`` rows
+    of ``x`` gathered by the loop itself — the by-expert layout of ``x`` is
+    never made whole."""
     def body(i, ys):
-        x = jax.lax.dynamic_slice_in_dim(xs, i * rows, rows)
-        y = swiglu(_expert_weights(w, block_expert[i]), x)
-        return jax.lax.dynamic_update_slice_in_dim(ys, y, i * rows, 0)
+        with phase("agent.experts.dispatch"):
+            xb = _rows(x, jax.lax.dynamic_slice_in_dim(slot_token, i * rows, rows))
+        with phase("agent.experts.blocks"):
+            y = swiglu(_expert_weights(w, block_expert[i]), xb)
+            return jax.lax.dynamic_update_slice_in_dim(ys, y, i * rows, 0)
 
     with phase("agent.experts.dispatch"):
-        ys = jnp.zeros_like(xs)
-    with phase("agent.experts.blocks"):
-        return jax.lax.fori_loop(0, live_blocks, body, ys)
+        ys = jnp.zeros((slot_token.shape[0], x.shape[1]), x.dtype)
+    return jax.lax.fori_loop(0, live_blocks, body, ys)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -899,56 +925,58 @@ def routed_experts(rows, x, gates, w, plan):
 
 def _routed_fwd(rows, x, gates, w, plan):
     slot, slot_token, _, block_expert, live_blocks = plan
-    with phase("agent.experts.dispatch"):
-        xs = _rows(x, slot_token)
-    ys = _expert_blocks(rows, xs, block_expert, live_blocks, w)
+    ys = _expert_blocks(rows, x, slot_token, block_expert, live_blocks, w)
     with phase("agent.experts.dispatch"):
         y = jnp.zeros_like(x)
         for k in range(gates.shape[1]):   # one [N, D] gather a choice, not [N, k, D]
             y = y + gates[:, k, None] * _rows(ys, slot[:, k])
-    return y, (xs, gates, w, plan)
+    return y, (x, gates, w, plan)
 
 
 def _routed_bwd(rows, res, dy):
     """Gathers both ways: a row of the dispatch buffer holds one pair, so
-    what autodiff would scatter-add is read back by index instead. The
-    blocks' hidden activations are recomputed, not kept."""
-    xs, gates, w, plan = res
+    what autodiff would scatter-add is read back by index instead. Each live
+    block gathers its own rows of ``x`` and ``dy``; the blocks' hidden
+    activations are recomputed, not kept."""
+    x, gates, w, plan = res
     slot, slot_token, slot_choice, block_expert, live_blocks = plan
     k = gates.shape[1]
     with phase("agent.experts.dispatch"):
-        slot_gate = _rows(gates.reshape(-1), slot_token * k + slot_choice)
-        dy_rows = _rows(dy, slot_token)                       # [P, D], not yet gated
+        slot_gate = _rows(gates.reshape(-1), slot_token * k + slot_choice)   # [P], one word a row
 
     def body(i, carry):
         d_xs, d_slot_gate, d_w = carry
         e = block_expert[i]
         cut = lambda a: jax.lax.dynamic_slice_in_dim(a, i * rows, rows)  # noqa: E731
-        x, g_out, gate = cut(xs), cut(dy_rows), cut(slot_gate)
-        gate_w, up_w, down_w = (_expert_weights(w, e)[n] for n in ("gate", "up", "down"))
-        a, u = x @ gate_w, x @ up_w
-        sig = jax.nn.sigmoid(a)
-        s = a * sig                                           # silu(a)
-        hidden = s * u
-        d_gate = jnp.sum(g_out * (hidden @ down_w), axis=-1)  # <dy, E(x)> of each pair
-        g_y = gate[:, None] * g_out
-        g_h = g_y @ down_w.T
-        g_a, g_u = g_h * u * sig * (1.0 + a * (1.0 - sig)), g_h * s
-        add = lambda name, g: jax.lax.dynamic_update_index_in_dim(  # noqa: E731
-            d_w[name], jax.lax.dynamic_index_in_dim(d_w[name], e, keepdims=False) + g,
-            e, 0)
-        d_w = {"gate": add("gate", x.T @ g_a), "up": add("up", x.T @ g_u),
-               "down": add("down", hidden.T @ g_y)}
-        g_x = g_a @ gate_w.T + g_u @ up_w.T
-        return (jax.lax.dynamic_update_slice_in_dim(d_xs, g_x, i * rows, 0),
-                jax.lax.dynamic_update_slice_in_dim(d_slot_gate, d_gate, i * rows, 0),
-                d_w)
+        with phase("agent.experts.dispatch"):
+            token = cut(slot_token)
+            xb, g_out = _rows(x, token), _rows(dy, token)     # g_out not yet gated
+        with phase("agent.experts.blocks"):
+            gate = cut(slot_gate)
+            gate_w, up_w, down_w = (_expert_weights(w, e)[n] for n in ("gate", "up", "down"))
+            a, u = xb @ gate_w, xb @ up_w
+            sig = jax.nn.sigmoid(a)
+            s = a * sig                                           # silu(a)
+            hidden = s * u
+            d_gate = jnp.sum(g_out * (hidden @ down_w), axis=-1)  # <dy, E(x)> of each pair
+            g_y = gate[:, None] * g_out
+            g_h = g_y @ down_w.T
+            g_a, g_u = g_h * u * sig * (1.0 + a * (1.0 - sig)), g_h * s
+            add = lambda name, g: jax.lax.dynamic_update_index_in_dim(  # noqa: E731
+                d_w[name], jax.lax.dynamic_index_in_dim(d_w[name], e, keepdims=False) + g,
+                e, 0)
+            d_w = {"gate": add("gate", xb.T @ g_a), "up": add("up", xb.T @ g_u),
+                   "down": add("down", hidden.T @ g_y)}
+            g_x = g_a @ gate_w.T + g_u @ up_w.T
+            return (jax.lax.dynamic_update_slice_in_dim(d_xs, g_x, i * rows, 0),
+                    jax.lax.dynamic_update_slice_in_dim(d_slot_gate, d_gate, i * rows, 0),
+                    d_w)
 
     with phase("agent.experts.dispatch"):
-        empty = jnp.zeros_like(xs), jnp.zeros_like(slot_gate)
+        empty = jnp.zeros((slot_token.shape[0], x.shape[1]), x.dtype), jnp.zeros_like(slot_gate)
     with phase("agent.experts.blocks"):
-        d_xs, d_slot_gate, d_w = jax.lax.fori_loop(
-            0, live_blocks, body, (*empty, jax.tree_util.tree_map(jnp.zeros_like, w)))
+        d_w = jax.tree_util.tree_map(jnp.zeros_like, w)
+    d_xs, d_slot_gate, d_w = jax.lax.fori_loop(0, live_blocks, body, (*empty, d_w))
     with phase("agent.experts.dispatch"):
         dx = jnp.zeros_like(dy)
         for j in range(k):

@@ -478,11 +478,15 @@ class Trainer:
                 + json.dumps(self._ring.describe_storage())
             )
             if config.agent.torso is not None:
-                from d4pg_tpu.models.torso import describe_mixers
+                from d4pg_tpu.models.torso import describe_experts, describe_mixers
 
                 # Static: the stack's mixers in order and, where the delta
-                # rule runs, its chunks and the state a window carries.
-                print("[torso] mixers: " + json.dumps(describe_mixers(config.agent.torso)))
+                # rule runs, its chunks and the state a window carries; the
+                # expert layer's buffer and blocks at a chip's tokens a pass.
+                torso = config.agent.torso
+                print("[torso] mixers: " + json.dumps(describe_mixers(torso)))
+                tokens = config.batch_size // (config.dp or 1) * torso.window
+                print("[torso] experts: " + json.dumps(describe_experts(torso, tokens)))
             if self._mega_mesh is not None and self._procs > 1:
                 # Multi-host: each process's host buffer feeds only its
                 # LOCAL dp shards through make_array_from_callback staging;

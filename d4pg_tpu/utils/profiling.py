@@ -58,8 +58,8 @@ PHASES = (
     "agent.attention.scores",   # q·k, scale, mask, softmax, P·v: the score tile's whole life
     "agent.experts",        # router, dispatch plan, expert blocks, shared expert, combine
     "agent.experts.route",      # router product, top-k, renormalisation, the dispatch plan
-    "agent.experts.dispatch",   # every access over the padded_pairs-row buffer outside the block loops
-    "agent.experts.blocks",     # the loops over live blocks and their bodies
+    "agent.experts.dispatch",   # moving rows: each live block's gathers, the combine, the buffer fills
+    "agent.experts.blocks",     # each live block's products and its writes
     "agent.indexer",        # index projections and scores, the top-k, the alignment loss
     "agent.indexer.scores",     # each chunk's index scores and their recomputation
     "agent.indexer.select",     # the radix select and its tie rule

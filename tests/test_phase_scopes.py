@@ -219,15 +219,17 @@ def test_sub_phases_lie_inside_their_phase_and_every_pass_is_told(variant):
     assert any(op == "dot" for op, n in instructions if "agent.networks.target" in spans_of(n))
     if variant == "device_per":
         return
-    # through the custom VJP: the dispatch gathers run in all four passes
-    # (the checkpoint runs the forward rule again for its residual, the
-    # gathered rows); the block loop forward, for the target and backward —
-    # its recomputed copy feeds nothing the backward rule reads, and XLA
-    # removes it
-    assert {pass_of(n) for n in under("agent.experts.dispatch")} == set(PASSES)
-    assert {pass_of(n) for n in under("agent.experts.blocks")} == set(PASSES) - {"recompute"}
-    assert any(op == "dot" and pass_of(n) == "backward" and "while/body" in n
-               for op, n in instructions if "agent.experts.blocks" in spans_of(n))
+    # through the custom VJP: the rows are gathered by the block loops, one
+    # block at a time, and the forward rule's residual is x itself, so the
+    # dispatch and the blocks run forward, for the target and backward — the
+    # checkpoint's re-run of the forward rule feeds nothing the backward rule
+    # reads (route and plan are the recomputation's), and XLA removes it
+    for part in ("agent.experts.dispatch", "agent.experts.blocks"):
+        assert {pass_of(n) for n in under(part)} == set(PASSES) - {"recompute"}, part
+    for part, op in (("agent.experts.blocks", "dot"), ("agent.experts.dispatch", "gather")):
+        assert {pass_of(n) for o, n in instructions
+                if o == op and part in spans_of(n) and "while/body" in n} == (
+            set(PASSES) - {"recompute"}), part
     assert {pass_of(n) for n in under("agent.attention.scores")} == set(PASSES)
     if variant == "device_per_indexed_torso":    # chosen once, kept by the checkpoint
         assert {pass_of(n) for n in under("agent.indexer.select")} == {"target", "forward"}

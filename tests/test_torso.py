@@ -6,6 +6,8 @@ Tiny sizes on the CPU; what the chip measured is PERF.md's."""
 from __future__ import annotations
 
 import dataclasses
+import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,11 +29,19 @@ def _layer_params(cfg, seed=0):
     return T._block_init(cfg, jax.random.PRNGKey(seed), moe=True)["ffn"]
 
 
+def _shared(cfg, p, x):
+    """The shared expert's part, behind its gate where the kind has one."""
+    if not cfg.n_shared_experts:
+        return jnp.zeros_like(x)
+    gate = jax.nn.sigmoid(x @ p["shared_gate"]) if cfg.shared_expert_gate else 1.0
+    return gate * T.swiglu(p["shared"], x)
+
+
 def _masked_dense(cfg, p, x):
     """Every held expert on every token, masked: what the grouped products
     must equal."""
     chosen, gates = T.route(cfg, p, x)
-    y = T.swiglu(p["shared"], x)
+    y = _shared(cfg, p, x)
     for e in range(cfg.experts_held):
         gate = jnp.sum(jnp.where(chosen == e + cfg.experts_first, gates, 0.0), -1)
         w = jax.tree_util.tree_map(lambda a: a[e], p["experts"])
@@ -39,21 +49,197 @@ def _masked_dense(cfg, p, x):
     return y
 
 
-@pytest.mark.parametrize("first, held", [(0, 8), (2, 4), (6, 2), (3, 1)])
-def test_expert_layer_equals_masked_dense_forward_and_backward(first, held):
-    cfg = dataclasses.replace(TINY, experts_first=first, experts_held=held)
+# The routed layer as it stood until PR 38: the by-expert layout of x (and,
+# backward, of dy) gathered whole, [padded_pairs, D], before the block loop.
+# The oracle the in-loop gathers are held to, bit for bit.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _gather_first(rows, x, gates, w, plan):
+    return _gather_first_fwd(rows, x, gates, w, plan)[0]
+
+
+def _gather_first_fwd(rows, x, gates, w, plan):
+    slot, slot_token, _, block_expert, live_blocks = plan
+    xs = T._rows(x, slot_token)
+
+    def body(i, ys):
+        xb = jax.lax.dynamic_slice_in_dim(xs, i * rows, rows)
+        y = T.swiglu(T._expert_weights(w, block_expert[i]), xb)
+        return jax.lax.dynamic_update_slice_in_dim(ys, y, i * rows, 0)
+
+    ys = jax.lax.fori_loop(0, live_blocks, body, jnp.zeros_like(xs))
+    y = jnp.zeros_like(x)
+    for k in range(gates.shape[1]):
+        y = y + gates[:, k, None] * T._rows(ys, slot[:, k])
+    return y, (xs, gates, w, plan)
+
+
+def _gather_first_bwd(rows, res, dy):
+    xs, gates, w, plan = res
+    slot, slot_token, slot_choice, block_expert, live_blocks = plan
+    k = gates.shape[1]
+    slot_gate = T._rows(gates.reshape(-1), slot_token * k + slot_choice)
+    dy_rows = T._rows(dy, slot_token)
+
+    def body(i, carry):
+        d_xs, d_slot_gate, d_w = carry
+        e = block_expert[i]
+        cut = lambda a: jax.lax.dynamic_slice_in_dim(a, i * rows, rows)  # noqa: E731
+        x, g_out, gate = cut(xs), cut(dy_rows), cut(slot_gate)
+        gate_w, up_w, down_w = (T._expert_weights(w, e)[n] for n in ("gate", "up", "down"))
+        a, u = x @ gate_w, x @ up_w
+        sig = jax.nn.sigmoid(a)
+        s = a * sig
+        hidden = s * u
+        d_gate = jnp.sum(g_out * (hidden @ down_w), axis=-1)
+        g_y = gate[:, None] * g_out
+        g_h = g_y @ down_w.T
+        g_a, g_u = g_h * u * sig * (1.0 + a * (1.0 - sig)), g_h * s
+        add = lambda name, g: jax.lax.dynamic_update_index_in_dim(  # noqa: E731
+            d_w[name], jax.lax.dynamic_index_in_dim(d_w[name], e, keepdims=False) + g, e, 0)
+        d_w = {"gate": add("gate", x.T @ g_a), "up": add("up", x.T @ g_u),
+               "down": add("down", hidden.T @ g_y)}
+        g_x = g_a @ gate_w.T + g_u @ up_w.T
+        return (jax.lax.dynamic_update_slice_in_dim(d_xs, g_x, i * rows, 0),
+                jax.lax.dynamic_update_slice_in_dim(d_slot_gate, d_gate, i * rows, 0), d_w)
+
+    d_xs, d_slot_gate, d_w = jax.lax.fori_loop(
+        0, live_blocks, body, (jnp.zeros_like(xs), jnp.zeros_like(slot_gate),
+                               jax.tree_util.tree_map(jnp.zeros_like, w)))
+    dx = jnp.zeros_like(dy)
+    for j in range(k):
+        dx = dx + T._rows(d_xs, slot[:, j])
+    return dx, T._rows(d_slot_gate, slot), d_w, None
+
+
+_gather_first.defvjp(_gather_first_fwd, _gather_first_bwd)
+
+KEYE_TINY, QWEN_TINY = T.TORSO_PRESETS["keye_vl2_tiny"], T.TORSO_PRESETS["qwen3_next_tiny"]
+# (preset, experts_first, experts_held, expert_block_rows, skew): a skew
+# {expert: logit} makes those experts' router columns logit · x₀ with every
+# token's x₀ ≥ 1, so that the tokens pile onto them
+EXPERT_CASES = {
+    # the sigmoid router (GLM's kind), shares of the eight experts
+    "sigmoid-0of8": (TINY, 0, 8, 8, None),          # every expert held
+    "sigmoid-2of4": (TINY, 2, 4, 8, None),
+    "sigmoid-6of2": (TINY, 6, 2, 8, None),
+    "sigmoid-3of1": (TINY, 3, 1, 8, None),
+    "sigmoid-rows4": (TINY, 0, 8, 4, None),
+    # several blocks on one expert
+    "sigmoid-skewed": (TINY, 0, 8, 8, {1: 10.0}),
+    # a group of 33 pairs in 8-row blocks: its last block one pair, seven sentinels
+    "sigmoid-last-block-sentinels": (TINY, 0, 4, 8, {2: 10.0}),
+    # the softmax router (Keye's and Qwen's kind), with and without a gated
+    # shared expert; held experts no token picks
+    "softmax-16of16": (KEYE_TINY, 0, 16, 8, None),  # every expert held
+    "softmax-4of8-rows16": (KEYE_TINY, 4, 8, 16, None),
+    "softmax-skewed-no-pairs": (KEYE_TINY, 0, 8, 8, {3: 20.0, 12: 20.0, 13: 20.0, 14: 20.0}),
+    "softmax-gated-shared": (QWEN_TINY, 0, 16, 8, None),
+    "softmax-gated-shared-skewed": (QWEN_TINY, 8, 8, 4, {9: 20.0}),
+}
+
+
+def _expert_case(name, tokens=33):
+    preset, first, held, rows, skew = EXPERT_CASES[name]
+    cfg = dataclasses.replace(preset, experts_first=first, experts_held=held,
+                              expert_block_rows=rows)
     p = _layer_params(cfg)
-    p["router_bias"] = 0.2 * jax.random.normal(jax.random.PRNGKey(9), (cfg.n_routed_experts,))
-    x = jax.random.normal(jax.random.PRNGKey(1), (40, cfg.hidden_size))
+    if cfg.router == "sigmoid_bias":
+        p["router_bias"] = 0.2 * jax.random.normal(jax.random.PRNGKey(9), (cfg.n_routed_experts,))
+    x = jax.random.normal(jax.random.PRNGKey(1), (tokens, cfg.hidden_size))
+    if skew:
+        x = x.at[:, 0].set(jnp.abs(x[:, 0]) + 1.0)
+    for e, logit in (skew or {}).items():
+        p["router"] = p["router"].at[:, e].set(0.0).at[0, e].set(logit)
+    return cfg, p, x
+
+
+@pytest.mark.parametrize("name", EXPERT_CASES)
+def test_expert_layer_equals_gather_first_to_the_bit_and_masked_dense(name):
+    """The routed part against the gather-first form, bit for bit — ``y``
+    and the cotangents of ``x``, the gates and the experts' weights — and
+    the whole layer against every held expert on every token, masked."""
+    cfg, p, x = _expert_case(name)
+    chosen, gates = T.route(cfg, p, x)
+    *plan, load = T.dispatch_plan(cfg, chosen)
+    held = plan[0] < plan[1].shape[0]
+    gates = jnp.where(held, gates, 0.0)
+    dy = jax.random.normal(jax.random.PRNGKey(7), x.shape)
+    outs = []
+    for f in (T.routed_experts, _gather_first):
+        y, vjp = jax.vjp(lambda x, g, w: f(cfg.expert_block_rows, x, g, w, tuple(plan)),
+                         x, gates, p["experts"])
+        outs.append((y, *vjp(dy)))
+    for got, want in zip(jax.tree_util.tree_leaves(outs[0]), jax.tree_util.tree_leaves(outs[1])):
+        np.testing.assert_array_equal(got, want)
+    assert int(load.sum()) == int(held.sum())       # dropless
+    rows = cfg.expert_block_rows
+    if EXPERT_CASES[name][4]:                       # what the skew was for
+        assert int(load.max()) > 2 * rows, load
+    if "no-pairs" in name:
+        assert int(jnp.sum(load == 0)) >= 1, load
+    if name == "sigmoid-last-block-sentinels":
+        assert int(load.max()) % rows == 1, load
+
     y, (load, dropped) = T.expert_layer(cfg, p, x)
     np.testing.assert_allclose(y, _masked_dense(cfg, p, x), atol=2e-6)
-    assert int(dropped) == 0 and load.shape == (held,)
+    assert int(dropped) == 0 and load.shape == (cfg.experts_held,)
     loss = lambda f: lambda p, x: jnp.sum(jnp.sin(f(p, x)))  # noqa: E731
     got = jax.grad(loss(lambda p, x: T.expert_layer(cfg, p, x)[0]), argnums=(0, 1))(p, x)
     want = jax.grad(loss(lambda p, x: _masked_dense(cfg, p, x)), argnums=(0, 1))(p, x)
     for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(g, w, atol=5e-6)
-    assert float(jnp.abs(got[0]["router_bias"]).max()) == 0.0    # a buffer: no gradient
+    if cfg.router == "sigmoid_bias":
+        assert float(jnp.abs(got[0]["router_bias"]).max()) == 0.0    # a buffer: no gradient
+
+
+# "%gather.5 = f32[8,1,32]{2,1,0} gather(…), …, metadata={op_name="…"": the
+# rows (first dimension), the row width (last) and where it was traced
+GATHER = re.compile(r"= \w+\[(\d+),(?:\d+,)*(\d+)\]\S* gather\(.*op_name=\"([^\"]*)\"")
+
+
+@pytest.mark.parametrize("preset", [KEYE_TINY, QWEN_TINY], ids=["indexed", "hybrid"])
+def test_no_gather_of_the_whole_dispatch_buffer(preset):
+    """The layer's forward and backward, compiled: no ``[padded_pairs, D]``
+    gather anywhere (what the forward rule keeps is ``x``, not its by-expert
+    layout; the backward rule reads ``dy`` by block), ``[R, D]`` gathers in
+    the loops' bodies."""
+    cfg = dataclasses.replace(preset, experts_first=2, experts_held=8)
+    p = _layer_params(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (40, cfg.hidden_size))
+    big, block = cfg.padded_pairs(40), cfg.expert_block_rows
+    assert big not in (40, block)
+
+    def loss(p, x):
+        return jnp.sum(jnp.sin(T.expert_layer(cfg, p, x)[0]))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(p, x).compile().as_text()
+    wide = [(int(rows), name) for rows, width, name in GATHER.findall(text)
+            if int(width) == cfg.hidden_size]
+    assert not [name for rows, name in wide if rows == big], wide
+    in_loops = [name for rows, name in wide if rows == block]
+    assert len(in_loops) == 3 and all("while/body" in name for name in in_loops), wide
+    chosen, gates = T.route(cfg, p, x)
+    plan = T.dispatch_plan(cfg, chosen)[:5]
+    _, residual = T._routed_fwd(block, x, gates, p["experts"], plan)
+    assert all(np.shape(a)[:1] != (big,) or np.ndim(a) < 2
+               for a in jax.tree_util.tree_leaves(residual))
+
+
+def test_the_layout_is_described_and_the_rows_gathered_follow_the_load():
+    full = dataclasses.replace(T.TORSO_PRESETS["qwen3_next"], num_hidden_layers=4,
+                               experts_held=16)
+    assert T.describe_experts(full, 8192) == {
+        "tokens": 8192, "padded_pairs": 86016, "block_rows": 256,
+        "worst_case_blocks": 336, "rows_gathered_a_block": 256}
+    keye = dataclasses.replace(T.TORSO_PRESETS["keye_vl2"], num_hidden_layers=4,
+                               experts_held=16)
+    assert T.describe_experts(keye, 8192)["padded_pairs"] == 73728
+    load = np.array([[0, 1, 256, 257], [512, 0, 0, 3]])
+    assert T.gathered_rows(full, load).tolist() == [256 + 256 + 512, 512 + 256]
+    cfg, p, x = _expert_case("sigmoid-skewed")
+    chosen, _ = T.route(cfg, p, x)
+    *_, live_blocks, load = T.dispatch_plan(cfg, chosen)
+    assert int(T.gathered_rows(cfg, load)) == int(live_blocks) * cfg.expert_block_rows
 
 
 def test_the_selection_bias_enters_the_choice_only():
