@@ -6,8 +6,9 @@ Mix parameters (``cellbench/traffic/<mix>.json``): ``argv`` (appended to the
 configuration's), ``dp`` (chips the mesh spans; absent = one device, no
 mesh), ``ring_rows`` ("global": the configuration's ``--rmsize`` is the whole
 ring; "per_chip": each chip holds that many rows), ``warm_dispatches``,
-``inflight`` (dispatches the host may run ahead of the device),
-``trace_seconds``, ``dispatch_module``.
+``inflight`` (dispatches the host may run ahead of the device in the
+timed window), ``trace_inflight`` (the same in the traced window; absent =
+``inflight``), ``trace_seconds``, ``dispatch_module``.
 
 Surface into the program: ``train.build_parser``/``config_from_args``,
 ``agent.create_train_state``, the ``make_megastep_*`` factories,
@@ -188,10 +189,14 @@ class Loop:
                 self.state, self.ring, self.tree, self.key)
         return metrics["critic_loss"]
 
-    def run(self, seconds: float | None = None, dispatches: int | None = None):
+    def run(self, seconds: float | None = None, dispatches: int | None = None,
+            inflight: int | None = None):
         """Dispatch until the deadline (or a count); returns ``(clock at the
         first dispatch, clock after the last one's state is ready, losses)``.
+        Every dispatch sent counts, and the clock stops only once the last
+        is done. ``inflight`` overrides the loop's own depth for this run.
         A dispatch that raises ends the loop and is kept in ``error``."""
+        ahead = self.inflight if inflight is None else inflight
         pending, losses = collections.deque(), []
         jax.block_until_ready(self.state.step)
         gc.disable()
@@ -206,7 +211,7 @@ class Loop:
                     break
                 losses.append(loss)
                 pending.append(loss)
-                if len(pending) > self.inflight:
+                if len(pending) > ahead:
                     jax.block_until_ready(pending.popleft())
             jax.block_until_ready(self.state.step)
             c1 = probe.Clock()
@@ -277,7 +282,9 @@ def run(job: Job) -> dict:
         trace_dir = os.path.join(job.cell.out_dir, "trace")
         trace.start(trace_dir)
         with jax.profiler.TraceAnnotation(trace.WINDOW_SPAN):
-            t0, t1, traced_losses = loop.run(seconds=job.trace_seconds)
+            t0, t1, traced_losses = loop.run(
+                seconds=job.trace_seconds,
+                inflight=int(mix.get("trace_inflight", mix["inflight"])))
         trace.stop()
         xplane = trace.newest_xplane(trace_dir)
         traced = {"seconds": t1.perf - t0.perf, "dispatches": len(traced_losses)}

@@ -6,10 +6,11 @@ Needs the TPU the cell asks for and fails without it (no CPU fallback).
 ``--rehearsal`` is the one way onto the CPU: the cell's tiny rehearsal sizes,
 ``platform: cpu`` in the last line and no metric in it. Earlier lines are
 progress and the report; the LAST line of stdout is the contract's object:
-``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` and, traced,
-``breakdown``. With ``--trace 0`` the metrics are the cell's end-to-end
-metrics, with ``--trace 1`` its per-layer metrics. ``README.md`` says what is
-written where.
+``correct``, ``attempted``, ``failed``, ``metrics``, ``device``, traced
+``breakdown``, and last ``compared``: each number the reference comparison
+held to a limit, beside it (also the last lines of stderr). With ``--trace
+0`` the metrics are the cell's end-to-end metrics, with ``--trace 1`` its
+per-layer metrics. ``README.md`` says what is written where.
 """
 
 from __future__ import annotations
@@ -123,6 +124,23 @@ def breakdown(tr) -> dict:
     return {"device_ops": top(by_op), "idle_gaps": top(by_host)}
 
 
+def compared(checks) -> dict:
+    """The numbers the reference comparison holds to a limit, each beside
+    its limit: ``{"<check>.<number>": [value, limit]}``, a number passing
+    where it is at most its limit. Relative errors and ulps of check 1 (a
+    driver's own limits by name under ``tol_of`` / ``tol_ulp_of``), and the
+    descent's mismatches, which must be none."""
+    out = {}
+    for check, c in checks.items():
+        for errs, tol, tol_of in (("rel_err", "tol_rel", "tol_of"),
+                                  ("ulp_err", "tol_ulp", "tol_ulp_of")):
+            for name, value in (c.get(errs) or {}).items():
+                out[f"{check}.{name}"] = [value, (c.get(tol_of) or {}).get(name, c[tol])]
+        if "mismatches" in c:
+            out[f"{check}.mismatches"] = [c["mismatches"], 0]
+    return out
+
+
 def read_trace(cell, xplane, say):
     """The traced window's events, clipped to the window annotation; leaves
     a slice of them next to the report, to read by hand (the form of the
@@ -223,9 +241,13 @@ def main(argv=None) -> int:
         line["metrics"] = {
             m["name"]: {"value": e2e[m["name"]], "unit": m["unit"]}
             for m in cell.end_to_end if m["name"] in e2e}
+    line["compared"] = compared(result["checks"])
     with open(os.path.join(cell.out_dir, "report.json"), "w") as f:
         json.dump(report, f, indent=1, default=str)
     print(json.dumps(report, default=str), flush=True)
+    for name, (value, limit) in line["compared"].items():
+        print(f"compared: {name} {value:.6g} (limit {limit:g})", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

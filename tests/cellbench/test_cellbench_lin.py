@@ -229,9 +229,13 @@ def test_the_cell_declares_its_four_metrics_and_no_other_cell_reads_them():
             assert m["workloads"] == [CELL] and m["moves"] == "transitions_per_s"
     for other in (w["name"] for w in manifest["workloads"] if w["name"] != CELL):
         assert not NEW & {m["name"] for m in mf.cell(manifest, root, other).per_layer}
-    # the last entries of their lists: nothing put first or in the middle
-    assert manifest["configs"][-1]["name"] == CONFIG and manifest["workloads"][-1]["name"] == CELL
-    assert {m["name"] for m in manifest["per_layer"][-4:]} == NEW
+    # each entry once, and the four together after the entries that came
+    # before them: nothing put first or in the middle, whatever is appended
+    assert [c["name"] for c in manifest["configs"]].count(CONFIG) == 1
+    assert [w["name"] for w in manifest["workloads"]].count(CELL) == 1
+    listed = [m["name"] for m in manifest["per_layer"]]
+    after = listed.index("agent.ctx_step_mfu") + 1
+    assert set(listed[after:after + len(NEW)]) == NEW
 
 
 def test_the_mix_is_ctx8ks_to_the_letter_under_its_own_driver():
@@ -249,7 +253,13 @@ def test_config_file_holds_the_programs_preset(body):
     from train import build_parser, config_from_args
 
     cfg = config_from_args(build_parser().parse_args(body["argv"]))
-    assert dataclasses.asdict(cfg.agent.torso) == body["torso"]
+    # the file's keys as stated; a field it does not state at its default
+    got = dataclasses.asdict(cfg.agent.torso)
+    assert {key: got[key] for key in body["torso"]} == body["torso"]
+    assert {f.name: got[f.name] for f in dataclasses.fields(cfg.agent.torso)
+            if f.name not in body["torso"]} == {
+        f.name: f.default for f in dataclasses.fields(cfg.agent.torso)
+        if f.name not in body["torso"]}
     cut = dataclasses.replace(
         TORSO_PRESETS["qwen3_next"], num_hidden_layers=4, experts_held=16, window=8192,
         row_stride=1, span="stream")

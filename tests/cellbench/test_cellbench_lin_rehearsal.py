@@ -29,7 +29,9 @@ def test_lin_cell_rehearsal_traced():
     lines = [json.loads(ln) for ln in p.stdout.splitlines() if ln.startswith("{")]
     report, line = lines[-2:]
     assert p.stdout.strip().splitlines()[-1].startswith('{"correct"')
-    assert set(line) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert {"correct", "attempted", "failed", "metrics", "device", "compared"} <= set(line)
+    assert list(line)[-1] == "compared"
+    assert line["compared"]["reference_step.priorities"][1] > 0
     assert line["device"]["platform"] == "cpu" and line["metrics"] == {}
     assert line["correct"] is True and line["failed"] == 0 and line["attempted"] > 0
     assert all(c["ok"] for c in report["checks"].values()), report["checks"]

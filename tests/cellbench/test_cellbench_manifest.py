@@ -4,8 +4,10 @@ only: discovery is by name, and no list of them lives in code."""
 
 from __future__ import annotations
 
+import glob
 import json
 import os
+import re
 import shutil
 
 import pytest
@@ -178,9 +180,44 @@ def test_new_cells_need_only_files_and_entries(tmp_path):
     (lambda m: m["end_to_end"].pop(), "setup_s"),
     (lambda m: m.update(extra=1), "manifest keys"),
     (lambda m: m["configs"][0].update(why="x" * 201), "why is over 200"),
-    (lambda m: m["per_layer"][-2]["workloads"].append("no.such"), "unknown cell"),
+    (lambda m: next(x for x in m["per_layer"] if "workloads" in x)["workloads"].append(
+        "no.such"), "unknown cell"),
 ])
 def test_problems_are_named(manifest, break_it, complaint):
     broken = json.loads(json.dumps(manifest))
     break_it(broken)
     assert any(complaint in p for p in mf.problems(broken, REPO)), mf.problems(broken, REPO)
+
+
+# A list of the manifest read from its end: ``<list>[-n]`` or ``<list>[-n:]``
+# on configs, workloads or per_layer, by key or by attribute.
+FROM_THE_END = re.compile(
+    r"""(?:\[\s*["'](?:configs|workloads|per_layer)["']\s*\]|\.(?:configs|workloads|per_layer))"""
+    r"\s*\[\s*-\s*\d")
+
+
+def test_no_test_reads_a_manifest_list_from_its_end():
+    """New configurations, cells and per-layer metrics go at the end of
+    their lists: a test that holds an entry to the tail fails the next PR
+    that appends one, and a later PR may not edit a benchmark test."""
+    hits = []
+    for path in sorted(glob.glob(os.path.join(REPO, "tests", "cellbench", "*.py"))):
+        with open(path) as f:
+            for n, line in enumerate(f, 1):
+                if FROM_THE_END.search(line):
+                    hits.append(f"{os.path.basename(path)}:{n}: {line.strip()}")
+    assert hits == []
+
+
+@pytest.mark.parametrize("head, tail, reads_the_end", [
+    ('m["configs"]', '[-1]["name"]', True),
+    ("manifest['workloads']", "[-1]", True),
+    ('{x["name"] for x in manifest["per_layer"]', "[-4:]}", True),
+    ("cell.per_layer", "[ -2 ]", True),
+    ('m["per_layer"]', "[0]", False),
+    ("listed", "[-4:]", False),
+    ('m["per_layer"]', ".index(entry)", False),
+])
+def test_the_guard_sees_a_read_from_the_end(head, tail, reads_the_end):
+    """Each case in two halves, so that this file holds no such read."""
+    assert bool(FROM_THE_END.search(head + tail)) == reads_the_end

@@ -16,7 +16,7 @@ from cellbench import manifest as mf
 from test_cellbench_manifest import copy_of_the_benchmark, add_files_and_entries
 
 REPO = mf.CODE_ROOT
-LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
@@ -43,7 +43,13 @@ def _check_rehearsal(p, devices=1):
     assert p.returncode == 0, p.stdout[-2000:] + p.stderr[-3000:]
     report, line = _json_lines(p.stdout)[-2:]
     assert p.stdout.strip().splitlines()[-1].startswith('{"correct"')
-    assert set(line) == LINE_KEYS and set(line["device"]) == DEVICE_KEYS
+    # the contract's keys (a later key may join them), the numbers compared
+    # beside their limits last in the line and on stderr
+    assert LINE_KEYS <= set(line) and list(line)[-1] == "compared"
+    assert set(line["device"]) == DEVICE_KEYS
+    tail = p.stderr.strip().splitlines()[-len(line["compared"]):]
+    assert [t.split()[1] for t in tail] == list(line["compared"])
+    assert all(value <= limit for value, limit in line["compared"].values())
     assert line["device"]["platform"] == "cpu" and line["device"]["count"] == devices
     assert line["metrics"] == {}, "a CPU run prints under no metric's name"
     assert "end_to_end" not in report and "grad_steps_per_s" not in report

@@ -1,7 +1,7 @@
 """The reducer ``span_ms`` (whole ``ph:`` tokens, sub-phases and passes) on
 raw ``.xplane.pb`` files written here byte by byte, on the recorded slices,
-and with the arguments of the per-layer metrics the torso cells are to
-declare through it.
+and with the arguments of the per-layer metrics the torso cells declare
+through it.
 
 The raw files hold one device plane whose op events carry the ``op_name``s
 a lowered torso really has (``tests/test_phase_scopes.py`` holds those on
@@ -173,14 +173,10 @@ def test_the_join_is_remade_for_another_trace(tmp_path):
     assert first("agent.experts.blocks", measure="events") == 2.0
 
 
-# ---------------------------------------- the torso cells' metrics-to-be
-# ISSUE 37's per-layer metrics: (reducer, args, cells). They are NOT in
-# ``BENCHMARK.json``: ``test_cellbench_lin.py`` holds PR 34's four metrics to
-# the last four ``per_layer`` entries and new entries go at the end, so a
-# ``benchmark`` PR has to loosen that line before it can declare these
-# (PERF.md section 7). Until then they are read by hand — ``python -m
-# cellbench.reducers.span_ms <xplane.pb>`` — and held here, ready to become a
-# file each under ``cellbench/layer_metrics/``.
+# ------------------------------------------- the torso cells' span metrics
+# The per-layer metrics that read the torso's sub-phases and passes:
+# (reducer, args, cells), each declared in ``BENCHMARK.json`` with a file of
+# its own under ``cellbench/layer_metrics/``.
 WANT = {
     "agent.torso_optimizer_ms": ("scope_ms", {"phase": "agent.optimizer"}, T3),
     "agent.torso_networks_rest_ms": ("scope_ms", {"phase": "agent.networks"}, T3),
@@ -214,11 +210,14 @@ def test_a_torso_metric_to_be_names_an_opened_span_and_cells_that_could_report_i
     for cell_name in cells:
         reported = {m["name"] for m in mf.cell(manifest, mf.CODE_ROOT, cell_name).end_to_end}
         assert "transitions_per_s" in reported
-    # a manifest that declares it (a later ``benchmark`` PR's) declares this
-    for entry in (m for m in manifest["per_layer"] if m["name"] == name):
-        spec = mf._read(mf.CODE_ROOT, mf.metric_file(name))
-        assert entry["workloads"] == list(cells) and entry["source"] == "program_span"
-        assert (spec["reducer"], spec["args"]) == (reducer, args)
+    # the manifest declares it once, for those cells, from its own file
+    (entry,) = (m for m in manifest["per_layer"] if m["name"] == name)
+    spec = mf._read(mf.CODE_ROOT, mf.metric_file(name))
+    assert entry["workloads"] == list(cells) and entry["source"] == "program_span"
+    assert (spec["reducer"], spec["args"]) == (reducer, args)
+    assert entry["moves"] == "transitions_per_s" and entry["better"] == "lower"
+    assert entry["layer"] == name.split(".")[0]
+    assert entry["unit"] == ("events" if args.get("measure") == "events" else "ms")
 
 
 @pytest.mark.parametrize("cell_name", T3)

@@ -197,7 +197,13 @@ def test_config_file_holds_the_programs_preset(body):
     from train import build_parser, config_from_args
 
     cfg = config_from_args(build_parser().parse_args(body["argv"]))
-    assert dataclasses.asdict(cfg.agent.torso) == body["torso"]
+    # the file's keys as stated; a field it does not state at its default
+    got = dataclasses.asdict(cfg.agent.torso)
+    assert {key: got[key] for key in body["torso"]} == body["torso"]
+    assert {f.name: got[f.name] for f in dataclasses.fields(cfg.agent.torso)
+            if f.name not in body["torso"]} == {
+        f.name: f.default for f in dataclasses.fields(cfg.agent.torso)
+        if f.name not in body["torso"]}
     cut = dataclasses.replace(
         TORSO_PRESETS["glm47_flash"], num_hidden_layers=5, experts_held=8, window=32,
         row_stride=1)
